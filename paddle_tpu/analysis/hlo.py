@@ -88,7 +88,12 @@ _INSTR_RE = re.compile(
 _BUF_RE = re.compile(r'(\w+)\[([\d,]*)\]')
 # computation header: `ENTRY %main (...) -> ... {` / `%body.12 (...) {`
 _COMP_RE = re.compile(r'^(ENTRY\s+)?%?([\w.\-]+)\s*\([^)]*\)[^{]*{')
-_META_RE = re.compile(r'source_file="([^"]*)"\s+source_line=(\d+)')
+# source metadata: instructions carry `stack_frame_id=N`; the module
+# header's FileNames / FileLocations / StackFrames tables resolve a
+# frame to the (file, line) of the user code that emitted it
+_FRAME_ID_RE = re.compile(r'stack_frame_id=(\d+)')
+_TABLE_ROW_RE = re.compile(r'^(\d+)\s+(.*)$')
+_TABLE_FIELD_RE = re.compile(r'(\w+)=(\d+)')
 # iota replica groups: `replica_groups=[8,2]<=[16]` (groups x size)
 _GROUPS_IOTA_RE = re.compile(r'replica_groups=\[(\d+),(\d+)\]<=')
 _GROUPS_LIST_RE = re.compile(r'replica_groups=\{\{([\d,]*)\}')
@@ -232,7 +237,7 @@ def _parse_sharding(line):
     return '{' + body + '}'
 
 
-def _parse_instr(line, num_partitions):
+def _parse_instr(line, num_partitions, frames):
     m = _INSTR_RE.match(line)
     if not m:
         return None
@@ -263,10 +268,9 @@ def _parse_instr(line, num_partitions):
     if opcode == 'custom-call':
         tm = re.search(r'custom_call_target="([^"]*)"', rest)
         call_target = tm.group(1) if tm else None
-    file = line_no = None
-    mm = _META_RE.search(rest)
-    if mm:
-        file, line_no = mm.group(1), int(mm.group(2))
+    fm = _FRAME_ID_RE.search(rest)
+    file, line_no = frames.get(int(fm.group(1)), (None, None)) \
+        if fm else (None, None)
     return HloInstr(name, opcode, type_spec, operands=operands,
                     sharding=_parse_sharding(rest), group_size=group_size,
                     called=called, fusion_kind=fusion_kind, file=file,
@@ -274,10 +278,26 @@ def _parse_instr(line, num_partitions):
                     call_target=call_target)
 
 
+def _resolve_frames(tables):
+    """{stack frame id: (file, line)} from the header tables."""
+    files = {i: row.strip('"') for i, row in tables['FileNames'].items()}
+    locs = {i: dict(_TABLE_FIELD_RE.findall(row))
+            for i, row in tables['FileLocations'].items()}
+    frames = {}
+    for i, row in tables['StackFrames'].items():
+        loc = locs.get(int(dict(_TABLE_FIELD_RE.findall(row))
+                           .get('file_location_id', 0)), {})
+        frames[i] = (files.get(int(loc.get('file_name_id', 0))),
+                     int(loc['line']) if 'line' in loc else None)
+    return frames
+
+
 def parse_module(text):
     """Compiled HLO text -> HloModule (computations, instrs, graph)."""
     mod = HloModule()
     current = None
+    tables = {'FileNames': {}, 'FileLocations': {}, 'StackFrames': {}}
+    table = frames = None
     for line in text.splitlines():
         if line.startswith('HloModule'):
             pm = _NUM_PARTITIONS_RE.search(line)
@@ -285,6 +305,15 @@ def parse_module(text):
                 mod.num_partitions = int(pm.group(1))
             mod.is_scheduled = 'is_scheduled=true' in line
             continue
+        if current is None and line.strip() in tables:
+            table = tables[line.strip()]
+            continue
+        if current is None and table is not None:
+            rm = _TABLE_ROW_RE.match(line)
+            if rm:
+                table[int(rm.group(1))] = rm.group(2)
+                continue
+            table = None
         cm = _COMP_RE.match(line)
         if cm:
             current = HloComputation(cm.group(2),
@@ -298,7 +327,9 @@ def parse_module(text):
             continue
         if current is None:
             continue
-        ins = _parse_instr(line, mod.num_partitions)
+        if frames is None:
+            frames = _resolve_frames(tables)
+        ins = _parse_instr(line, mod.num_partitions, frames)
         if ins is not None:
             current.add(ins)
     return mod

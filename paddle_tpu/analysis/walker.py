@@ -18,12 +18,8 @@ import math
 import numpy as np
 
 import jax
-
-try:                      # jax.core is the public alias; keep a fallback
-    from jax import core as _core
-    _core.Jaxpr, _core.ClosedJaxpr, _core.Literal, _core.Var
-except (ImportError, AttributeError):       # pragma: no cover
-    from jax._src import core as _core
+from jax._src import source_info_util
+from jax.extend import core as _core
 
 __all__ = ['trace_jaxpr', 'walk', 'subjaxprs', 'eqn_location',
            'aval_bytes', 'is_literal', 'const_derived_vars']
@@ -74,16 +70,12 @@ def is_literal(v):
 
 def eqn_location(eqn):
     """(file, line) of the user frame that emitted this equation, or
-    (None, None) when source info is unavailable.  Uses jax's own
-    user-frame filter so jax-internal frames are skipped."""
-    try:
-        from jax._src import source_info_util
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is None:
-            return None, None
-        return fr.file_name, fr.start_line
-    except Exception:
+    (None, None) when the equation carries no user frame.  Uses jax's
+    own user-frame filter so jax-internal frames are skipped."""
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    if fr is None:
         return None, None
+    return fr.file_name, fr.start_line
 
 
 def aval_bytes(aval):

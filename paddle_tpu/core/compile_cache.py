@@ -1,21 +1,30 @@
 """Persistent compilation cache + AOT warm start.
 
-Whole-program XLA compilation is this framework's core bet, but until
-now every elastic restart, reshape restore, planner candidate and
-inference cold-start re-paid the full trace+lower+compile.  This
-module makes compiled work durable across processes:
+Whole-program XLA compilation is this framework's core bet, and every
+elastic restart, reshape restore, planner candidate and inference
+cold-start would otherwise re-pay the full trace+lower+compile.  Two
+layers make compiled work durable across processes:
 
-* **exec tier** — serialized ``jax.export`` artifacts (StableHLO +
-  calling convention) of a jitted function.  A warm process
-  deserializes and runs ``jax.jit(exported.call)`` instead of
-  re-tracing the Python model; the XLA backend compile underneath is
-  additionally persisted via jax's own compilation cache, which this
-  module points at ``<cache>/xla`` — so a restarted worker skips BOTH
-  the trace/lower and the XLA optimization passes.
-* **text tier** — compiled (post-partitioner) HLO text keyed by the
-  planner/audit lowering keys, so repeated ``tpu_lint --plan``/
-  ``--hlo`` invocations on unchanged targets read disk instead of
-  compiling dozens of candidates again.
+* **jax's own persistent compilation cache** (backend executables,
+  donation kept) is on by default and placed by ONE rule,
+  ``setup_xla_cache()``: ``JAX_COMPILATION_CACHE_DIR`` set -> jax
+  reads it itself and this program sets no directory in code; unset
+  -> ``<checkout>/.jax_cache``.  The path is part of jax's cache key,
+  so it is fixed: never ``~``, a temporary name, a pid or the time.
+* **the exec and text tiers** of this module serve only when
+  ``PADDLE_TPU_COMPILE_CACHE`` names a directory (tests,
+  ``bench.py --cache-smoke`` and ``tools/precompile.py`` do):
+
+  - *exec tier* — serialized ``jax.export`` artifacts (StableHLO +
+    calling convention) of a jitted function.  A warm process
+    deserializes and runs ``jax.jit(exported.call)`` instead of
+    re-tracing the Python model.  A hit does NOT donate its inputs,
+    so the same commit runs a different program on its second start
+    than on its first — which is why the tier is opt-in;
+  - *text tier* — compiled (post-partitioner) HLO text keyed by the
+    planner/audit lowering keys, so repeated ``tpu_lint --plan``/
+    ``--hlo`` invocations on unchanged targets read disk instead of
+    compiling dozens of candidates again.
 
 Every entry is ONE file written through the resilience/manifest commit
 discipline (``manifest.atomic_write``: tmp + fsync + os.replace) with
@@ -29,12 +38,15 @@ of identical content.
 Keys are content fingerprints over (jaxpr text with memory addresses
 normalized out, static arguments, mesh axes, in/out shardings,
 donation mask, jax version, backend, device count, and a hash of the
-package sources — any code edit invalidates conservatively).
+package sources — any code edit, or a stray untracked ``.py`` under
+the package directory, invalidates conservatively).
 
-Enable/disable: the ``PADDLE_TPU_COMPILE_CACHE`` env var.  Unset ->
-``~/.cache/paddle_tpu/compile`` (on).  A path -> that directory.
-``0``/``off``/``false``/empty -> disabled entirely (the escape hatch;
-the test suite defaults to this so tier-1 timing is cache-independent).
+``PADDLE_TPU_COMPILE_CACHE``: unset -> jax's cache only.  A path ->
+the exec/text tiers in that directory, jax's cache still where the
+rule above puts it.  ``0``/``off``/``false``/empty -> everything off,
+jax's cache included (``jax_enable_compilation_cache`` False): a tool
+that wants cold compiles turns the cache off, it does not move it.
+The test suite runs this way so tier-1 is cache-independent.
 
 Telemetry: every hit/miss/serialize/deserialize/quarantine emits a
 ``compile_cache`` event with bytes and latency; ``tools/run_report``
@@ -57,7 +69,8 @@ import re
 import time
 
 __all__ = [
-    'enabled', 'cache_dir', 'fingerprint', 'jaxpr_text',
+    'enabled', 'cache_dir', 'setup_xla_cache', 'xla_cache_dir',
+    'fingerprint', 'jaxpr_text',
     'jaxpr_fingerprint', 'get', 'put', 'get_text', 'put_text',
     'lookup_executable', 'store_executable', 'export_jit',
     'through_cache', 'bucket_pow2', 'stats', 'reset_stats',
@@ -67,8 +80,10 @@ __all__ = [
 ]
 
 ENV_VAR = 'PADDLE_TPU_COMPILE_CACHE'
+XLA_ENV_VAR = 'JAX_COMPILATION_CACHE_DIR'
 _DISABLE_VALUES = ('0', 'off', 'false', 'no', '')
-DEFAULT_DIR = os.path.join('~', '.cache', 'paddle_tpu', 'compile')
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 PRECOMPILE_MANIFEST = '_PADDLE_PRECOMPILE.json'
 _FORMAT = 1
 _ADDR_RE = re.compile(r'0x[0-9a-fA-F]+')
@@ -77,90 +92,73 @@ _stats = {}
 _code_token_memo = None
 _extra_dirs = []    # sidecar-recorded cache dirs (warm_start) lookups
 #                     fall back to when the local dir misses
-_xla_wired_dir = None
-_xla_set_value = None
+_NOT_APPLIED = object()
+_xla_applied = _NOT_APPLIED  # the xla_cache_dir() last applied
+
+
+def _switched_off():
+    raw = os.environ.get(ENV_VAR)
+    return raw is not None and raw.strip().lower() in _DISABLE_VALUES
 
 
 def enabled():
-    """True iff the persistent cache is active for this process."""
+    """True iff the exec/text tiers are active for this process."""
     return cache_dir() is not None
 
 
 def cache_dir():
-    """The cache directory (created lazily by put), or None when the
-    escape hatch (PADDLE_TPU_COMPILE_CACHE=0/off/false) is set."""
+    """The exec/text tier directory (created lazily by put), or None
+    unless PADDLE_TPU_COMPILE_CACHE names one.  Every compile choke
+    point asks this before it builds, so this is also where jax's own
+    cache gets placed (setup_xla_cache)."""
+    setup_xla_cache()
     raw = os.environ.get(ENV_VAR)
-    if raw is None:
-        d = os.path.expanduser(DEFAULT_DIR)
-    elif raw.strip().lower() in _DISABLE_VALUES:
-        _unwire_xla_cache()
+    if raw is None or _switched_off():
         return None
+    return os.path.abspath(os.path.expanduser(raw))
+
+
+def xla_cache_dir():
+    """Where jax's persistent compilation cache lives under the rule,
+    or None when PADDLE_TPU_COMPILE_CACHE switches caching off."""
+    if _switched_off():
+        return None
+    return os.environ.get(XLA_ENV_VAR) or \
+        os.path.join(_CHECKOUT, '.jax_cache')
+
+
+def setup_xla_cache():
+    """Place jax's persistent compilation cache: the one function
+    every entry point and compile choke point goes through.  With
+    JAX_COMPILATION_CACHE_DIR set, jax has read it itself and no
+    directory is set here; unset, the cache is <checkout>/.jax_cache.
+    The persistence thresholds are the same either way (every module
+    is kept, however quick or small).  Returns xla_cache_dir()."""
+    global _xla_applied
+    want = xla_cache_dir()
+    if want == _xla_applied:
+        return want
+    import jax
+    if want is None:
+        jax.config.update('jax_enable_compilation_cache', False)
     else:
-        d = os.path.abspath(os.path.expanduser(raw))
-    _wire_xla_cache(d)
-    return d
-
-
-def _unwire_xla_cache():
-    """Disabling the cache must also release jax's XLA cache IF we set
-    it — otherwise a formerly-enabled dir (e.g. a test fixture's
-    deleted tmpdir) stays latched for the process lifetime."""
-    global _xla_wired_dir, _xla_set_value
-    if _xla_set_value is None:
-        return
-    _xla_wired_dir = None
-    value, _xla_set_value = _xla_set_value, None
-    try:
-        import sys
-        if 'jax' not in sys.modules:
-            return
-        import jax
-        if getattr(jax.config, 'jax_compilation_cache_dir',
-                   None) == value:
-            jax.config.update('jax_compilation_cache_dir', None)
-    except Exception:       # pragma: no cover - defensive
-        pass
-
-
-def _wire_xla_cache(d):
-    """Point jax's own persistent compilation cache under ours: the
-    exec tier removes trace+lower, this removes the XLA backend
-    compile — together a warm start deserializes instead of compiling.
-    A user-configured JAX_COMPILATION_CACHE_DIR (tools/_env) or a
-    config value we did not set ourselves wins; a cache-dir change
-    WE own (per-test tmpdirs, in-process reconfiguration) re-wires so
-    the two tiers can never silently diverge."""
-    global _xla_wired_dir, _xla_set_value
-    if d == _xla_wired_dir:
-        return
-    _xla_wired_dir = d
-    try:
-        import jax
-        if os.environ.get('JAX_COMPILATION_CACHE_DIR'):
-            return
-        current = getattr(jax.config, 'jax_compilation_cache_dir', None)
-        if current and current != _xla_set_value:
-            return      # someone else configured it — theirs wins
-        _xla_set_value = os.path.join(d, 'xla')
-        jax.config.update('jax_compilation_cache_dir', _xla_set_value)
+        if _xla_applied is None:
+            # switched back on in-process (tests): undo our own off
+            jax.config.update('jax_enable_compilation_cache', True)
+        if not os.environ.get(XLA_ENV_VAR):
+            jax.config.update('jax_compilation_cache_dir', want)
         jax.config.update('jax_persistent_cache_min_compile_time_secs',
                           0.0)
-        try:
-            jax.config.update('jax_persistent_cache_min_entry_size_bytes',
-                              -1)
-        except Exception:
-            pass
-        try:
-            # jax latches its cache-enabled decision at the FIRST
-            # compile; an eager op before this ran would have latched
-            # "no cache" — reset so the next compile re-reads config
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _jcc)
-            _jcc.reset_cache()
-        except Exception:
-            pass
-    except Exception:       # cache plumbing must never break a run
-        pass
+        jax.config.update('jax_persistent_cache_min_entry_size_bytes',
+                          -1)
+    # jax latches its cache decision at the FIRST compile; an eager op
+    # before this ran would have latched "no cache" — reset so the
+    # next compile re-reads the config
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _jcc)
+    _jcc.reset_cache()
+    _xla_applied = want
+    return want
 
 
 # -- stats / telemetry --------------------------------------------------------

@@ -343,6 +343,6 @@ class ChunkPrefetcher:
                 pass
             # bounded join: the producer's put-poll loop re-checks
             # _closed every 0.1s, so it exits within one poll tick —
-            # the timeout only guards against a stage_fn wedged on a
+            # the timeout only guards against a stage_fn hung on a
             # device transfer
             self._thread.join(timeout=2.0)

@@ -9,12 +9,12 @@ paddle.distributed.fleet.elastic.
 
 TPU-native: one worker process drives all of a host's chips, so the
 supervisor watches ONE child per host (more are supported for API
-parity).  A dead or wedged worker is restarted up to `max_restarts`
+parity).  A dead or hung worker is restarted up to `max_restarts`
 times with `PADDLE_ELASTIC_RESTART_COUNT` exported, and the training
 loop resumes from the last auto-checkpoint
 (incubate.checkpoint.auto_checkpoint) — together they give the
 kill-a-worker-mid-training recovery the reference's pod watcher
-provides.  Wedge detection is a heartbeat FILE (the worker's
+provides.  Hang detection is a heartbeat FILE (the worker's
 auto-checkpoint saves touch it): a stale mtime beyond
 `heartbeat_timeout` kills and restarts the worker, mirroring the
 reference watchdog's hung-trainer path.
@@ -33,7 +33,7 @@ __all__ = ['TrainerProc', 'start_local_trainers',
            'DEADLINE_EXIT_CODE']
 
 # returned by watch_local_trainers when its `deadline` expires before
-# the workers finish: the supervised run wedged (the timeout(1)
+# the workers finish: the supervised run hung (the timeout(1)
 # convention code, so shell drivers read it naturally)
 DEADLINE_EXIT_CODE = 124
 
@@ -213,7 +213,7 @@ def watch_local_trainers(procs, max_restarts=3, poll=0.2,
                          restart_backoff_max=30.0, deadline=None,
                          reshape_dir=None):
     """The pod watch loop: poll workers, restart the dead, kill the
-    wedged (stale or deleted heartbeat), stop everything when one
+    hung (stale or deleted heartbeat), stop everything when one
     fails beyond `max_restarts`.
 
     Returns 0 when every worker exited cleanly; the failing worker's
@@ -250,7 +250,7 @@ def watch_local_trainers(procs, max_restarts=3, poll=0.2,
     `deadline` bounds the WHOLE supervision in wall-clock seconds: a
     cluster that neither completes nor fails within it is torn down
     and the loop returns DEADLINE_EXIT_CODE (124) — chaos soaks use
-    this as invariant I7 (complete or die loudly, never wedge a
+    this as invariant I7 (complete or die loudly, never hang a
     reservation).  A worker exiting resilience.watchdog's
     WATCHDOG_EXIT_CODE (a self-detected hang) is restarted as a
     normal FAILURE (it consumes the max_restarts budget — a
@@ -274,7 +274,7 @@ def watch_local_trainers(procs, max_restarts=3, poll=0.2,
             'detection')
     if heartbeat_file:
         # seed the heartbeat at supervision start: a worker that
-        # wedges BEFORE its first checkpoint touch must still trip
+        # hangs BEFORE its first checkpoint touch must still trip
         # the stale-mtime detector
         _seed_heartbeat(heartbeat_file)
     reshape_seq = 0     # act once per NEW request seq
@@ -298,7 +298,7 @@ def watch_local_trainers(procs, max_restarts=3, poll=0.2,
                 return PREEMPTED_EXIT_CODE
             if watch_deadline is not None and \
                     time.monotonic() > watch_deadline:
-                # the I7 backstop: a wedged cluster is torn down and
+                # the I7 backstop: a hung cluster is torn down and
                 # reported as a deadline breach, never left running
                 terminate_local_procs(procs, grace=3.0)
                 return DEADLINE_EXIT_CODE

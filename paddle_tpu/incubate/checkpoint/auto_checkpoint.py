@@ -65,7 +65,7 @@ def configure(checkpoint_dir=None, model=None, optimizer=None,
     """Register what a snapshot contains.  `model`/`optimizer` may be
     single objects or lists; both expose state_dict/set_state_dict.
     `heartbeat_file` is touched at every save so an elastic supervisor
-    can detect a wedged trainer.  With `graceful_shutdown` (default) a
+    can detect a hung trainer.  With `graceful_shutdown` (default) a
     SIGTERM/SIGINT during a train range saves one final synchronous
     snapshot at the next step boundary and exits with
     resilience.PREEMPTED_EXIT_CODE — which distributed.elastic

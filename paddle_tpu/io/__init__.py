@@ -562,7 +562,7 @@ class DataLoader:
             stop.set()
             # workers poll `stop` on every queue op, so they exit
             # within one 0.1s tick; the timeout only guards a
-            # __getitem__ wedged mid-fetch
+            # __getitem__ hung mid-fetch
             for t in threads:
                 t.join(timeout=2.0)
 
@@ -896,7 +896,7 @@ class DataLoader:
             except queue.Empty:
                 pass
             # producer's put-poll re-checks `closed` every 0.1s; the
-            # timeout only guards a device_put wedged mid-transfer
+            # timeout only guards a device_put hung mid-transfer
             t.join(timeout=2.0)
 
     def _telemetry_iter(self, inner):

@@ -49,6 +49,7 @@ def _fwd_pallas(x2d, gamma, beta, eps, block_rows):
     kernel = functools.partial(_fwd_kernel, eps=eps)
     y, mean, rstd = pl.pallas_call(
         kernel,
+        name='layer_norm_fwd',
         interpret=_gating.INTERPRET,
         grid=grid,
         in_specs=[
@@ -108,7 +109,7 @@ def fused_layer_norm(x, gamma=None, beta=None, eps=1e-5,
     for s in lead:
         n *= s
     from ._gating import pallas_backend_ok, pick_block_rows
-    br = pick_block_rows(n, block_rows)
+    br = pick_block_rows(n, block_rows, h)
     if not (pallas_backend_ok() and gamma is not None
             and beta is not None and h % 128 == 0 and br):
         return _reference(x, gamma, beta, eps)

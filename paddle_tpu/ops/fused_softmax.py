@@ -43,6 +43,7 @@ def _fwd_pallas(x2d, mask2d, block_rows):
     if mask2d is None:
         return pl.pallas_call(
             _kernel,
+            name='softmax_fwd',
             interpret=_gating.INTERPRET,
             grid=grid,
             in_specs=[pl.BlockSpec((block_rows, h), lambda i: (i, 0))],
@@ -51,6 +52,7 @@ def _fwd_pallas(x2d, mask2d, block_rows):
         )(x2d)
     return pl.pallas_call(
         _masked_kernel,
+        name='masked_softmax_fwd',
         interpret=_gating.INTERPRET,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, h), lambda i: (i, 0)),
@@ -85,13 +87,14 @@ _sm.defvjp(_sm_fwd, _sm_bwd)
 
 def fused_softmax(x, mask=None, block_rows=_BLOCK_ROWS):
     """Softmax over the last axis (+ optional additive mask);
-    Pallas-fused on TPU, jnp fallback elsewhere."""
+    Pallas-fused on TPU, jnp fallback elsewhere and for rows too long
+    for VMEM (the 50k-vocabulary softmax)."""
     h = x.shape[-1]
     n = 1
     for s in x.shape[:-1]:
         n *= s
     from ._gating import pallas_backend_ok, pick_block_rows
-    br = pick_block_rows(n, block_rows)
+    br = pick_block_rows(n, block_rows, h)
     if not (pallas_backend_ok() and h % 128 == 0 and br):
         return _reference(x, mask)
     m2d = None

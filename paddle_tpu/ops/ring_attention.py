@@ -16,7 +16,7 @@ import functools
 import math
 
 import jax
-from ..core.jaxcompat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 

@@ -638,7 +638,7 @@ class ParallelTrainer:
         message.  Returns ``fn(params, buffers, step_no, key, batch)
         -> (loss, grads, new_buffers)`` with grads already mean-
         reduced (replicated), drop-in for the implicit-psum path."""
-        from ..core.jaxcompat import shard_map
+        from jax import shard_map
         from . import quant_collectives as _qc
         mesh = self.mesh
         dp_n = dict(mesh.shape)['dp']
@@ -812,15 +812,16 @@ class ParallelTrainer:
             + tuple(self._example_vals)
 
     def _maybe_persistent_cache(self):
-        """Swap the freshly-built jitted step for a deserialized
-        executable when the persistent cache holds this exact program
-        (same jaxpr, shardings, donation, mesh, jax, code); on a miss,
-        export the cold step so the NEXT process (elastic restart,
-        reshape restore, second worker) deserializes instead of
-        recompiling.  A hit forgoes donation (jax.export artifacts do
-        not donate) — correctness is identical, peak HBM grows by one
-        params+opt generation; set PADDLE_TPU_COMPILE_CACHE=0 to keep
-        strict donation.  Never raises."""
+        """With the exec tier on (PADDLE_TPU_COMPILE_CACHE names a
+        directory): swap the freshly-built jitted step for a
+        deserialized executable when the cache holds this exact
+        program (same jaxpr, shardings, donation, mesh, jax, code); on
+        a miss, export the cold step so the NEXT process (elastic
+        restart, reshape restore, second worker) deserializes instead
+        of recompiling.  A hit forgoes donation (jax.export artifacts
+        do not donate) — correctness is identical, peak HBM grows by
+        one params+opt generation, which is why the tier is opt-in and
+        the default warm start is jax's own cache.  Never raises."""
         from ..core import compile_cache as _cc
         self._cc_fp = None
         if not _cc.enabled():

@@ -99,7 +99,7 @@ class LocalSGDTrainer:
         batch_spec = P(dp_axis)
 
         def step(params, buffers, state, step_no, key, *batch):
-            from ..core.jaxcompat import shard_map
+            from jax import shard_map
             return shard_map(
                 local_step, mesh=self.mesh,
                 in_specs=(spec_p, spec_b, spec_s, P(), P())
@@ -132,7 +132,7 @@ class LocalSGDTrainer:
                 return jax.tree_util.tree_map(lambda a: a[None], avg)
 
             def sync(params, step_no):
-                from ..core.jaxcompat import shard_map
+                from jax import shard_map
                 return shard_map(
                     sync_body, mesh=self.mesh,
                     in_specs=(spec_p, P()), out_specs=spec_p,

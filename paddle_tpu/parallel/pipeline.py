@@ -98,7 +98,7 @@ def gpipe_spmd(stacked_params, x, stage_fn, mesh, num_microbatches,
         out = gpipe(params, xmb, stage_fn=stage_fn, axis_name=pp_axis)
         return out[None]  # per-stage leading dim; only stage S-1 is real
 
-    from ..core.jaxcompat import shard_map
+    from jax import shard_map
     out = shard_map(
         run, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: p_spec,

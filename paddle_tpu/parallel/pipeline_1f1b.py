@@ -251,7 +251,7 @@ def pipeline_value_and_grad(shared, stages, ids_mb, labels_mb, *, mesh,
     out_specs = (repl, shared_specs, out_stage_specs)
     if with_finite:
         out_specs = out_specs + (repl,)
-    from ..core.jaxcompat import shard_map
+    from jax import shard_map
     out = shard_map(
         worker, mesh=mesh,
         in_specs=(shared_specs, stage_specs, mb_spec, mb_spec),

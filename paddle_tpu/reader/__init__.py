@@ -175,7 +175,7 @@ def buffered(reader, size):
             stop.set()
             # bounded join — the producer's put-poll re-checks `stop`
             # every 0.1s; the timeout only guards a source reader
-            # wedged mid-next()
+            # hung mid-next()
             t.join(timeout=2.0)
 
     return buffered_reader

@@ -28,7 +28,7 @@ attributed, restartable event:
   rank as ONE failure restart — never a deadlock.  The exit is
   ``os._exit``: the main thread is by definition stuck (possibly
   inside XLA, uninterruptible), and a watchdog that politely raises in
-  its own thread un-wedges nothing.
+  its own thread frees nothing.
 * **collective_budget** — a thread-local deadline scope the host
   transport (and anything else doing bounded cluster waits) arms;
   ``resilience.retry(deadline=)`` clamps to the remaining budget so a
@@ -568,7 +568,7 @@ class Watchdog:
         """Grace, then hard exit.  The grace window lets a main thread
         that was stuck in a HOST collective observe the abort flag and
         exit cooperatively (also WATCHDOG_EXIT_CODE, via the worker's
-        abort handler); a thread wedged inside XLA or a dead fs gets
+        abort handler); a thread hung inside XLA or a dead fs gets
         os._exit — the only call guaranteed to free the rank so the
         elastic supervisor can respawn it."""
         time.sleep(self.budget.grace_s)

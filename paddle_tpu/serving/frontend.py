@@ -11,7 +11,8 @@ unless a caller constructs one):
 * ``POST /v1/cancel/<rid>``  — evict an in-flight request
 * ``POST /admin/drain``      — stop admitting (typed 503s), finish
   in-flight; the router's replica-swap lever
-* ``GET  /healthz``          — liveness + draining flag
+* ``GET  /healthz``          — liveness + draining flag; 503 and
+  ``ok: false`` once the engine thread has failed
 * ``GET  /status.json``      — live occupancy/queue-depth snapshot
   (what the router's dispatch reads)
 
@@ -35,7 +36,11 @@ an Event) and read request progress through ``Request.tokens`` —
 CPython list appends are atomic, and the reader only indexes below
 ``len``, so streaming never takes the engine's locks and a slow
 client never stalls decode (tokens buffer host-side; TCP backpressure
-stays in the handler thread).
+stays in the handler thread).  If the engine thread raises (a decode
+module that fails to compile on the chip, a device OOM) it does not
+die silently: every in-flight request is terminalized as evicted with
+reason ``engine_failed``, the door drains, and ``/healthz`` turns
+not-ok with the error — no client is left waiting on a dead loop.
 """
 import json
 import queue
@@ -100,6 +105,7 @@ class ServingFrontend:
                           if max_queue is None else int(max_queue))
         self.poll_s = float(poll_s)
         self.draining = False
+        self.engine_error = None     # repr of what killed the engine
         self.shed_counts = {r: 0 for r in RejectReason.ALL}
         # alerts forced through POST /admin/alert/<kind> — the chaos
         # drill's deterministic stand-in for a latched monitor (the
@@ -167,42 +173,91 @@ class ServingFrontend:
         """The ONLY thread that mutates the engine: drain control
         ops, run one intervention, repeat.  Mirrors ``engine.run()``'s
         drain loop but never exits on an empty schedule — the door
-        stays open until stop()."""
+        stays open until stop().  An exception out of the engine ends
+        the loop through ``_engine_failed``."""
+        try:
+            while not self._stop.is_set():
+                self._engine_turn()
+        except Exception as e:
+            import traceback
+            traceback.print_exc()
+            self._engine_failed(e)
+
+    def _engine_turn(self):
         eng = self.engine
         sched = eng.scheduler
-        while not self._stop.is_set():
-            ran_op = False
-            while True:
-                try:
-                    op = self._ops.get_nowait()
-                except queue.Empty:
-                    break
-                ran_op = True
-                try:
-                    if op.kind == 'submit':
-                        try:
-                            op.finish(eng.submit(**op.kw))
-                        finally:
-                            with self._lock:
-                                self._pending_submits -= 1
-                    elif op.kind == 'cancel':
-                        op.finish(eng.cancel(**op.kw))
-                    else:
-                        op.finish(error=ValueError(op.kind))
-                except Exception as e:      # pragma: no cover - relay
-                    op.finish(error=e)
-            if not sched.queue and not sched.running:
-                if not ran_op:
-                    time.sleep(self.poll_s)
-                continue
-            if eng.step() == 0 and not sched.running and sched.queue:
-                # the head of the queue can never be admitted even
-                # into an empty pool (engine.run()'s livelock guard —
-                # preflight makes this near-unreachable, but a guard
-                # that spins forever is worse than one that evicts)
-                req = sched.queue.popleft()
-                sched.finish(req, 'oom')
-                eng._note_finished([req], eng._clock())
+        ran_op = False
+        while True:
+            try:
+                op = self._ops.get_nowait()
+            except queue.Empty:
+                break
+            ran_op = True
+            try:
+                if op.kind == 'submit':
+                    try:
+                        op.finish(eng.submit(**op.kw))
+                    finally:
+                        with self._lock:
+                            self._pending_submits -= 1
+                elif op.kind == 'cancel':
+                    op.finish(eng.cancel(**op.kw))
+                else:
+                    op.finish(error=ValueError(op.kind))
+            except Exception as e:      # pragma: no cover - relay
+                op.finish(error=e)
+        if not sched.queue and not sched.running:
+            if not ran_op:
+                time.sleep(self.poll_s)
+            return
+        if eng.step() == 0 and not sched.running and sched.queue:
+            # the head of the queue can never be admitted even
+            # into an empty pool (engine.run()'s livelock guard —
+            # preflight makes this near-unreachable, but a guard
+            # that spins forever is worse than one that evicts)
+            req = sched.queue.popleft()
+            sched.finish(req, 'oom')
+            eng._note_finished([req], eng._clock())
+
+    def _engine_failed(self, exc):
+        """The engine thread is gone: close the door behind it.  New
+        work sheds 503 ``draining``, queued control ops get the same
+        typed refusal, and every request still in flight reaches a
+        terminal state."""
+        from .. import telemetry
+        self.engine_error = repr(exc)[:500]
+        self.draining = True
+        self._refuse_pending_ops()
+        eng = self.engine
+        sched = eng.scheduler
+        failed = list(sched.running) + list(sched.queue)
+        sched.queue.clear()
+        for req in failed:
+            sched.finish(req, 'engine_failed')
+        eng._note_finished(failed, eng._clock())
+        telemetry.event('fleet_event', action='engine_failed',
+                        error=self.engine_error, failed=len(failed))
+
+    def _refuse_pending_ops(self):
+        while True:
+            try:
+                op = self._ops.get_nowait()
+            except queue.Empty:
+                return
+            if op.kind == 'submit':
+                with self._lock:
+                    self._pending_submits -= 1
+            op.finish(error=RejectedRequest(
+                RejectReason.DRAINING,
+                f'engine thread failed: {self.engine_error}',
+                rid=op.kw.get('rid')))
+
+    def _enqueue(self, op):
+        self._ops.put(op)
+        if self.engine_error is not None:
+            # the loop that would have acked it is gone (it may have
+            # died between the caller's draining check and this put)
+            self._refuse_pending_ops()
 
     # -- admission (HTTP threads) --------------------------------------------
     def submit(self, prompt, max_new_tokens, rid=None,
@@ -214,7 +269,8 @@ class ServingFrontend:
         from .. import telemetry
         if self.draining:
             self._shed(RejectReason.DRAINING, rid,
-                       'front door is draining')
+                       'front door is draining' if not self.engine_error
+                       else f'engine thread failed: {self.engine_error}')
         with self._lock:
             depth = (len(self.engine.scheduler.queue)
                      + self._pending_submits)
@@ -230,7 +286,7 @@ class ServingFrontend:
         op = _Op('submit', prompt=np.asarray(prompt, np.int64),
                  max_new_tokens=int(max_new_tokens), rid=rid,
                  deadline_s=deadline_s)
-        self._ops.put(op)
+        self._enqueue(op)
         try:
             req = op.wait(timeout_s=30.0)
         except RejectedRequest as e:
@@ -253,7 +309,7 @@ class ServingFrontend:
         """Evict an in-flight request from any thread (handler path
         for /v1/cancel and for detected client disconnects)."""
         op = _Op('cancel', rid=rid, cause=cause)
-        self._ops.put(op)
+        self._enqueue(op)
         try:
             return bool(op.wait(timeout_s=30.0))
         except TimeoutError:
@@ -322,7 +378,8 @@ class ServingFrontend:
         total = eng.cache.num_blocks
         free = eng.cache.free_blocks
         return {
-            'ok': True,
+            'ok': self.engine_error is None,
+            'engine_error': self.engine_error,
             'draining': bool(self.draining),
             'uptime_s': round(time.monotonic() - self.started_t, 3),
             'queue_depth': len(sched.queue),
@@ -387,8 +444,10 @@ class _Handler(BaseHTTPRequestHandler):
         path = self.path.split('?', 1)[0].rstrip('/') or '/'
         try:
             if path == '/healthz':
-                self._send_json(200, {
-                    'ok': True, 'draining': bool(fe.draining),
+                ok = fe.engine_error is None
+                self._send_json(200 if ok else 503, {
+                    'ok': ok, 'draining': bool(fe.draining),
+                    'engine_error': fe.engine_error,
                     'uptime_s': round(
                         time.monotonic() - fe.started_t, 3)})
             elif path == '/status.json':

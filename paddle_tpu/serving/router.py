@@ -4,10 +4,11 @@ door, with proven failure semantics.
 Three pieces:
 
 * :class:`ReplicaHandle` — one engine replica: either a spawned
-  ``tools/serve_fleet.py worker`` subprocess (the ChaosCluster
-  posture: own process, env-configured, port published through a
-  file) or an attached already-running frontend URL (in-process
-  tests).  Thin HTTP client helpers over the replica's front door.
+  ``tools/serve_fleet.py worker`` subprocess (own process, the
+  launcher's environment and therefore its JAX platform, port
+  published through a file) or an attached already-running frontend
+  URL (in-process tests).  Thin HTTP client helpers over the
+  replica's front door.
 
 * :class:`FleetRouter` — the dispatch + supervision brain:
 
@@ -87,9 +88,14 @@ class ReplicaHandle:
     @classmethod
     def spawn(cls, name, config_path, workdir, host='127.0.0.1',
               warmup=False, extra_env=None):
-        """Start one ``tools/serve_fleet.py worker`` subprocess (the
-        ChaosCluster env posture: CPU backend, repo on PYTHONPATH,
-        port published through a file once the door is open)."""
+        """Start one ``tools/serve_fleet.py worker`` subprocess: repo
+        on PYTHONPATH, port published through a file once the door is
+        open.  The worker INHERITS its JAX platform from the launcher
+        (nothing is written here), so a fleet started on a TPU host
+        serves from the chip; a chip belongs to one process, so there
+        the launcher must not initialise a backend itself and runs one
+        replica per chip.  Tests get the CPU from conftest's
+        environment."""
         os.makedirs(workdir, exist_ok=True)
         port_file = os.path.join(workdir, f'{name}.port')
         log = open(os.path.join(workdir, f'{name}.log'), 'ab')
@@ -100,20 +106,18 @@ class ReplicaHandle:
         if warmup:
             cmd.append('--warmup')
         env = dict(os.environ)
-        env.update({
-            'JAX_PLATFORMS': 'cpu',
-            'PYTHONPATH': _REPO + os.pathsep
-            + env.get('PYTHONPATH', ''),
-        })
+        env['PYTHONPATH'] = _REPO + os.pathsep + env.get('PYTHONPATH', '')
         env.update(extra_env or {})
         proc = subprocess.Popen(cmd, env=env, stdout=log, stderr=log,
                                 start_new_session=True)
         log.close()
         return cls(name, host=host, proc=proc, port_file=port_file)
 
-    def wait_ready(self, timeout_s=120.0):
+    def wait_ready(self, timeout_s=900.0):
         """Block until the worker published its port and /healthz
-        answers; raises on worker death or timeout."""
+        answers ok; raises on worker death or timeout.  The allowance
+        covers a cold warm-up of every bucket module at full width on
+        the chip; a worker that dies is noticed at once."""
         deadline = time.monotonic() + timeout_s
         while time.monotonic() < deadline:
             if self.proc is not None and self.proc.poll() is not None:
@@ -418,7 +422,7 @@ class FleetRouter:
                 tried_dead.add(rep.name)
                 if not rep.alive() or rep.proc is not None:
                     # a stream that died on a live process means the
-                    # process is wedged (hang) — kill it so its KV
+                    # process is hung — kill it so its KV
                     # blocks and port free up before the retry lands
                     if rep.alive():
                         rep.kill()

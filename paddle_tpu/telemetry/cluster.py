@@ -31,7 +31,7 @@ module is the training-side sensor substrate (ROADMAP item 3):
     compute/collective split;
   - a cross-rank **loss-divergence** digest (relative spread of the
     per-rank loss windows);
-  - **degraded-view semantics**: a dead or wedged rank's frame goes
+  - **degraded-view semantics**: a dead or hung rank's frame goes
     stale and is *marked* stale (age, last step, heartbeat age) —
     the view degrades, it never crashes.  Chaos-validated by
     ``bench.py --cluster-obs-smoke`` (SIGKILL mid-run).
@@ -338,7 +338,7 @@ def attribute_straggler(per_rank, skew_threshold=1.75,
     * ``behind`` — a rank's last published step trails the cluster
       max by ``behind_threshold`` steps or more;
     * ``stale`` — a rank stopped publishing (frame stale / missing)
-      while peers progressed: dead or wedged."""
+      while peers progressed: dead or hung."""
     if not per_rank:
         return None
 

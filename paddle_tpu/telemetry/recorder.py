@@ -121,7 +121,8 @@ EVENT_KINDS = (
                            # RejectReason is the one source of truth)
     'fleet_event',         # one serving-fleet control action
                            # (action: dispatch/retry/drain/promote/
-                           # replica_down/replica_up, replica, rid) —
+                           # replica_down/replica_up/engine_failed,
+                           # replica, rid) —
                            # serving/router.py's control-plane trail,
                            # joinable with serve_request by rid
     'slo_breach',          # a rolling SLO monitor tripped (what:

@@ -47,7 +47,7 @@ def check_numerics(tree, name='tensors'):
 class Watchdog:
     """Fires `on_stall` if `beat()` is not called within `timeout_s`.
 
-    Use around training loops: a hung collective, a wedged input
+    Use around training loops: a hung collective, a hung input
     pipeline or a dead worker surfaces as a stall instead of silence.
     """
 

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle
 from paddle_tpu import nn
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from paddle_tpu.distributed import env as dist_env
 from paddle_tpu.parallel import (ParallelTrainer, LocalSGDTrainer,
                                  QuantCollectiveConfig,

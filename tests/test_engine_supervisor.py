@@ -533,36 +533,6 @@ class TestPlangenSupervisorClass:
         assert plangen.plan_fingerprint(plan) == g['fingerprint']
 
 
-# ----------------------------------------- bench preflight classes ----------
-class TestPreflightReasonClasses:
-    @staticmethod
-    def _bench():
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            'bench', os.path.join(_REPO, 'bench.py'))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_classify(self):
-        bench = self._bench()
-        assert bench._classify_preflight_reason(
-            'timeout after 120s') == 'timeout'
-        assert bench._classify_preflight_reason(
-            'RuntimeError: Unable to initialize backend') \
-            == 'device_unavailable'
-        assert bench._classify_preflight_reason(
-            'failed to connect to coordinator') == 'device_unavailable'
-        assert bench._classify_preflight_reason(
-            'exit code -11') == 'crash'
-        for cls in ('timeout', 'device_unavailable', 'crash'):
-            assert cls in bench._PREFLIGHT_RETRY_WAIT_S
-        # backoff ordering: infra warmup waits longest, a crash-looping
-        # binary retries fastest
-        w = bench._PREFLIGHT_RETRY_WAIT_S
-        assert w['timeout'] > w['device_unavailable'] > w['crash']
-
-
 # ---------------------------------------- elastic coordinated reshape -------
 class TestCoordinatedReshape:
     def test_request_reshape_restarts_all_without_budget_burn(

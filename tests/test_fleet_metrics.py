@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 import paddle_tpu as paddle
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from paddle_tpu.distributed import fleet
 from paddle_tpu.distributed.fleet import metrics as FM
 from paddle_tpu.metric import Auc

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
 
 

@@ -8,7 +8,7 @@ import numpy as np
 import pytest  # noqa: F401
 
 import paddle_tpu as paddle
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 from paddle_tpu import nn
 from paddle_tpu.incubate import HostOffloadEmbedding
 

@@ -252,8 +252,9 @@ class TestFusedLinearGelu:
 
 
 class TestFlashAutotuneTable:
-    """Per-shape block tuning table (tools/tune_flash.py populates it on
-    the real chip; here: lookup/override semantics)."""
+    """Per-shape block tuning table (a literal in the module;
+    tools/tune_flash.py measures candidates on the chip): lookup and
+    override semantics."""
 
     def test_default_when_untupled(self):
         import importlib
@@ -276,6 +277,5 @@ class TestFlashAutotuneTable:
         autotune returns the defaults without touching the table."""
         import importlib
         fa = importlib.import_module('paddle_tpu.ops.flash_attention')
-        best, ms = fa.autotune_blocks(256, 256, 64, bh=1, iters=1,
-                                      persist=False)
+        best, ms = fa.autotune_blocks(256, 256, 64, bh=1, iters=1)
         assert best == (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)

@@ -29,9 +29,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from tools._env import setup_jax_cache
-setup_jax_cache()
-
 
 def main():
     ap = argparse.ArgumentParser()
@@ -45,6 +42,8 @@ def main():
         args.vocab, args.tokens, args.iters = 1024, 512, 3
 
     import jax
+    from paddle_tpu.core.compile_cache import setup_xla_cache
+    setup_xla_cache()
     import jax.numpy as jnp
     from jax import lax
 

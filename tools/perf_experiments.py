@@ -20,9 +20,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tools._env import setup_jax_cache
-setup_jax_cache()
-
 
 def run(tag, batch=256, image=224, recompute=False, bf16_in=False,
         s2d=False, iters=30, warmup=5):
@@ -72,6 +69,8 @@ def run(tag, batch=256, image=224, recompute=False, bf16_in=False,
 
 def main():
     import jax
+    from paddle_tpu.core.compile_cache import setup_xla_cache
+    setup_xla_cache()
     print('device:', jax.devices()[0], flush=True)
     results = {}
     results['base'] = run('base')

@@ -306,8 +306,9 @@ def main(argv=None):
 
     from paddle_tpu.core import compile_cache as _cc
     if not _cc.enabled():
-        print('precompile: the persistent compile cache is disabled '
-              f'({_cc.ENV_VAR}); nothing to do', file=sys.stderr)
+        print('precompile: the exec/text tiers are off; name a '
+              f'directory with --cache or {_cc.ENV_VAR}',
+              file=sys.stderr)
         return 2
 
     target_names = [] if args.targets.strip().lower() == 'none' else \

@@ -24,9 +24,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tools._env import setup_jax_cache
-setup_jax_cache()
-
 
 def log(*a):
     print(*a, file=sys.stderr, flush=True)
@@ -41,11 +38,13 @@ def main():
                    help='capture a trace window around the timed loop '
                         'and stream telemetry JSONL to --out')
     p.add_argument('--out', default=os.path.join(
-        'tools', 'chip_out', 'profile_resnet'),
+        'chiprun_out', 'profile_resnet'),
         help='telemetry/trace output dir for --emit-telemetry')
     args = p.parse_args()
 
     import jax
+    from paddle_tpu.core.compile_cache import setup_xla_cache
+    setup_xla_cache()
     import jax.numpy as jnp
     import paddle_tpu as paddle
     from paddle_tpu import nn, telemetry

@@ -2,9 +2,9 @@
 """profile_run — capture → parse → emit → (optionally) fit, in one
 shot: the self-profiling loop's end-to-end driver.
 
-Runs a built-in data-parallel workload on whatever accelerator is
-present (a dp=8 virtual CPU mesh by default — no chip needed), with
-the sampled profiler (``telemetry.profile``) capturing a trace window
+Runs a built-in data-parallel workload on whatever devices jax finds
+(with JAX_PLATFORMS=cpu: a dp=8 virtual CPU mesh, no chip needed),
+with the sampled profiler (``telemetry.profile``) capturing a trace window
 mid-training.  Profiled collectives are census-matched against the
 compiled module and land as real ``collective_observed`` telemetry —
 **zero hand-written fixtures** — which:
@@ -19,7 +19,7 @@ That closes the loop the PR-4/6 cost model opened: predict (planner)
 → measure (this driver) → re-calibrate (the fitted table) → predict
 better.
 
-    python tools/profile_run.py                        # CPU mesh, report
+    JAX_PLATFORMS=cpu python tools/profile_run.py      # CPU mesh, report
     python tools/profile_run.py --fit calibration.json # + fit the table
     python tools/profile_run.py --json                 # run_report schema
     python tools/profile_run.py --model lenet --dp 8 --steps 16
@@ -85,12 +85,12 @@ def parse_args(argv=None):
 
 
 def _force_virtual_mesh(dp):
-    """A dp>1 run on a single-device CPU backend gets XLA's virtual
-    host devices — set BEFORE jax imports (bench/tpu_lint posture)."""
-    plat = os.environ.get('JAX_PLATFORMS', '')
-    if plat not in ('', 'cpu'):
-        return          # a real multi-device backend is configured
-    os.environ['JAX_PLATFORMS'] = 'cpu'
+    """A dp>1 run that was ASKED onto the CPU (JAX_PLATFORMS=cpu) gets
+    XLA's virtual host devices — set BEFORE jax imports.  An unset
+    variable is what a plain TPU install looks like: there the run
+    keeps the real devices."""
+    if os.environ.get('JAX_PLATFORMS') != 'cpu':
+        return
     flags = os.environ.get('XLA_FLAGS', '')
     if '--xla_force_host_platform_device_count' not in flags:
         os.environ['XLA_FLAGS'] = (

@@ -12,10 +12,14 @@ door (paddle_tpu/serving/router.py).
 
 CONFIG is the same JSON ``tools/precompile.py --serve`` reads:
 ServeConfig fields plus ``"model"`` ('tiny' | 'small') and
-``"model_kwargs"``.  Workers run on the CPU backend with the repo on
-PYTHONPATH (the ChaosCluster env posture); each publishes
-``{"port": ..., "pid": ...}`` through its --port-file once
-``/healthz`` answers, which is the router's readiness handshake.
+``"model_kwargs"``.  Workers run with the repo on PYTHONPATH and
+INHERIT their JAX platform from this launcher, which sets none and
+initialises no backend itself: on a TPU machine the workers serve
+from the chip.  A chip belongs to one process, so there the fleet is
+one replica per chip (``--replicas 1`` on a one-chip machine).  Each
+worker publishes ``{"port": ..., "pid": ...}`` through its
+--port-file once ``/healthz`` answers, which is the router's
+readiness handshake.
 
 ``up`` binds the door to 127.0.0.1 by default — same posture as the
 single-engine frontend; set PADDLE_TPU_FRONTEND_HOST to widen.

@@ -16,9 +16,6 @@ import os
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from tools._env import setup_jax_cache
-setup_jax_cache()
-
 
 def main():
     ap = argparse.ArgumentParser()
@@ -29,6 +26,8 @@ def main():
     ap.add_argument('--no-causal', action='store_true')
     args = ap.parse_args()
 
+    from paddle_tpu.core.compile_cache import setup_xla_cache
+    setup_xla_cache()
     from paddle_tpu.ops.flash_attention import autotune_blocks
 
     if args.tq:
