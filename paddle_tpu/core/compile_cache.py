@@ -151,6 +151,16 @@ def setup_xla_cache():
                           0.0)
         jax.config.update('jax_persistent_cache_min_entry_size_bytes',
                           -1)
+        # jax's key leaves an instruction's metadata out by default, so
+        # a module that differs from a cached one only by a
+        # jax.named_scope is served the OLD executable and its op_name
+        # (what a profile's ops are attributed by) never shows: the key
+        # sees metadata, with source paths relative to the checkout so
+        # that another checkout of the same code still hits
+        jax.config.update(
+            'jax_compilation_cache_include_metadata_in_key', True)
+        jax.config.update('jax_hlo_source_file_canonicalization_regex',
+                          re.escape(_CHECKOUT + os.sep))
     # jax latches its cache decision at the FIRST compile; an eager op
     # before this ran would have latched "no cache" — reset so the
     # next compile re-reads the config

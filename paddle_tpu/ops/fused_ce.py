@@ -69,6 +69,7 @@ def _chunk_w(w, num_chunks):
     return w.reshape(H, num_chunks, Vc).transpose(1, 0, 2), Vc, pad
 
 
+@jax.named_scope('fused_ce.fwd')
 def _scan_core(x, w, labels, num_chunks, col0, axis=None):
     """Online logsumexp over w's columns (one shard's slice of the
     full vocab, starting at GLOBAL column col0).  Returns (m, s, zl):
@@ -112,6 +113,7 @@ def _scan_core(x, w, labels, num_chunks, col0, axis=None):
     return m, s, zl
 
 
+@jax.named_scope('fused_ce.bwd')
 def _bwd_core(x, w, labels, lse, g, num_chunks, col0, axis=None):
     """Chunked recompute backward for one shard's columns: returns
     (dx_partial, dw).  dx_partial covers only this shard's columns —

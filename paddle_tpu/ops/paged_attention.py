@@ -49,13 +49,18 @@ def write_kv(k_pool, v_pool, k_new, v_new, block_tables, slots):
     inactive batch slots stay in the compiled step without corrupting
     live sequences.
     """
+    import jax
     import jax.numpy as jnp
-    bs = k_pool.shape[2]
-    idx = (slots // bs).astype(jnp.int32)
-    bids = jnp.take_along_axis(block_tables, idx[:, None], axis=1)[:, 0]
-    offs = (slots % bs).astype(jnp.int32)
-    k_pool = k_pool.at[bids, :, offs].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[bids, :, offs].set(v_new.astype(v_pool.dtype))
+    with jax.named_scope('paged.write_kv'):
+        bs = k_pool.shape[2]
+        idx = (slots // bs).astype(jnp.int32)
+        bids = jnp.take_along_axis(
+            block_tables, idx[:, None], axis=1)[:, 0]
+        offs = (slots % bs).astype(jnp.int32)
+        k_pool = k_pool.at[bids, :, offs].set(
+            k_new.astype(k_pool.dtype))
+        v_pool = v_pool.at[bids, :, offs].set(
+            v_new.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
@@ -64,12 +69,14 @@ def gather_dense(pool, block_table):
     [num_blocks, nh, bs, hd] gathered through [S, max_blocks] tables
     -> [S, nh, max_blocks*bs, hd] (position-contiguous per sequence).
     """
+    import jax
     import jax.numpy as jnp
     S, mb = block_table.shape
     _, nh, bs, hd = pool.shape
-    g = pool[block_table]                      # [S, mb, nh, bs, hd]
-    g = jnp.transpose(g, (0, 2, 1, 3, 4))      # [S, nh, mb, bs, hd]
-    return g.reshape(S, nh, mb * bs, hd)
+    with jax.named_scope('paged.gather_dense'):
+        g = pool[block_table]                  # [S, mb, nh, bs, hd]
+        g = jnp.transpose(g, (0, 2, 1, 3, 4))  # [S, nh, mb, bs, hd]
+        return g.reshape(S, nh, mb * bs, hd)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lens):
@@ -91,11 +98,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, lens):
     import jax
     import jax.numpy as jnp
     hd = q.shape[-1]
-    k = gather_dense(k_pool, block_tables)     # [S, nh, mb*bs, hd]
-    v = gather_dense(v_pool, block_tables)
-    scores = jnp.einsum('shd,shkd->shk', q, k) * (1.0 / math.sqrt(hd))
-    cols = jnp.arange(k.shape[2], dtype=lens.dtype)
-    mask = cols[None, :] < lens[:, None]       # ragged, per sequence
-    scores = jnp.where(mask[:, None, :], scores, -1e9)
-    att = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum('shk,shkd->shd', att, v)
+    with jax.named_scope('paged.attention'):
+        k = gather_dense(k_pool, block_tables)  # [S, nh, mb*bs, hd]
+        v = gather_dense(v_pool, block_tables)
+        scores = jnp.einsum('shd,shkd->shk', q, k) \
+            * (1.0 / math.sqrt(hd))
+        cols = jnp.arange(k.shape[2], dtype=lens.dtype)
+        mask = cols[None, :] < lens[:, None]    # ragged, per sequence
+        scores = jnp.where(mask[:, None, :], scores, -1e9)
+        att = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum('shk,shkd->shd', att, v)

@@ -349,8 +349,9 @@ class ParallelTrainer:
             else:
                 loss, (d_sh, d_st) = out
             grads = {'shared': shard_shared_grads(d_sh), 'stages': d_st}
-            new_params, new_state = opt.apply_gradients(
-                params, grads, opt_state, step_no)
+            with jax.named_scope('optimizer_update'):
+                new_params, new_state = opt.apply_gradients(
+                    params, grads, opt_state, step_no)
             if nan_guard:
                 # device-side skip, same contract as the dp path: a
                 # non-finite microbatch (or non-finite reduced grads)
@@ -542,14 +543,16 @@ class ParallelTrainer:
                 # module; only the boolean crosses to the host where
                 # the sentinel's strike/rollback policy runs
                 ok = finite_step(loss, grads)
-                new_params, new_state = opt.apply_gradients(
-                    params, grads, opt_state, step_no)
+                with jax.named_scope('optimizer_update'):
+                    new_params, new_state = opt.apply_gradients(
+                        params, grads, opt_state, step_no)
                 new_params = guard_update(ok, new_params, params)
                 new_state = guard_update(ok, new_state, opt_state)
                 new_buffers = guard_update(ok, new_buffers, buffers)
                 return new_params, new_buffers, new_state, loss, ok
-            new_params, new_state = opt.apply_gradients(
-                params, grads, opt_state, step_no)
+            with jax.named_scope('optimizer_update'):
+                new_params, new_state = opt.apply_gradients(
+                    params, grads, opt_state, step_no)
             return new_params, new_buffers, new_state, loss
 
         self._raw_step = train_step          # linted by _run_lint
@@ -918,37 +921,49 @@ class ParallelTrainer:
         analysis.safe_emit(build, self.lint)
 
     def step(self, *batch):
-        """batch: numpy/jax arrays (x, y, ...). Returns python float loss."""
-        if self._pipeline:
-            return self._pipe_step(*batch)
-        import time as _time
+        """batch: numpy/jax arrays (x, y, ...). Returns python float loss.
+
+        Spans: ``trainer.step`` around the call, children
+        ``trainer.prepare``, ``trainer.dispatch``, ``trainer.note``
+        (PERF.md section 3 lists the metrics that read them); the
+        pipeline step has the outer span only."""
         from .. import telemetry as _tel
-        if self._pending_plan is not None:
-            self._apply_pending_plan()
-        first_call = self._compiled is None
-        vals = self._ensure_compiled(batch)
-        key = rng_mod.next_key()
-        wd = self._ensure_watchdog()
-        if wd is not None:
-            # the deadline covers dispatch + (nan path) the device
-            # sync — where a hung collective actually blocks the host
-            wd.step_started(self._step_no + 1, first=first_call)
-        self._note_ledger_step(self._step_no + 1)
+        with _tel.span('trainer.step'):
+            if self._pipeline:
+                return self._pipe_step(*batch)
+            return self._step(_tel, batch)
+
+    def _step(self, _tel, batch):
+        import time as _time
+        with _tel.span('trainer.prepare'):
+            if self._pending_plan is not None:
+                self._apply_pending_plan()
+            first_call = self._compiled is None
+            vals = self._ensure_compiled(batch)
+            key = rng_mod.next_key()
+            wd = self._ensure_watchdog()
+            if wd is not None:
+                # the deadline covers dispatch + (nan path) the device
+                # sync — where a hung collective actually blocks the host
+                wd.step_started(self._step_no + 1, first=first_call)
+            self._note_ledger_step(self._step_no + 1)
         _t0 = _time.perf_counter()
         try:
-            if self.nan_guard:
-                (self.params, self.buffers, self.opt_state, loss,
-                 ok) = self._compiled(
+            with _tel.span('trainer.dispatch'):
+                out = self._compiled(
                     self.params, self.buffers, self.opt_state,
                     jnp.asarray(self._step_no + 1), key, *vals)
-                self._note_step(first_call, _time.perf_counter() - _t0,
-                                loss, _tel)
+            if self.nan_guard:
+                (self.params, self.buffers, self.opt_state, loss,
+                 ok) = out
+                with _tel.span('trainer.note'):
+                    self._note_step(first_call,
+                                    _time.perf_counter() - _t0, loss,
+                                    _tel)
                 ok = bool(ok)   # the one host sync nan_guard costs
             else:
                 (self.params, self.buffers, self.opt_state,
-                 loss) = self._compiled(
-                    self.params, self.buffers, self.opt_state,
-                    jnp.asarray(self._step_no + 1), key, *vals)
+                 loss) = out
         finally:
             if wd is not None:
                 wd.step_finished(self._step_no + 1)
@@ -959,8 +974,9 @@ class ParallelTrainer:
                 self._nan_rollback()
             return loss
         self._step_no += 1
-        self._note_step(first_call, _time.perf_counter() - _t0, loss,
-                        _tel)
+        with _tel.span('trainer.note'):
+            self._note_step(first_call, _time.perf_counter() - _t0,
+                            loss, _tel)
         # LR-scheduler advancement is the caller's job (hapi epoch loop)
         return loss
 
@@ -1036,9 +1052,57 @@ class ParallelTrainer:
                 'fused_steps under pipeline parallelism: the 1F1B '
                 'schedule is already a fused multi-microbatch module')
         import time as _time
-        import warnings
         from .. import telemetry as _tel
         from ..core import scan_loop as _scan
+        with _tel.span('trainer.step'):
+            with _tel.span('trainer.prepare'):
+                fn, vals, k, keys, wd, first_call = \
+                    self._prepare_fused(batch, _tel)
+            _t0 = _time.perf_counter()
+            try:
+                with _tel.span('trainer.dispatch'):
+                    out = fn(
+                        self.params, self.buffers, self.opt_state,
+                        jnp.asarray(self._step_no, jnp.int32), keys,
+                        *vals)
+                if self.nan_guard:
+                    (self.params, self.buffers, self.opt_state, _s,
+                     losses, oks) = out
+                else:
+                    (self.params, self.buffers, self.opt_state, _s,
+                     losses) = out
+            finally:
+                if wd is not None:
+                    wd.step_finished(self._step_no + k)
+            dt = _time.perf_counter() - _t0
+            # telemetry rows are labeled by a monotone DISPATCH
+            # counter: under nan_guard, _step_no advances only by the
+            # finite count, so labeling rows _step_no-k+1.. would
+            # reuse ids across chunks containing skips
+            row_lo = getattr(self, '_fused_rows', 0) + 1
+            self._fused_rows = row_lo + k - 1
+            if self.nan_guard:
+                # the chunk's ONE sanctioned host sync: the K-step mask
+                mask = _scan.chunk_sync(oks)
+                self._step_no += int(mask.sum())
+                with _tel.span('trainer.note'):
+                    self._note_chunk(first_call, dt, losses, k, row_lo)
+                for ok in mask:
+                    if self.sentinel.observe(
+                            finite=bool(ok)) == 'rollback':
+                        self._nan_rollback()
+                        break
+                return losses
+            self._step_no += k
+            with _tel.span('trainer.note'):
+                self._note_chunk(first_call, dt, losses, k, row_lo)
+            return losses
+
+    def _prepare_fused(self, batch, _tel):
+        """Everything step_fused does before its dispatch (the
+        ``trainer.prepare`` span): land a queued plan, build or find
+        the K-step module, draw the K keys, arm the watchdog."""
+        import warnings
         if self._pending_plan is not None:
             # chunk boundary: the supervisor's queued plan lands
             # BEFORE this chunk compiles/dispatches
@@ -1059,7 +1123,7 @@ class ParallelTrainer:
                     f'step budget (fits {fit}); stage '
                     'fused_chunk_len() chunks so hang detection stays '
                     'inside the armed deadline', RuntimeWarning,
-                    stacklevel=2)
+                    stacklevel=3)
                 _tel.event('fused_clamp', requested=k, fits=fit)
             jitted = self._build_fused_step(k)
             from ..core import compile_cache as _cc
@@ -1099,41 +1163,7 @@ class ParallelTrainer:
             wd.step_started(self._step_no + k, budget_s=budget_s,
                             first=first_call)
         self._note_ledger_step(self._step_no + 1, k=k)
-        _t0 = _time.perf_counter()
-        try:
-            if self.nan_guard:
-                (self.params, self.buffers, self.opt_state, _s,
-                 losses, oks) = fn(
-                    self.params, self.buffers, self.opt_state,
-                    jnp.asarray(self._step_no, jnp.int32), keys, *vals)
-            else:
-                (self.params, self.buffers, self.opt_state, _s,
-                 losses) = fn(
-                    self.params, self.buffers, self.opt_state,
-                    jnp.asarray(self._step_no, jnp.int32), keys, *vals)
-        finally:
-            if wd is not None:
-                wd.step_finished(self._step_no + k)
-        dt = _time.perf_counter() - _t0
-        # telemetry rows are labeled by a monotone DISPATCH counter:
-        # under nan_guard, _step_no advances only by the finite count,
-        # so labeling rows _step_no-k+1.. would reuse ids across
-        # chunks containing skips
-        row_lo = getattr(self, '_fused_rows', 0) + 1
-        self._fused_rows = row_lo + k - 1
-        if self.nan_guard:
-            # the chunk's ONE sanctioned host sync: the K-step mask
-            mask = _scan.chunk_sync(oks)
-            self._step_no += int(mask.sum())
-            self._note_chunk(first_call, dt, losses, k, row_lo)
-            for ok in mask:
-                if self.sentinel.observe(finite=bool(ok)) == 'rollback':
-                    self._nan_rollback()
-                    break
-            return losses
-        self._step_no += k
-        self._note_chunk(first_call, dt, losses, k, row_lo)
-        return losses
+        return fn, vals, k, keys, wd, first_call
 
     def _raw_fused(self, k):
         """The unjitted fused scan (fingerprint input)."""

@@ -21,10 +21,10 @@ import sys
 
 import jax
 
-# THE step timer of the stack lives in telemetry (its stop() feeds the
-# recorder's step-time reservoir); this module and utils/profiler used
-# to carry near-duplicate implementations — both now re-export it.
-from ..telemetry import StepTimer  # noqa: F401
+# THE step timer and THE span of the stack live in telemetry; the
+# reference's RecordEvent is that span (a jax.profiler.TraceAnnotation
+# for its extent) under its reference name
+from ..telemetry import StepTimer, span as RecordEvent  # noqa: F401
 from . import trace  # noqa: F401
 from .trace import (  # noqa: F401
     TraceProfile, parse_trace, find_traces, match_collectives)
@@ -180,24 +180,6 @@ def profiler(state=None, sorted_key=None,
         yield
     finally:
         stop_profiler(sorted_key)
-
-
-class RecordEvent:
-    """Named host-side trace annotation (reference: RecordEvent);
-    shows up in the XProf timeline via jax.profiler.TraceAnnotation."""
-
-    def __init__(self, name):
-        self.name = name
-        self._ctx = None
-
-    def __enter__(self):
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        self._ctx.__exit__(*exc)
-        self._ctx = None
 
 
 class Profiler:

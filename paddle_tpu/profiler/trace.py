@@ -82,13 +82,9 @@ def _done_half(name):
 def find_traces(logdir):
     """All ``*.trace.json.gz`` under `logdir`, oldest → newest (one
     per host per capture; jax nests them under plugins/profile/<run>)."""
-    pats = (os.path.join(logdir, '**', '*.trace.json.gz'),
-            os.path.join(logdir, '*.trace.json.gz'))
-    out = []
-    for p in pats:
-        out += glob.glob(p, recursive=True)
-    out = sorted(set(out), key=lambda f: (os.path.getmtime(f), f))
-    return out
+    found = glob.glob(os.path.join(logdir, '**', '*.trace.json.gz'),
+                      recursive=True)     # '**' is also no directory
+    return sorted(found, key=lambda f: (os.path.getmtime(f), f))
 
 
 class TraceProfile:

@@ -356,6 +356,7 @@ class ServingEngine:
         row_sample = make_row_sampler(self.config.temperature,
                                       self.config.top_k)
 
+        @jax.named_scope('serve.sample')
         def sample(logits, seeds, pos):
             bases = jax.vmap(jax.random.PRNGKey)(seeds)
             return row_sample(logits, bases, pos)
@@ -403,6 +404,7 @@ class ServingEngine:
         pair: ONE cached forward over B padded prompts, per-row first
         tokens sampled at each row's true length, every row's
         block-rounded KV scattered through its own block-table row."""
+        import jax
         import jax.numpy as jnp
         from ..parallel.api import maybe_shard
         from ..ops.paged_attention import POOL_SPEC
@@ -414,6 +416,7 @@ class ServingEngine:
         nh = model.config.num_heads
         hd = model.config.hidden_size // nh
 
+        @jax.named_scope('serve.prefill')
         def prefill_fn(params, buffers, ids, t0, ks, vs, block_ids,
                        seeds):
             caches = model.init_decode_caches(B, Pc)
@@ -486,6 +489,7 @@ class ServingEngine:
         sample = self._sample_fn()
         eos = self.config.eos_id
 
+        @jax.named_scope('serve.decode')
         def decode_fn(params, buffers, ks, vs, tables, ctx, tok,
                       active, limit, seeds):
             ks = tuple(maybe_shard(k, POOL_SPEC) for k in ks)
@@ -660,44 +664,59 @@ class ServingEngine:
         self.cache.set_pools(list(zip(ks, vs)))
         return toks, valid
 
+    def _emit_serve_step(self, admitted, t_start, **fields):
+        """The ``serve_step`` event, carrying the pending first-token /
+        rollback counts.  The pool-shape fields cost a sort of the free
+        list and a pass over every owned sequence (``frag_report``), so
+        they are built only where a writer or a subscriber reads the
+        stream; the flight ring keeps the cheap fields."""
+        from .. import telemetry
+        if telemetry.streaming():
+            frag = self.cache.frag_report()
+            fields.update(
+                kv_frag_frac=frag['frag_frac'],
+                kv_largest_free_run=frag['largest_free_run'])
+        telemetry.event('serve_step', intervention=self.interventions,
+                        admitted=admitted,
+                        queued=len(self.scheduler.queue),
+                        free_blocks=self.cache.free_blocks,
+                        total_blocks=self.cache.num_blocks,
+                        kv_high_water=self.cache.high_water_blocks,
+                        prefilled=self._pending_prefilled,
+                        discarded=self._pending_discarded,
+                        dur_s=round(self._clock() - t_start, 6),
+                        **fields)
+        self._pending_prefilled = 0
+        self._pending_discarded = 0
+
     def _flush_pending_tokens(self, admitted, t_start):
         """A prefill-only intervention (nothing left running) emits a
         decode-less ``serve_step`` carrying the pending first-token /
         rollback counts, so no delivered token is ever lost to the
         early-return paths."""
-        if not self._pending_prefilled and not self._pending_discarded:
-            return
-        from .. import telemetry
-        sched = self.scheduler
-        frag = self.cache.frag_report()
-        telemetry.event('serve_step', intervention=self.interventions,
-                        live=0, batch=0, span=0, decoded=0,
-                        admitted=admitted, finished=0, preempted=0,
-                        queued=len(sched.queue),
-                        free_blocks=self.cache.free_blocks,
-                        total_blocks=self.cache.num_blocks,
-                        kv_frag_frac=frag['frag_frac'],
-                        kv_largest_free_run=frag['largest_free_run'],
-                        kv_high_water=frag['high_water_blocks'],
-                        prefilled=self._pending_prefilled,
-                        discarded=self._pending_discarded,
-                        dur_s=round(self._clock() - t_start, 6))
-        self._pending_prefilled = 0
-        self._pending_discarded = 0
+        if self._pending_prefilled or self._pending_discarded:
+            self._emit_serve_step(admitted, t_start, live=0, batch=0,
+                                  span=0, decoded=0, finished=0,
+                                  preempted=0)
 
     def _note_finished(self, finished, now):
         from .. import telemetry
+        # the full lifecycle trail is copied into an event only where
+        # something consumes the stream (the live plane's
+        # /requests/<rid> store, a JSONL writer); otherwise the rows
+        # stay on the Request and _live_trace serves them
+        trails = bool(finished) and telemetry.streaming()
         for req in finished:
             rec = req.record(now)
             telemetry.event('serve_request', **rec)
-            # the full lifecycle trail, ONE event per finished request
-            # (bounded by request count, never by decode steps);
-            # joinable with serve_request by rid, served live at
-            # /requests/<rid>
-            telemetry.event('serve_trace', rid=req.rid,
-                            state=req.state, reason=req.reason,
-                            prompt_bucket=req.prompt_bucket,
-                            trace=[dict(r) for r in req.trace])
+            if trails:
+                # ONE event per finished request (bounded by request
+                # count, never by decode steps); joinable with
+                # serve_request by rid
+                telemetry.event('serve_trace', rid=req.rid,
+                                state=req.state, reason=req.reason,
+                                prompt_bucket=req.prompt_bucket,
+                                trace=[dict(r) for r in req.trace])
             if req.reason == 'deadline':
                 telemetry.event(
                     'timeout', op='serve_request', rid=req.rid,
@@ -708,51 +727,63 @@ class ServingEngine:
         """ONE scheduler intervention: release/admit/prefill, decode
         the live set for one span, absorb, evict, backfill.  Returns
         the intervention's progress count (admissions + evictions +
-        decoded tokens); 0 means nothing could move at all."""
+        decoded tokens); 0 means nothing could move at all.
+
+        The spans (``serve.step`` and its children, in this order) are
+        the contract PERF.md section 3 lists beside the metrics that
+        read them."""
+        from ..telemetry import span
+        with span('serve.step'):
+            return self._step(now)
+
+    def _step(self, now):
         from .. import telemetry
+        span = telemetry.span
         sched = self.scheduler
         now = self._clock() if now is None else now
         t_start = self._clock()
-        breached = sched.check_deadlines(now)
-        self._note_finished(breached, now)
+        with span('serve.deadlines'):
+            breached = sched.check_deadlines(now)
+            self._note_finished(breached, now)
         # two-phase admission: chunk same-bucket admissions into
         # batched prefill dispatches (device work pipelines through
         # the donated pool chain), then sync first tokens in order
-        dispatched, chunk = [], []
-        admitted = 0
-
-        def flush():
-            if chunk:
-                dispatched.append((list(chunk),
-                                   self._prefill_dispatch(chunk)))
-                chunk.clear()
-
-        while True:
-            req = sched.admit_next()
-            if req is None:
-                break
-            admitted += 1
-            if chunk and (req.prompt_bucket != chunk[0].prompt_bucket
-                          or len(chunk) >= self.config.prefill_batch):
-                flush()
-            chunk.append(req)
-        flush()
-        for reqs, toks_dev in dispatched:
-            toks = np.asarray(toks_dev)
+        chunks = []
+        with span('serve.admit'):
+            while True:
+                req = sched.admit_next()
+                if req is None:
+                    break
+                if not chunks or (
+                        req.prompt_bucket != chunks[-1][0].prompt_bucket
+                        or len(chunks[-1]) >= self.config.prefill_batch):
+                    chunks.append([])
+                chunks[-1].append(req)
+        admitted = sum(len(c) for c in chunks)
+        dispatched = []
+        for chunk in chunks:
+            with span('serve.prefill_dispatch'):
+                dispatched.append(self._prefill_dispatch(chunk))
+        for reqs, toks_dev in zip(chunks, dispatched):
+            with span('serve.first_token_sync'):
+                toks = np.asarray(toks_dev)
             for i, req in enumerate(reqs):
                 self._prefill_finish(req, toks[i])
             self._pending_prefilled += len(reqs)
-        self._note_finished(
-            [r for reqs, _ in dispatched for r in reqs if r.done], now)
+        prefill_done = [r for reqs in chunks for r in reqs if r.done]
         progress = admitted + len(breached)
         if not sched.running:
             # everything finished at prefill (or evicted): flush the
             # carried first-token counts NOW — no later serve_step
             # will fire to carry them, and the live plane / run_report
             # token accounting must still match decoded_tokens
-            self._flush_pending_tokens(admitted, t_start)
+            with span('serve.bookkeeping'):
+                self._note_finished(prefill_done, now)
+                self._flush_pending_tokens(admitted, t_start)
             return progress
-        preempted = sched.reserve_span(sched.decode_span)
+        self._note_finished(prefill_done, now)
+        with span('serve.reserve'):
+            preempted = sched.reserve_span(sched.decode_span)
         # a preempted request's emitted tokens are discarded and will
         # be recomputed — un-count them so tokens_per_s only ever
         # counts DELIVERED tokens once
@@ -760,39 +791,32 @@ class ServingEngine:
                         for r in preempted)
         self.decoded_tokens -= discarded
         self._pending_discarded += discarded
-        plan = sched.plan()
+        with span('serve.plan'):
+            plan = sched.plan()
         if plan is None:
-            self._flush_pending_tokens(admitted, t_start)
+            with span('serve.bookkeeping'):
+                self._flush_pending_tokens(admitted, t_start)
             return progress
-        toks_dev, valid_dev = self._decode(plan)
+        with span('serve.decode_dispatch'):
+            toks_dev, valid_dev = self._decode(plan)
         if self._prof is not None:
             self._prof.observe(self.interventions * plan.span,
                                sync=toks_dev, span=plan.span)
-        toks = np.asarray(toks_dev)
-        valid = np.asarray(valid_dev)
-        finished = sched.absorb(plan, toks, valid)
-        self._note_finished(finished, self._clock())
-        n = int(valid.sum())
-        self.decoded_tokens += n
-        self.interventions += 1
-        frag = self.cache.frag_report()
-        telemetry.event('serve_step', intervention=self.interventions,
-                        live=len(plan.requests), batch=plan.batch,
-                        span=plan.span, decoded=n, admitted=admitted,
-                        finished=len(finished),
-                        preempted=len(preempted),
-                        queued=len(sched.queue),
-                        free_blocks=self.cache.free_blocks,
-                        total_blocks=self.cache.num_blocks,
-                        kv_frag_frac=frag['frag_frac'],
-                        kv_largest_free_run=frag['largest_free_run'],
-                        kv_high_water=frag['high_water_blocks'],
-                        prefilled=self._pending_prefilled,
-                        discarded=self._pending_discarded,
-                        dur_s=round(self._clock() - t_start, 6))
-        self._pending_prefilled = 0
-        self._pending_discarded = 0
-        telemetry.add('serve.decoded_tokens', n)
+        with span('serve.decode_sync'):
+            toks = np.asarray(toks_dev)
+            valid = np.asarray(valid_dev)
+        with span('serve.absorb'):
+            finished = sched.absorb(plan, toks, valid)
+        with span('serve.bookkeeping'):
+            self._note_finished(finished, self._clock())
+            n = int(valid.sum())
+            self.decoded_tokens += n
+            self.interventions += 1
+            self._emit_serve_step(
+                admitted, t_start, live=len(plan.requests),
+                batch=plan.batch, span=plan.span, decoded=n,
+                finished=len(finished), preempted=len(preempted))
+            telemetry.add('serve.decoded_tokens', n)
         return progress + n
 
     def run(self, requests=(), timeout_s=None):
@@ -800,6 +824,7 @@ class ServingEngine:
         ``arrival_t`` offsets (the Poisson load path), loop
         interventions until every request completes or evicts.
         Returns the report dict."""
+        from ..telemetry import span
         pending = sorted(requests, key=lambda r: r.arrival_t)
         sched = self.scheduler
         t0 = self.now_fn()
@@ -830,8 +855,9 @@ class ServingEngine:
                     self.submit(pending.pop(0))
                 if not sched.queue and not sched.running:
                     if pending:
-                        time.sleep(min(
-                            0.05, max(0.0, pending[0].arrival_t - now)))
+                        with span('serve.wait_arrival'):
+                            time.sleep(min(0.05, max(
+                                0.0, pending[0].arrival_t - now)))
                     continue
                 if self.step(now=now) == 0 and not sched.running \
                         and sched.queue:
@@ -858,6 +884,10 @@ class ServingEngine:
         ttfts = sorted(r['ttft_s'] for r in recs
                        if r['ttft_s'] is not None)
         tpots = [r['tpot_s'] for r in recs if r['tpot_s'] is not None]
+        lates = sorted(r['submit_late_s'] for r in recs
+                       if r['submit_late_s'] is not None)
+        waits = sorted(r['queue_wait_s'] for r in recs
+                       if r['queue_wait_s'] is not None)
 
         def pct(sorted_vals, q):
             if not sorted_vals:
@@ -877,6 +907,12 @@ class ServingEngine:
             'ttft_p50_s': pct(ttfts, 0.50),
             'ttft_p99_s': pct(ttfts, 0.99),
             'tpot_mean_s': (sum(tpots) / len(tpots)) if tpots else None,
+            # how late run() handed requests over (the generator's
+            # lateness) and how long they then waited for a slot
+            'submit_late_p50_s': pct(lates, 0.50),
+            'submit_late_p95_s': pct(lates, 0.95),
+            'queue_wait_p50_s': pct(waits, 0.50),
+            'queue_wait_p95_s': pct(waits, 0.95),
             'compile_count': self.compile_count,
             'modules': sorted(str(s) for s in self._modules),
             'audit': sched.audit(),

@@ -135,11 +135,21 @@ class Request:
                 and len(self.tokens) > 1:
             tpot = (self.finish_t - self.first_token_t) \
                 / (len(self.tokens) - 1)
+        # request-level times are no with-blocks, so they are fields:
+        # the first `queued` / `admitted` rows of the trace, less the
+        # time the request was due
+        first = {}
+        for row in self.trace:
+            first.setdefault(row['stage'], row['t'])
         return {'rid': self.rid, 'state': self.state,
                 'reason': self.reason, 'prompt_len': int(self.prompt.size),
                 'tokens': len(self.tokens), 'ttft_s': ttft,
                 'tpot_s': tpot, 'preemptions': self.preemptions,
-                'age_s': (now - self.arrival_t)}
+                'age_s': (now - self.arrival_t),
+                'submit_late_s': None if 'queued' not in first
+                else first['queued'] - self.arrival_t,
+                'queue_wait_s': None if 'admitted' not in first
+                else first['admitted'] - self.arrival_t}
 
 
 class DecodePlan:

@@ -1,12 +1,9 @@
 """paddle_tpu.telemetry — unified, sync-free run telemetry.
 
-A run's health used to be scattered: profiler HLO tables, lint
-warnings, resilience log lines, per-callback progress printing.  This
-package is the one structured record of *what happened during a run*:
+The one structured record of *what happened during a run*:
 
-* **spans** — ``with telemetry.span('compile'):`` nested
-  monotonic-clock timers (compile, checkpoint_save/restore, fit,
-  evaluate), aggregated per name and streamed as events;
+* **spans** — ``with telemetry.span('serve.plan'):`` a profiler
+  annotation always, a record (start, end, ids, rid) when enabled;
 * **counters / gauges** — retrace counts, dataloader host-wait
   seconds, collective bytes;
 * **typed events** — ``compile``, ``retrace``,
@@ -47,12 +44,13 @@ flight ring + counters (boundary-rate, negligible) so crash/preemption
 dumps work out of the box; ``enable()`` adds the JSONL stream and the
 per-step accumulation.
 """
-import contextlib
 import os
 import sys
 
+from . import recorder as _recorder
 from .recorder import (  # noqa: F401
-    Recorder, get_recorder, reset, hard_off, EVENT_KINDS)
+    Recorder, Span, get_recorder, reset, hard_off, enabled,
+    EVENT_KINDS)
 from .stepstats import (  # noqa: F401
     StepAccumulator, StepTimer, percentiles)
 from .exporters import (  # noqa: F401
@@ -74,8 +72,8 @@ from .cluster import (  # noqa: F401
     enable_cluster_plane, resolve_cluster_stats)
 
 __all__ = [
-    'Recorder', 'get_recorder', 'reset', 'hard_off', 'EVENT_KINDS',
-    'StepAccumulator', 'StepTimer', 'percentiles',
+    'Recorder', 'Span', 'get_recorder', 'reset', 'hard_off',
+    'EVENT_KINDS', 'StepAccumulator', 'StepTimer', 'percentiles',
     'JsonlWriter', 'ScalarAdapter', 'TensorBoardWriter', 'TeeWriter',
     'ProfileSchedule', 'StepProfiler', 'step_profiler', 'capture',
     'resolve_schedule',
@@ -87,11 +85,11 @@ __all__ = [
     'ClusterPublisher', 'ClusterAggregator', 'ClusterPlane',
     'enable_cluster_plane', 'resolve_cluster_stats',
     'enable', 'disable', 'enabled', 'active',
-    'event', 'add', 'set_gauge', 'span', 'events',
+    'event', 'add', 'set_gauge', 'span', 'events', 'streaming',
     'step_accumulator', 'dump_flight', 'flight_dir',
 ]
 
-_enabled = False
+span = Span      # the module-level name every call site uses
 _prev_excepthook = None
 _crash_dir = None
 
@@ -100,12 +98,6 @@ def active():
     """True when telemetry records at all (the default; in-memory
     flight ring + counters).  False only under PADDLE_TPU_TELEMETRY=0."""
     return not hard_off()
-
-
-def enabled():
-    """True when enable() turned on the JSONL export + per-step
-    accumulation (the opt-in, heavier-weight layer)."""
-    return _enabled and not hard_off()
 
 
 def enable(log_dir=None, flush_interval=32, crash_dump=True,
@@ -125,7 +117,7 @@ def enable(log_dir=None, flush_interval=32, crash_dump=True,
     records — become TB scalar points at their flush boundary, so the
     export adds zero per-step host syncs (stdlib-only writer, see
     exporters.TensorBoardWriter)."""
-    global _enabled, _crash_dir
+    global _crash_dir
     if hard_off():
         return None
     rec = get_recorder()
@@ -142,7 +134,7 @@ def enable(log_dir=None, flush_interval=32, crash_dump=True,
         if old is not None:
             old.close()
         _crash_dir = os.path.abspath(log_dir)
-    _enabled = True
+    _recorder._enabled = True
     if crash_dump:
         _install_crash_hook()
     meta = {'pid': os.getpid(), 'argv': list(sys.argv),
@@ -160,8 +152,7 @@ def enable(log_dir=None, flush_interval=32, crash_dump=True,
 def disable():
     """Detach the JSONL writer and stop per-step accumulation; the
     in-memory flight ring keeps recording (see active())."""
-    global _enabled
-    _enabled = False
+    _recorder._enabled = False
     rec = get_recorder()
     w = rec.attach_writer(None)
     if w is not None:
@@ -200,12 +191,12 @@ def events(kind=None):
     return get_recorder().events(kind)
 
 
-def span(name, **attrs):
-    """``with telemetry.span('compile'): ...`` — no-op under the hard
-    kill switch."""
-    if hard_off():
-        return contextlib.nullcontext()
-    return get_recorder().span(name, **attrs)
+def streaming():
+    """True when a JSONL writer or a subscriber reads the event stream
+    beyond the flight ring: emitters of costly payloads ask first."""
+    rec = get_recorder()
+    return not hard_off() and (
+        rec.writer is not None or bool(rec._subscribers))
 
 
 def step_accumulator(tag='train', flush_interval=None):

@@ -48,7 +48,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 __all__ = ['MetricsServer', 'resolve_metrics_port', 'attach_source',
-           'running_servers', 'METRICS_PORT_ENV', 'METRICS_HOST_ENV']
+           'METRICS_PORT_ENV', 'METRICS_HOST_ENV']
 
 METRICS_PORT_ENV = 'PADDLE_TPU_METRICS_PORT'
 METRICS_HOST_ENV = 'PADDLE_TPU_METRICS_HOST'
@@ -240,13 +240,16 @@ class MetricsServer:
             target=httpd.serve_forever, name='paddle-tpu-metrics',
             daemon=True)
         self._thread.start()
-        _note_running(self)
+        with _running_lock:
+            _running.append(self)
         return self
 
     def stop(self):
         httpd, self._httpd = self._httpd, None
         t, self._thread = self._thread, None
-        _note_stopped(self)
+        with _running_lock:
+            if self in _running:
+                _running.remove(self)
         if httpd is not None:
             httpd.shutdown()
             httpd.server_close()
@@ -266,34 +269,11 @@ class MetricsServer:
         return False
 
 
-# -- process-wide running-server registry -------------------------------------
-#
-# The double-binding fix: when a ServingEngine already bound a metrics
-# port in this process, the training cluster plane must ADD its view
-# there instead of fighting for a second port.  start()/stop() keep
-# this list current; attach_source() consults it.
-
+# the servers running in this process, kept by start()/stop(): where a
+# ServingEngine already bound a metrics port, attach_source() ADDS the
+# cluster plane's view there instead of fighting for a second port
 _running = []
 _running_lock = threading.Lock()
-
-
-def _note_running(server):
-    with _running_lock:
-        if server not in _running:
-            _running.append(server)
-
-
-def _note_stopped(server):
-    with _running_lock:
-        if server in _running:
-            _running.remove(server)
-
-
-def running_servers():
-    """The MetricsServers currently serving in this process (oldest
-    first — the first binder is the canonical process endpoint)."""
-    with _running_lock:
-        return list(_running)
 
 
 def attach_source(name, source, port=None, host=None):

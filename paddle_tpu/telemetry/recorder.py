@@ -12,9 +12,9 @@ One Recorder per process holds everything the run emits:
   (``telemetry.enable``), every event additionally streams to disk.
 * **counters / gauges** — cheap monotonic adds and last-value reads
   (retrace counts, dataloader wait seconds, collective bytes).
-* **spans** — nested monotonic-clock timers (``span('compile')``);
-  each close updates per-name aggregate stats and emits a ``span``
-  event.
+* **spans** — :class:`Span`: a profiler annotation for its extent, on
+  the device trace's clock; records (``span`` events, per-name stats)
+  only when telemetry is enabled.
 
 Emission points are boundary-rate (compile, checkpoint, epoch, flush),
 never per-device-step: the per-step path lives in
@@ -24,9 +24,9 @@ reintroduces the host syncs the PR-2 lint work removed.
 
 This module imports only stdlib — it must be importable from anywhere
 in the package (io, resilience, analysis) without cycles; jax is
-touched lazily and only for rank discovery.
+touched lazily, for rank discovery and a span's annotation.
 """
-import contextlib
+import itertools
 import json
 import os
 import sys
@@ -34,8 +34,8 @@ import threading
 import time
 from collections import deque
 
-__all__ = ['Recorder', 'get_recorder', 'reset', 'hard_off',
-           'EVENT_KINDS']
+__all__ = ['Recorder', 'Span', 'get_recorder', 'reset', 'hard_off',
+           'enabled', 'EVENT_KINDS']
 
 # documented event vocabulary.  Every kind any module under
 # paddle_tpu/ emits MUST be declared here — a meta-test greps the
@@ -103,15 +103,12 @@ EVENT_KINDS = (
                            # state/reason, prompt_len, tokens, TTFT,
                            # TPOT, preemptions) — deadline breaches
                            # additionally emit a 'timeout' event
-    'serve_trace',         # one finished request's full lifecycle
-                           # trace (rid + ordered stage rows:
-                           # queued -> admitted -> prefill ->
-                           # first_token -> decode_span* ->
-                           # finished/evicted/preempted, each with
-                           # cause and bucket tags) — joinable with
-                           # serve_request by rid; telemetry.live
-                           # keeps a bounded store of these for the
-                           # /requests/<rid> HTTP trace view
+    'serve_trace',         # one finished request's lifecycle rows
+                           # (queued -> admitted -> prefill ->
+                           # first_token -> decode_span* -> end),
+                           # joinable with serve_request by rid; built
+                           # only where telemetry.streaming() (live's
+                           # /requests/<rid> store, a JSONL writer)
     'serve_reject',        # admission control refused a request
                            # (rid, reason: queue_full/draining/
                            # exceeds_pool, retry_after_s, detail) —
@@ -161,7 +158,8 @@ EVENT_KINDS = (
     'steps',               # StepAccumulator flush (per-step scalars;
                            # fused chunk rows arrive expanded to
                            # per-step entries)
-    'span',                # a closed span (name, dur_s)
+    'span',                # a closed span (name, start, end, dur_s,
+                           # id, parent_id, rid) when enabled
     'scalar',              # user scalar (VisualDL / ScalarAdapter)
     'flight_dump',         # a flight-recorder dump was written
     'lockcheck',           # analysis.lockcheck disarm summary (locks
@@ -203,6 +201,58 @@ def hard_off():
         '0', 'off', 'false')
 
 
+_enabled = False        # telemetry.enable() / disable() flip this
+
+
+def enabled():
+    """True when enable() turned on the JSONL export, per-step
+    accumulation and span records (the opt-in, heavier-weight layer)."""
+    return _enabled and not hard_off()
+
+
+class Span:
+    """``with span('serve.plan', rid=...):`` — the program's ONE span
+    (``telemetry.span`` and ``profiler.RecordEvent`` are this class).
+    Always a ``jax.profiler.TraceAnnotation(name)`` for its extent: a
+    disabled TraceMe until a profiler session opens, then an event on
+    the host line of the device trace's own file; that session is the
+    only switch.  A record (``start``/``end`` in seconds on the
+    recorder's clock, ``id``, ``parent_id``, the inherited ``rid``;
+    closed into ``span_stats`` and one ``span`` event) is kept only
+    when telemetry is enabled or the span was opened on a ``Recorder``:
+    off, no lock, no dict, no event."""
+    __slots__ = ('name', 'rid', 'attrs', 'id', 'parent_id', 'start',
+                 'end', '_rec', '_ann')
+
+    def __init__(self, name, rid=None, recorder=None, **attrs):
+        self.name, self.rid, self.attrs = name, rid, attrs
+        self.id = self.parent_id = self.start = self.end = None
+        self._rec = recorder
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation    # a dict lookup
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        rec = self._rec
+        if rec is None and enabled():
+            rec = self._rec = get_recorder()
+        if rec is not None:
+            stack = rec._span_stack()
+            if stack:
+                self.parent_id = stack[-1].id
+                if self.rid is None:
+                    self.rid = stack[-1].rid
+            self.id = next(rec._span_ids)
+            stack.append(self)
+            self.start = _MONO() - rec._t0
+        return self
+
+    def __exit__(self, *exc):
+        if self._rec is not None:
+            self._rec._close_span(self)
+        self._ann.__exit__(*exc)
+
+
 def _rank():
     """Best-effort host rank; never raises, never initializes a
     backend that is not already up."""
@@ -234,6 +284,7 @@ class Recorder:
         self._writer = None     # exporters.JsonlWriter when enabled
         self._subscribers = ()  # in-process stream consumers (live.py)
         self._local = threading.local()
+        self._span_ids = itertools.count(1)
         self._t0_wall = _WALL()
         self._t0 = _MONO()
         self.flush_interval = 32   # StepAccumulator default
@@ -299,30 +350,27 @@ class Recorder:
             stack = self._local.stack = []
         return stack
 
-    @contextlib.contextmanager
-    def span(self, name, **attrs):
-        """Nested monotonic timer.  Closing updates span_stats[name]
-        and emits a ``span`` event carrying the parent span's name so
-        nesting is reconstructable offline."""
+    def span(self, name, rid=None, **attrs):
+        """A :class:`Span` that records into THIS recorder whether or
+        not telemetry is enabled."""
+        return Span(name, rid=rid, recorder=self, **attrs)
+
+    def _close_span(self, sp):
+        sp.end = _MONO() - self._t0
         stack = self._span_stack()
-        parent = stack[-1] if stack else None
-        stack.append(name)
-        t0 = _MONO()
-        try:
-            yield self
-        finally:
-            dt = _MONO() - t0
-            stack.pop()
-            with self._lock:
-                st = self.span_stats.setdefault(
-                    name, {'count': 0, 'total_s': 0.0, 'max_s': 0.0})
-                st['count'] += 1
-                st['total_s'] += dt
-                st['max_s'] = max(st['max_s'], dt)
-            ev = dict(attrs)
-            if parent:
-                ev['parent'] = parent
-            self.event('span', name=name, dur_s=round(dt, 6), **ev)
+        if sp in stack:             # tolerate out-of-order exits
+            del stack[stack.index(sp):]
+        dt = sp.end - sp.start
+        with self._lock:
+            st = self.span_stats.setdefault(
+                sp.name, {'count': 0, 'total_s': 0.0, 'max_s': 0.0})
+            st['count'] += 1
+            st['total_s'] += dt
+            st['max_s'] = max(st['max_s'], dt)
+        self.event('span', name=sp.name, dur_s=round(dt, 6),
+                   start=round(sp.start, 6), end=round(sp.end, 6),
+                   id=sp.id, parent_id=sp.parent_id, rid=sp.rid,
+                   **sp.attrs)
 
     # -- step-time reservoir -------------------------------------------------
     def observe_step_time(self, dt_s, tag='step', _cap=4096):

@@ -241,12 +241,17 @@ class TestTwoPhaseCommit:
         M.write_intent(d, 0, step=4)
         before_f = len(_events('commit_finalize'))
         before_i = len(_events('commit_intent'))
-        M.finalize_two_phase(d, 1, step=4, timeout=5)
+        telemetry.enable()      # spans keep records only when enabled
+        try:
+            M.finalize_two_phase(d, 1, step=4, timeout=5)
+        finally:
+            telemetry.disable()
         assert len(_events('commit_finalize')) == before_f + 1
         assert len(_events('commit_intent')) == before_i
         spans = [e for e in _events('span')
                  if e.get('name') == 'commit_barrier']
         assert spans and spans[-1]['hosts'] == 1
+        assert spans[-1]['start'] <= spans[-1]['end']
 
     def test_sigkill_between_intent_and_finalize(self, tmp_path):
         """THE two-phase crash window: every host acked, the finalizer

@@ -556,6 +556,11 @@ class TestEngineLivePlane:
         exactly when the evidence matters."""
         model = _tiny_model()
         eng = ServingEngine(model, _tiny_config())
+        seen = []
+        # the full trails are copied into events only where something
+        # reads the stream (a subscriber here, the live plane or a
+        # JSONL writer in a deployment)
+        telemetry.get_recorder().subscribe(seen.append)
         for r in _tiny_load(model, n=3):
             eng.submit(r.prompt, max_new_tokens=4)
         eng.run((), timeout_s=0.0)
@@ -563,6 +568,34 @@ class TestEngineLivePlane:
         assert len(evs) == 3
         assert {e['reason'] for e in evs} == {'engine_timeout'}
         assert len(telemetry.events('serve_trace')) == 3
+        assert sum(e['kind'] == 'serve_trace' for e in seen) == 3
+
+    def test_no_consumer_no_serve_trace_but_rows_stay(self):
+        """Nothing attached: no serve_trace event is built and no
+        frag_report runs per intervention, the flight ring keeps
+        serve_request and serve_step with the cheap fields, and the
+        rows stay on the Request for _live_trace."""
+        model = _tiny_model()
+        eng = ServingEngine(model, _tiny_config())
+        calls = []
+        frag = eng.cache.frag_report
+        eng.cache.frag_report = lambda: calls.append(1) or frag()
+        load = _tiny_load(model, n=3)
+        eng.run(load)
+        assert not telemetry.streaming()
+        assert telemetry.events('serve_trace') == []
+        assert len(telemetry.events('serve_request')) == 3
+        steps = telemetry.events('serve_step')
+        assert steps and not calls
+        assert all('kv_frag_frac' not in e for e in steps)
+        assert all(e['kv_high_water'] >= 1 and 'free_blocks' in e
+                   for e in steps)
+        rid = load[0].rid
+        stages = [r['stage'] for r in eng._live_trace(rid)]
+        assert stages[:2] == ['queued', 'admitted']
+        rec = load[0].record(0.0)
+        assert rec['submit_late_s'] >= 0
+        assert rec['queue_wait_s'] >= rec['submit_late_s']
 
     def test_prefill_only_tokens_reach_the_live_plane(self):
         """max_new_tokens=1 requests finish AT prefill — no decode

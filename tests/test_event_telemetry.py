@@ -79,16 +79,82 @@ class TestRecorder:
 
     def test_span_nesting_and_stats(self):
         r = Recorder()
-        with r.span('outer'):
-            with r.span('inner', target='x'):
+        with r.span('outer', rid='r7') as outer:
+            with r.span('inner', target='x') as inner:
+                pass
+            with r.span('other', rid='r8') as other:
                 pass
         assert r.span_stats['outer']['count'] == 1
         assert r.span_stats['inner']['count'] == 1
         assert r.span_stats['outer']['total_s'] >= \
             r.span_stats['inner']['total_s']
-        inner_ev = [e for e in r.events('span') if e['name'] == 'inner']
-        assert inner_ev[0]['parent'] == 'outer'
-        assert inner_ev[0]['target'] == 'x'
+        by_name = {e['name']: e for e in r.events('span')}
+        inner_ev = by_name['inner']
+        # nesting gives parent_id; a request's spans share its rid
+        assert inner_ev['parent_id'] == by_name['outer']['id'] \
+            == outer.id
+        assert by_name['outer']['parent_id'] is None
+        assert inner_ev['rid'] == by_name['outer']['rid'] == 'r7'
+        assert by_name['other']['rid'] == 'r8'
+        assert by_name['other']['parent_id'] == outer.id
+        assert len({outer.id, inner.id, other.id}) == 3
+        assert inner_ev['target'] == 'x'
+        for sp, ev in ((outer, by_name['outer']), (inner, inner_ev)):
+            assert 0 <= sp.start <= sp.end
+            assert ev['start'] == pytest.approx(sp.start, abs=1e-5)
+            assert ev['end'] == pytest.approx(sp.end, abs=1e-5)
+            assert ev['dur_s'] == pytest.approx(sp.end - sp.start,
+                                                abs=1e-5)
+        assert outer.start <= inner.start and inner.end <= outer.end
+
+    def test_span_off_leaves_nothing_in_memory(self):
+        """Telemetry not enabled (the default, and how the benchmark
+        measures): a span is its profiler annotation and nothing else —
+        ring, counters and span_stats unchanged."""
+        r = telemetry.get_recorder()
+        assert not telemetry.enabled()
+        before = (len(r.events()), dict(r.counters), dict(r.span_stats))
+        with telemetry.span('serve.step', rid='r1') as sp:
+            with telemetry.span('serve.plan') as child:
+                pass
+        assert (len(r.events()), dict(r.counters),
+                dict(r.span_stats)) == before
+        assert r._span_stack() == []
+        assert sp.id is None and sp.start is None and sp.end is None
+        assert child.parent_id is None and child.rid is None
+
+    def test_span_enabled_records_and_streams(self, tmp_path):
+        """enable() turns the same call sites into records: span
+        events with start/end/id/parent_id/rid in the ring and the
+        JSONL stream, and span_stats for the flight dump."""
+        telemetry.enable(str(tmp_path))
+        with telemetry.span('serve.step', rid='r1'):
+            with telemetry.span('serve.plan'):
+                pass
+        evs = {e['name']: e for e in telemetry.events('span')}
+        assert evs['serve.plan']['parent_id'] == evs['serve.step']['id']
+        assert evs['serve.plan']['rid'] == 'r1'
+        assert evs['serve.plan']['start'] <= evs['serve.plan']['end']
+        r = telemetry.get_recorder()
+        assert r.span_stats['serve.step']['count'] == 1
+        telemetry.disable()
+        rows = [json.loads(line) for line in
+                (tmp_path / 'telemetry-r0.jsonl').read_text().splitlines()]
+        spans = [x for x in rows if x['kind'] == 'span']
+        assert {x['name'] for x in spans} == {'serve.step', 'serve.plan'}
+        assert all({'start', 'end', 'id', 'parent_id', 'rid'} <= set(x)
+                   for x in spans)
+
+    def test_record_event_is_the_same_span(self):
+        """The reference's RecordEvent name and telemetry.span are one
+        object: one span implementation in the package."""
+        from paddle_tpu import profiler
+        assert profiler.RecordEvent is telemetry.span is telemetry.Span
+        telemetry.enable()
+        with profiler.RecordEvent('user_block'):
+            pass
+        assert [e['name'] for e in telemetry.events('span')] \
+            == ['user_block']
 
     def test_event_unlocked_is_ring_only(self, tmp_path):
         telemetry.enable(str(tmp_path))
@@ -226,6 +292,7 @@ class TestEmissionPoints:
 
     def test_checkpoint_save_restore_events(self, tmp_path):
         from paddle_tpu.distributed.checkpoint import CheckpointManager
+        telemetry.enable()      # spans keep records only when enabled
         tree = {'w': jnp.arange(8.0), 'step': jnp.asarray(3)}
         mgr = CheckpointManager(str(tmp_path), async_save=False)
         mgr.save(tree, 3)
