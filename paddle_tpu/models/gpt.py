@@ -184,8 +184,10 @@ class CausalSelfAttention(nn.Layer):
             # paged serving decode (serving/kv_cache.PagedCacheView):
             # ONE query token per sequence, k/v scattered into the
             # sequence's pool blocks through its block table, ragged
-            # per-sequence length masking — bit-exact vs the dense
-            # buffer below on shared prefixes (ops/paged_attention).
+            # per-sequence length masking — ops/paged_attention: a
+            # Pallas kernel over the pool in place where its gate
+            # allows, else the gather reference, bit-exact vs the
+            # dense buffer below on shared prefixes.
             if T != 1:
                 raise ValueError(
                     'paged cache views decode one token per step; '

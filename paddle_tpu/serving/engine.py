@@ -7,7 +7,12 @@ Ties the whole PR-7..11 runway into live decode throughput:
   ``tp`` mesh axis;
 - **ragged paged attention** (``ops/paged_attention.py``): the whole
   live set — every sequence at its own depth — decodes as ONE batched
-  step, bit-exact vs the dense cached path;
+  step.  On the chip a Pallas kernel reads each sequence's blocks in
+  place, up to its length (within 1e-5 of the reference; a row never
+  depends on its batch); everywhere else the gather-and-attend
+  reference runs, bit-exact vs the dense cached path.  ``report()``
+  says which (``paged_kernel``) and what share of the tables' blocks
+  the dispatches had to read (``kv_read_share``);
 - **continuous batching** (``scheduler.py``): admit/evict at every
   intervention, prefill into freed blocks, immediate backfill;
 - **fused multi-step decode**: ``decode_span=K`` scans K decode steps
@@ -37,9 +42,9 @@ Ties the whole PR-7..11 runway into live decode throughput:
   lifecycle trace (``serve_trace`` events).
 
 The decode math runs through the SAME ``GPTForCausalLM.prefill`` /
-``decode_step`` functional forwards that ``generate()`` uses, so
-greedy engine output is bit-exact with sequential batch-1 generate —
-pinned by test and by ``bench.py --serve-smoke``.
+``decode_step`` functional forwards that ``generate()`` uses, so on
+the reference path greedy engine output is bit-exact with sequential
+batch-1 generate — pinned by test and by ``bench.py --serve-smoke``.
 """
 import json
 import math
@@ -240,6 +245,12 @@ class ServingEngine:
         # serve_step of their own)
         self._pending_prefilled = 0
         self._pending_discarded = 0
+        # KV blocks the decode dispatches had to read against the
+        # blocks their tables hold (DecodePlan.kv_blocks, summed over
+        # token steps), and the paths the decode modules were built on
+        self.kv_blocks_read = 0
+        self.kv_blocks_table = 0
+        self._paged_paths = set()
         from ..telemetry.profile import step_profiler
         self._prof = step_profiler(profile=self.config.profile,
                                    name='serve')
@@ -528,12 +539,19 @@ class ServingEngine:
     def _decode_spec(self, S, K):
         """Same single-source contract as _prefill_spec, for the
         fused decode modules."""
+        import jax
         import jax.numpy as jnp
+        from ..ops.paged_attention import can_use_pallas
         fn = self._decode_build(S, K)
-        fp = self._fingerprint('serve-decode', batch=S, span=K,
-                               keys='per-request-pos')
         ks, vs = (tuple(x) for x in zip(*self.cache.pools))
         W = self.scheduler.table_width
+        # the same gate, on the same operands, that paged_attention
+        # asks when this module is traced
+        paged = 'kernel' if can_use_pallas(
+            ks[0], jax.ShapeDtypeStruct((S, W), jnp.int32)) else 'gather'
+        self._paged_paths.add(paged)
+        fp = self._fingerprint('serve-decode', batch=S, span=K,
+                               keys='per-request-pos', paged=paged)
         example = (self._params, self._buffers, ks, vs,
                    jnp.zeros((S, W), jnp.int32),
                    jnp.zeros((S,), jnp.int64),
@@ -697,7 +715,8 @@ class ServingEngine:
         if self._pending_prefilled or self._pending_discarded:
             self._emit_serve_step(admitted, t_start, live=0, batch=0,
                                   span=0, decoded=0, finished=0,
-                                  preempted=0)
+                                  preempted=0, kv_blocks_read=0,
+                                  kv_blocks_table=0)
 
     def _note_finished(self, finished, now):
         from .. import telemetry
@@ -812,10 +831,14 @@ class ServingEngine:
             n = int(valid.sum())
             self.decoded_tokens += n
             self.interventions += 1
+            read, table = plan.kv_blocks(self.config.block_size)
+            self.kv_blocks_read += read * plan.span
+            self.kv_blocks_table += table * plan.span
             self._emit_serve_step(
                 admitted, t_start, live=len(plan.requests),
                 batch=plan.batch, span=plan.span, decoded=n,
-                finished=len(finished), preempted=len(preempted))
+                finished=len(finished), preempted=len(preempted),
+                kv_blocks_read=read, kv_blocks_table=table)
             telemetry.add('serve.decoded_tokens', n)
         return progress + n
 
@@ -831,6 +854,7 @@ class ServingEngine:
         start = self._clock()
         fin0 = len(sched.finished)
         tok0 = self.decoded_tokens
+        kv0 = (self.kv_blocks_read, self.kv_blocks_table)
         # arrival offsets land on the engine clock at release time
         for r in pending:
             r.arrival_t = start + max(0.0, r.arrival_t)
@@ -871,13 +895,16 @@ class ServingEngine:
             if self._prof is not None:
                 self._prof.close()
         return self.report(wall_s=self.now_fn() - t0,
-                           finished_from=fin0, tokens_from=tok0)
+                           finished_from=fin0, tokens_from=tok0,
+                           kv_from=kv0)
 
     # -- reporting / stats ---------------------------------------------------
-    def report(self, wall_s=None, finished_from=0, tokens_from=0):
+    def report(self, wall_s=None, finished_from=0, tokens_from=0,
+               kv_from=(0, 0)):
         """Aggregate latency/throughput report — over the whole engine
-        life by default, or over one run()'s window (its requests and
-        its decoded tokens) when the slicing args are given."""
+        life by default, or over one run()'s window (its requests, its
+        decoded tokens and the KV blocks its dispatches read) when the
+        slicing args are given."""
         now = self._clock()
         sched = self.scheduler
         recs = [r.record(now) for r in sched.finished[finished_from:]]
@@ -916,7 +943,18 @@ class ServingEngine:
             'compile_count': self.compile_count,
             'modules': sorted(str(s) for s in self._modules),
             'audit': sched.audit(),
+            **self._kv_read_stats(*kv_from),
         }
+
+    def _kv_read_stats(self, read_from=0, table_from=0):
+        """What the decode dispatches read of what their tables hold
+        (blocks a token step, summed), and whether every decode module
+        built so far took the Pallas kernel."""
+        read = self.kv_blocks_read - read_from
+        table = self.kv_blocks_table - table_from
+        return {'kv_blocks_read': read, 'kv_blocks_table': table,
+                'kv_read_share': read / table if table else None,
+                'paged_kernel': self._paged_paths == {'kernel'}}
 
     def stats(self):
         return {'compile_count': self.compile_count,
@@ -924,7 +962,8 @@ class ServingEngine:
                 'interventions': self.interventions,
                 'decoded_tokens': self.decoded_tokens,
                 'free_blocks': self.cache.free_blocks,
-                'kv_frag': self.cache.frag_report()}
+                'kv_frag': self.cache.frag_report(),
+                **self._kv_read_stats()}
 
     # -- AOT / declared bucket set -------------------------------------------
     def bucket_set(self):

@@ -167,6 +167,18 @@ class DecodePlan:
         self.limit = np.zeros((self.batch,), np.int64)
         self.seed = np.zeros((self.batch,), np.int64)
 
+    def kv_blocks(self, block_size):
+        """(read, table): the KV blocks one token step of this plan
+        has to read — each active row's context at the span's end,
+        rounded up to blocks, and one block for every other row (its
+        length is its context plus the token just written) — against
+        the blocks its table holds, which is what a gather of the
+        whole table reads.  Host arithmetic; no device work."""
+        width = self.tables.shape[1]
+        ends = np.where(self.active, self.ctx + self.span, self.ctx + 1)
+        read = np.minimum(-(-ends // block_size), width)
+        return int(read.sum()), self.batch * width
+
 
 class ContinuousBatchingScheduler:
     """Admission/eviction policy over a :class:`PagedKVCache`.

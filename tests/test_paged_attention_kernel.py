@@ -1,0 +1,331 @@
+"""The Pallas paged decode kernel (ops/paged_attention.py:paged_decode)
+against the gather-and-attend reference, in Pallas interpret mode on
+the CPU: ragged lengths, both pool dtypes, every batch bucket, a row's
+independence of its batch, nothing read past a length, the gate, and
+the kernel compiled at the serving configuration's widths for a
+described v5e.
+"""
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
+
+import paddle_tpu as paddle  # noqa: F401
+from paddle_tpu.ops import _gating
+from paddle_tpu.ops import paged_attention as pa
+
+NH, BS, HD, WIDTH = 16, 16, 128, 6
+NUM_BLOCKS = 48
+FULL = WIDTH * BS
+# 1, one under, at and one over a block boundary, mid-table, the table
+LENGTHS = [1, BS - 1, BS, BS + 1, 3 * BS + 5, FULL]
+
+
+@pytest.fixture()
+def interpret_mode(monkeypatch):
+    monkeypatch.setattr(_gating, 'INTERPRET', True)
+    yield
+
+
+def _pools(dtype, seed=0, num_blocks=NUM_BLOCKS, hd=HD):
+    rs = np.random.RandomState(seed)
+    shape = (num_blocks, NH, BS, hd)
+    return (jnp.asarray(rs.randn(*shape), dtype),
+            jnp.asarray(rs.randn(*shape), dtype))
+
+
+def _rows(lengths, batch, seed=1, hd=HD):
+    """q, tables and lens for `batch` slots: the first rows hold
+    `lengths` on distinct blocks, the rest sit on the trash block with
+    length 1, as the scheduler pads a plan."""
+    rs = np.random.RandomState(seed)
+    tables = np.zeros((batch, WIDTH), np.int32)
+    lens = np.ones((batch,), np.int32)
+    free = iter(rs.permutation(np.arange(1, NUM_BLOCKS)))
+    for i, n in enumerate(lengths):
+        for b in range(-(-n // BS)):
+            tables[i, b] = next(free)
+        lens[i] = n
+    q = jnp.asarray(rs.randn(batch, NH, hd), jnp.float32)
+    return q, jnp.asarray(tables), jnp.asarray(lens)
+
+
+def _attend(*operands):
+    """paged_attention under a jit of its own: jax keys a trace by the
+    function, and the gate is asked when the function is traced."""
+    return jax.jit(lambda *a: pa.paged_attention(*a))(*operands)
+
+
+def _kernel(q, kp, vp, tables, lens):
+    assert pa.can_use_pallas(kp, tables)
+    return np.asarray(_attend(q, kp, vp, tables, lens))
+
+
+def _close(out, ref):
+    ref = np.asarray(ref)
+    assert np.isfinite(out).all()
+    assert np.abs(out - ref).max() <= 1e-5 * np.abs(ref).max()
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('length', LENGTHS)
+def test_one_row_matches_the_reference(interpret_mode, dtype, length):
+    kp, vp = _pools(dtype)
+    q, tables, lens = _rows([length], 1)
+    _close(_kernel(q, kp, vp, tables, lens),
+           pa._reference(q, kp, vp, tables, lens))
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('batch', [8, 32])
+def test_a_ragged_batch_matches_the_reference(interpret_mode, dtype,
+                                              batch):
+    """Every length at once, the other slots inactive on the trash
+    block; the output has the reference's dtype."""
+    kp, vp = _pools(dtype)
+    q, tables, lens = _rows(LENGTHS, batch)
+    out = _attend(q, kp, vp, tables, lens)
+    ref = pa._reference(q, kp, vp, tables, lens)
+    assert out.dtype == ref.dtype and out.shape == ref.shape
+    _close(np.asarray(out), ref)
+
+
+@pytest.mark.parametrize('blocks_a_round', [1, 4])
+def test_the_fetch_round_does_not_change_the_result(
+        interpret_mode, monkeypatch, blocks_a_round):
+    """Rounds of one block and rounds that do not divide the table."""
+    monkeypatch.setattr(pa, 'ROUND_BYTES',
+                        blocks_a_round * NH * BS * HD * 4)
+    kp, vp = _pools('float32')
+    q, tables, lens = _rows(LENGTHS, 8)
+    _close(_kernel(q, kp, vp, tables, lens),
+           pa._reference(q, kp, vp, tables, lens))
+
+
+@pytest.mark.parametrize('length', [1, BS + 1, 3 * BS + 5, FULL])
+def test_a_row_does_not_depend_on_its_batch(interpret_mode, length):
+    """Bitwise the same alone, in a batch of 8, in a batch of 32 and
+    with the other rows permuted: batch composition cannot perturb a
+    request's stream (serving/engine.py's promise)."""
+    kp, vp = _pools('float32')
+    others = [n for n in LENGTHS if n != length][:4]
+    q, tables, lens = _rows([length] + others, 32)
+    alone = _kernel(q[:1], kp, vp, tables[:1], lens[:1])[0]
+    in8 = _kernel(q[:8], kp, vp, tables[:8], lens[:8])[0]
+    in32 = _kernel(q, kp, vp, tables, lens)[0]
+    order = np.array([3, 7, 0, 5, 1, 6, 2, 4])
+    permuted = _kernel(q[order], kp, vp, tables[order], lens[order])[2]
+    for got in (in8, in32, permuted):
+        np.testing.assert_array_equal(alone, got)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_nothing_past_a_length_reaches_the_result(interpret_mode, dtype):
+    """NaN in every block a table names past its row's length, in the
+    tail of each row's last block and in every block no table names:
+    the output is finite and bitwise what it was."""
+    kp, vp = _pools(dtype)
+    q, tables, lens = _rows(LENGTHS, 8)
+    tables = np.array(tables)
+    named = set(tables.ravel())
+    spare = [b for b in range(1, NUM_BLOCKS) if b not in named]
+    for i, n in enumerate(LENGTHS):     # tables run on past the length
+        for b in range(-(-n // BS), WIDTH):
+            tables[i, b] = spare.pop()
+    clean = _kernel(q, kp, vp, jnp.asarray(tables), lens)
+    poison = np.zeros((NUM_BLOCKS, BS), bool)
+    poison[1:] = True                   # the trash block stays finite
+    for i, n in enumerate(LENGTHS):
+        for b in range(-(-n // BS)):
+            poison[tables[i, b]] = False
+        if n % BS:
+            poison[tables[i, n // BS], n % BS:] = True
+    mask = jnp.asarray(poison)[:, None, :, None]
+    kp2 = jnp.where(mask, jnp.nan, kp)
+    vp2 = jnp.where(mask, jnp.nan, vp)
+    assert bool(jnp.isnan(kp2).any())
+    out = _kernel(q, kp2, vp2, jnp.asarray(tables), lens)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(clean, out)
+
+
+def _path(kp, tables, hd=HD):
+    """'kernel' or 'gather': which path paged_attention traces."""
+    q, _, lens = _rows([BS], tables.shape[0], hd=hd)
+    text = str(jax.make_jaxpr(lambda *a: pa.paged_attention(*a))(
+        q, kp, kp, tables, lens))
+    return 'kernel' if 'pallas_call' in text else 'gather'
+
+
+class TestGate:
+    def test_the_serving_shape_takes_the_kernel(self, interpret_mode):
+        kp, _ = _pools('float32')
+        _, tables, _ = _rows([BS], 8)
+        assert pa.can_use_pallas(kp, tables)
+        assert _path(kp, tables) == 'kernel'
+
+    def test_a_cpu_without_interpret_mode_takes_the_reference(self):
+        assert not _gating.INTERPRET
+        kp, _ = _pools('float32')
+        _, tables, _ = _rows([BS], 8)
+        assert not pa.can_use_pallas(kp, tables)
+        assert _path(kp, tables) == 'gather'
+
+    def test_head_dim_64_takes_the_reference(self, interpret_mode):
+        kp, _ = _pools('float32', hd=64)
+        _, tables, _ = _rows([BS], 8)
+        assert not pa.can_use_pallas(kp, tables)
+        assert _path(kp, tables, hd=64) == 'gather'
+
+    def test_a_mesh_takes_the_reference(self, interpret_mode):
+        from paddle_tpu.distributed import env
+        kp, _ = _pools('float32')
+        _, tables, _ = _rows([BS], 8)
+        mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
+                    ('dp', 'tp'))
+        env.set_mesh(mesh)
+        try:
+            assert not pa.can_use_pallas(kp, tables)
+            assert _path(kp, tables) == 'gather'
+        finally:
+            env.set_mesh(None)
+        assert pa.can_use_pallas(kp, tables)
+
+    @pytest.mark.parametrize('why, kwargs', [
+        ('a block of 4 positions', dict(bs=4)),
+        ('12 heads', dict(nh=12)),
+        ('8 heads in bfloat16', dict(nh=8, dtype='bfloat16')),
+        ('a float16 pool', dict(dtype='float16')),
+        ('a block past one fetch round', dict(nh=32, bs=64, hd=256)),
+        ('tables past SMEM', dict(batch=512, width=128)),
+    ])
+    def test_other_shapes_take_the_reference(self, interpret_mode, why,
+                                             kwargs):
+        kw = dict(nh=NH, bs=BS, hd=HD, dtype='float32', batch=8,
+                  width=WIDTH)
+        kw.update(kwargs)
+        pool = jax.ShapeDtypeStruct(
+            (NUM_BLOCKS, kw['nh'], kw['bs'], kw['hd']),
+            jnp.dtype(kw['dtype']))
+        tables = jax.ShapeDtypeStruct((kw['batch'], kw['width']),
+                                      jnp.int32)
+        assert not pa.can_use_pallas(pool, tables), why
+
+
+# -- the kernel at the serve configuration's widths, for a described chip ----
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('batch', [8, 32])
+def test_mosaic_compiles_the_kernel_at_serving_widths(one_chip, dtype,
+                                                      batch):
+    """Cerebras-GPT-1.3B's decode: 16 heads of 128, blocks of 16, a
+    pool of 832 blocks, tables of 128.  Compiled, not run."""
+    from jax.experimental.compilation_cache import compilation_cache
+    nb, nh, bs, hd, width = 832, 16, 16, 128, 128
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        pool, tables = sd((nb, nh, bs, hd), dtype), sd((batch, width),
+                                                       'int32')
+        compiled = pa._paged_decode.lower(
+            sd((batch, nh, hd), 'float32'), pool, pool, tables,
+            sd((batch,), 'int32'),
+            chunk=pa._blocks_a_round(pool, tables)).compile()
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+    text = compiled.as_text()
+    assert 'tpu_custom_call' in text and 'paged_decode' in text
+
+
+# -- the engine on the kernel path, and the counter of what it reads ---------
+def _engine(**config):
+    from paddle_tpu.models.gpt import gpt_tiny
+    from paddle_tpu.serving import ServeConfig, ServingEngine
+    paddle.seed(7)
+    model = gpt_tiny(num_layers=2, hidden_size=8 * HD, num_heads=8,
+                     max_seq_len=64)
+    model.eval()
+    kw = dict(block_size=8, max_slots=4, decode_span=2,
+              prompt_buckets=(8, 16), batch_buckets=(1, 2, 4),
+              prefill_batch=1, max_model_len=64, temperature=0.0)
+    kw.update(config)
+    return ServingEngine(model, ServeConfig(**kw))
+
+
+def _serve(prompts, new_tokens):
+    eng = _engine()
+    for i, (p, n) in enumerate(zip(prompts, new_tokens)):
+        eng.submit(np.asarray(p), n, rid=f'r{i}')
+    report = eng.run()
+    assert report['audit'] == []
+    return {r.rid: list(r.tokens) for r in eng.scheduler.finished}, report
+
+
+def test_the_engine_decodes_the_same_tokens_on_either_path(monkeypatch):
+    rs = np.random.RandomState(3)
+    prompts = [rs.randint(1, 200, size=n) for n in (5, 11, 3, 16, 9)]
+    new_tokens = [7, 12, 9, 5, 17]
+    gather, report = _serve(prompts, new_tokens)
+    assert report['paged_kernel'] is False
+    monkeypatch.setattr(_gating, 'INTERPRET', True)
+    kernel, report = _serve(prompts, new_tokens)
+    assert report['paged_kernel'] is True
+    assert kernel == gather
+    assert [len(kernel[f'r{i}']) for i in range(5)] == new_tokens
+    for name in ('kv_blocks_read', 'kv_blocks_table', 'kv_read_share'):
+        assert name in report
+    assert 0 < report['kv_read_share'] < 1
+    assert report['kv_read_share'] == \
+        report['kv_blocks_read'] / report['kv_blocks_table']
+
+
+def test_the_engine_counts_the_blocks_a_known_plan_reads(interpret_mode):
+    """One request of 5 positions and 7 tokens, blocks of 8, spans of
+    2, tables of 8 blocks.  Its three dispatches start at contexts 5,
+    7 and 9 and end at 7, 9 and 11: 1, 2 and 2 blocks a token step,
+    two token steps each, of tables of 8."""
+    from paddle_tpu import telemetry
+    telemetry.reset()
+    eng = _engine()
+    eng.submit(np.arange(1, 6), 7)
+    report = eng.run()
+    assert report['interventions'] == 3
+    assert report['kv_blocks_read'] == 2 * (1 + 2 + 2)
+    assert report['kv_blocks_table'] == 2 * 3 * 8
+    assert report['kv_read_share'] == 10 / 48
+    assert report['paged_kernel'] is True
+    steps = [e for e in telemetry.events('serve_step') if e['span']]
+    assert [e['kv_blocks_read'] for e in steps] == [1, 2, 2]
+    assert [e['kv_blocks_table'] for e in steps] == [8, 8, 8]
+    stats = eng.stats()
+    assert (stats['kv_blocks_read'], stats['kv_blocks_table'],
+            stats['paged_kernel']) == (10, 48, True)
+
+
+def test_a_plan_counts_one_block_for_a_row_that_is_not_active():
+    from paddle_tpu.serving.scheduler import DecodePlan
+    plan = DecodePlan([], 4, 8, span=2)
+    plan.ctx[:2] = [5, 17]
+    plan.active[:2] = True
+    # ends at 7 and 19: 1 and 3 blocks of 8; the two padding rows one
+    assert plan.kv_blocks(8) == (1 + 3 + 1 + 1, 4 * 8)
+    plan.ctx[1] = 70            # past the table: no more than it holds
+    assert plan.kv_blocks(8) == (1 + 8 + 1 + 1, 4 * 8)
