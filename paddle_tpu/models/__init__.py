@@ -10,9 +10,12 @@ from .bert import (  # noqa: F401
 from .ernie import (  # noqa: F401
     ErnieConfig, ErnieModel, ErnieForPretraining, ernie_base,
     ernie_tiny)
+from .retention import (  # noqa: F401
+    RetentionConfig, RetentionForCausalLM, retention_tiny)
 
 __all__ = ['GPTConfig', 'GPT', 'GPTForCausalLM', 'gpt_tiny', 'gpt_small',
            'gpt_1p3b', 'gpt_moe_tiny', 'WideDeep', 'DeepFM', 'BertConfig', 'BertModel',
            'BertForPretraining', 'bert_tiny', 'bert_base', 'bert_large',
            'ErnieConfig', 'ErnieModel', 'ErnieForPretraining',
-           'ernie_base', 'ernie_tiny']
+           'ernie_base', 'ernie_tiny', 'RetentionConfig',
+           'RetentionForCausalLM', 'retention_tiny']

@@ -14,10 +14,15 @@ AOT-compiles the whole surface at deploy time.
     eng.submit(prompt_ids, max_new_tokens=64)
     report = eng.run()
 
+A model whose memory is one recurrent state a sequence
+(``models/retention.py``: ``serving_state = 'recurrent'``) is served
+by the same engine over a ``RecurrentStateCache``.
+
 Additive: ``GPTForCausalLM.generate`` is unchanged (and bit-exact
 with the engine's greedy decode by test).
 """
-from .kv_cache import PagedKVCache, PagedCacheView   # noqa: F401
+from .kv_cache import (                              # noqa: F401
+    PagedKVCache, PagedCacheView, RecurrentStateCache, RecurrentStateView)
 from .scheduler import (                             # noqa: F401
     ContinuousBatchingScheduler, DecodePlan, Request, RejectReason,
     RejectedRequest)
@@ -25,7 +30,8 @@ from .loadgen import poisson_requests                # noqa: F401
 from .engine import (                                # noqa: F401
     DecodeAuditLayer, ServeConfig, ServingEngine, request_seed)
 
-__all__ = ['PagedKVCache', 'PagedCacheView', 'Request', 'DecodePlan',
+__all__ = ['PagedKVCache', 'PagedCacheView', 'RecurrentStateCache',
+           'RecurrentStateView', 'Request', 'DecodePlan',
            'ContinuousBatchingScheduler', 'poisson_requests',
            'ServeConfig', 'ServingEngine', 'DecodeAuditLayer',
            'RejectReason', 'RejectedRequest', 'request_seed']

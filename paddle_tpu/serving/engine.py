@@ -1,4 +1,8 @@
-"""Serving engine: continuous batching over the paged KV cache.
+"""Serving engine: continuous batching over a per-sequence cache: the
+paged KV pool, or for a model whose memory is one recurrent state a
+sequence (`model.serving_state == 'recurrent'`) a `RecurrentStateCache`.
+The model says which; the scheduler, the modules' scaffolding, `step`,
+`run` and `warmup` are one code path over both.
 
 Ties the whole PR-7..11 runway into live decode throughput:
 
@@ -56,7 +60,8 @@ import numpy as np
 from .. import nn
 from ..core import compile_cache as _cc
 from ..resilience.watchdog import resolve_watchdog
-from .kv_cache import PagedKVCache, PagedCacheView, blocks_for
+from .kv_cache import PagedKVCache, PagedCacheView, \
+    RecurrentStateCache, blocks_for
 from .scheduler import ContinuousBatchingScheduler, Request, \
     RejectedRequest
 
@@ -173,7 +178,8 @@ class ServeConfig:
 
 
 class ServingEngine:
-    """Continuous-batching decode over one ``GPTForCausalLM``.
+    """Continuous-batching decode over one causal LM with ``prefill``
+    and ``decode_step`` (``GPTForCausalLM``, ``RetentionForCausalLM``).
 
     ::
 
@@ -188,7 +194,7 @@ class ServingEngine:
     def __init__(self, model, config=None, now_fn=time.monotonic,
                  serve_metrics_port=None, live_window_s=60.0):
         cfg = model.config
-        if cfg.moe_num_experts > 0:
+        if getattr(cfg, 'moe_num_experts', 0) > 0:
             raise ValueError('serving engine requires a non-MoE model '
                              '(see GPTForCausalLM._decode_bucket)')
         model.eval()
@@ -220,11 +226,20 @@ class ServingEngine:
         self._epoch = now_fn()
         self._clock = lambda: self.now_fn() - self._epoch
         self._params, self._buffers = model.functional_state()
-        nh = cfg.num_heads
-        hd = cfg.hidden_size // nh
-        self.cache = PagedKVCache(
-            cfg.num_layers, nh, hd, block_size=self.config.block_size,
-            num_blocks=self.config.num_blocks)
+        # the cache follows from what the model says it needs
+        self.recurrent = getattr(model, 'serving_state',
+                                 'paged') == 'recurrent'
+        if self.recurrent:
+            self.cache = RecurrentStateCache(
+                **model.state_spec(), slots=self.config.max_slots,
+                max_model_len=self.config.max_model_len)
+        else:
+            nh = cfg.num_heads
+            hd = cfg.hidden_size // nh
+            self.cache = PagedKVCache(
+                cfg.num_layers, nh, hd,
+                block_size=self.config.block_size,
+                num_blocks=self.config.num_blocks)
         self.scheduler = ContinuousBatchingScheduler(
             self.cache, max_slots=self.config.max_slots,
             batch_buckets=self.config.batch_buckets,
@@ -250,7 +265,10 @@ class ServingEngine:
         # token steps), and the paths the decode modules were built on
         self.kv_blocks_read = 0
         self.kv_blocks_table = 0
-        self._paged_paths = set()
+        self._decode_paths = set()
+        # a recurrent cache: live rows x token steps (each rewrites one
+        # slot of every layer's state)
+        self.state_rows_updated = 0
         from ..telemetry.profile import step_profiler
         self._prof = step_profiler(profile=self.config.profile,
                                    name='serve')
@@ -413,59 +431,52 @@ class ServingEngine:
     def _prefill_build(self, P, B):
         """The prefill module body for one (prompt bucket, chunk)
         pair: ONE cached forward over B padded prompts, per-row first
-        tokens sampled at each row's true length, every row's
-        block-rounded KV scattered through its own block-table row."""
+        tokens sampled at each row's true length, every row's cache
+        written where `where` says (the paged pool: its block-rounded
+        KV through its own block-table row; a recurrent cache: its
+        final state into its slot)."""
         import jax
         import jax.numpy as jnp
-        from ..parallel.api import maybe_shard
-        from ..ops.paged_attention import POOL_SPEC
         model = self.model
-        bs = self.config.block_size
-        nblk = blocks_for(P, bs)
-        Pc = nblk * bs
+        cache = self.cache
         sample = self._sample_fn()
-        nh = model.config.num_heads
-        hd = model.config.hidden_size // nh
 
         @jax.named_scope('serve.prefill')
-        def prefill_fn(params, buffers, ids, t0, ks, vs, block_ids,
+        def prefill_fn(params, buffers, ids, t0, first, second, where,
                        seeds):
-            caches = model.init_decode_caches(B, Pc)
+            # the cache's arrays are a pair of per-layer tuples (k and
+            # v pools; S and z states), two arguments as the paged
+            # modules always had them
+            caches = cache.prefill_caches(model, B, P, t0)
             logits, caches = model.prefill(
                 params, buffers, ids, jnp.zeros((), jnp.int32), caches)
             lg = logits.value if hasattr(logits, 'value') else logits
-            rows = jnp.take_along_axis(
-                lg, (t0 - 1)[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]                      # [B, V]
+            if lg.shape[1] == 1 and P > 1:
+                # a model may return the last true position's logits
+                # alone (a large head over every prompt position is
+                # most of a prefill)
+                rows = lg[:, 0]
+            else:
+                rows = jnp.take_along_axis(
+                    lg, (t0 - 1)[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0]                      # [B, V]
             # the first token's absolute position is t0-1 — the same
             # position generate's prefill samples at
             tok = sample(rows, seeds,
                          (t0 - 1).astype(jnp.int64))  # [B]
-            new_ks, new_vs = [], []
-            for (kbuf, vbuf), kp, vp in zip(caches, ks, vs):
-                kbuf = kbuf.value if hasattr(kbuf, 'value') else kbuf
-                vbuf = vbuf.value if hasattr(vbuf, 'value') else vbuf
-                # [B, nh, Pc, hd] -> [B, nblk, nh, bs, hd] block rows
-                kb = jnp.transpose(
-                    kbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 1, 3, 4))
-                vb = jnp.transpose(
-                    vbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 1, 3, 4))
-                kp = maybe_shard(kp, POOL_SPEC)
-                vp = maybe_shard(vp, POOL_SPEC)
-                new_ks.append(kp.at[block_ids].set(
-                    kb.astype(kp.dtype)))
-                new_vs.append(vp.at[block_ids].set(
-                    vb.astype(vp.dtype)))
-            return tok, tuple(new_ks), tuple(new_vs)
+            first, second = cache.store_prefill((first, second), caches,
+                                                where)
+            return tok, first, second
 
-        return prefill_fn, nblk
+        return prefill_fn
 
     def _prefill_spec(self, P, B):
         """ONE source of truth for a prefill module's (fn, fp,
         example args, name, donate) — _prefill_module compiles it,
         precompile() AOT-exports it; they can never drift apart."""
         import jax.numpy as jnp
-        fn, nblk = self._prefill_build(P, B)
+        fn = self._prefill_build(P, B)
+        nblk = blocks_for(P, self.config.block_size)
         # keys= marks the per-request-position sampling discipline:
         # the module signature changed from one batch PRNGKey to
         # per-row seeds, and _fingerprint does not hash example avals
@@ -473,11 +484,10 @@ class ServingEngine:
         # deserialize against the new call signature
         fp = self._fingerprint('serve-prefill', bucket=P, nblk=nblk,
                                chunk=B, keys='per-request-pos')
-        ks, vs = (tuple(x) for x in zip(*self.cache.pools))
         example = (self._params, self._buffers,
                    jnp.zeros((B, P), jnp.int64),
-                   jnp.full((B,), P, jnp.int32), ks, vs,
-                   jnp.zeros((B, nblk), jnp.int32),
+                   jnp.full((B,), P, jnp.int32), *self.cache.arrays(),
+                   jnp.asarray(self.cache.prefill_where((), B, P)),
                    jnp.zeros((B,), jnp.int64))
         return fn, fp, example, f'serve.prefill[{P}x{B}]', (4, 5)
 
@@ -493,23 +503,19 @@ class ServingEngine:
         scheduler interventions only happen between these modules."""
         import jax
         import jax.numpy as jnp
-        from ..parallel.api import maybe_shard
-        from ..ops.paged_attention import POOL_SPEC
         model = self.model
-        L = model.config.num_layers
+        cache = self.cache
         sample = self._sample_fn()
         eos = self.config.eos_id
 
         @jax.named_scope('serve.decode')
-        def decode_fn(params, buffers, ks, vs, tables, ctx, tok,
+        def decode_fn(params, buffers, first, second, where, ctx, tok,
                       active, limit, seeds):
-            ks = tuple(maybe_shard(k, POOL_SPEC) for k in ks)
-            vs = tuple(maybe_shard(v, POOL_SPEC) for v in vs)
+            arrays = cache.constrain((first, second))
 
             def body(carry, _):
-                tok, ctx, active, ks, vs = carry
-                views = [PagedCacheView(ks[l], vs[l], tables, ctx,
-                                        ctx + 1) for l in range(L)]
+                tok, ctx, active, arrays = carry
+                views = cache.decode_views(arrays, where, ctx, active)
                 logits, views = model.decode_step(
                     params, buffers, tok[:, None], ctx, views)
                 lg = logits.value if hasattr(logits, 'value') else logits
@@ -524,36 +530,30 @@ class ServingEngine:
                 nactive = active & (nctx < limit)
                 if eos is not None:
                     nactive = nactive & (ntok != eos)
-                ks = tuple(v.k_pool for v in views)
-                vs = tuple(v.v_pool for v in views)
-                return (ntok, nctx, nactive, ks, vs), \
+                return (ntok, nctx, nactive, cache.arrays_of(views)), \
                     (ntok, emitted_valid)
 
-            (tok, ctx, active, ks, vs), (toks, valid) = \
-                jax.lax.scan(body, (tok, ctx, active, ks, vs),
+            (tok, ctx, active, arrays), (toks, valid) = \
+                jax.lax.scan(body, (tok, ctx, active, arrays),
                              None, length=K)
-            return toks, valid, ks, vs
+            return (toks, valid, *arrays)
 
         return decode_fn
 
     def _decode_spec(self, S, K):
         """Same single-source contract as _prefill_spec, for the
         fused decode modules."""
-        import jax
         import jax.numpy as jnp
-        from ..ops.paged_attention import can_use_pallas
         fn = self._decode_build(S, K)
-        ks, vs = (tuple(x) for x in zip(*self.cache.pools))
+        arrays = self.cache.arrays()
         W = self.scheduler.table_width
-        # the same gate, on the same operands, that paged_attention
-        # asks when this module is traced
-        paged = 'kernel' if can_use_pallas(
-            ks[0], jax.ShapeDtypeStruct((S, W), jnp.int32)) else 'gather'
-        self._paged_paths.add(paged)
+        path = self.cache.decode_path(self.model, S, W)
+        self._decode_paths.add(path)
+        extra = {self.cache.path_key: path}
+        where = jnp.asarray(self.cache.idle_where(S, W))
         fp = self._fingerprint('serve-decode', batch=S, span=K,
-                               keys='per-request-pos', paged=paged)
-        example = (self._params, self._buffers, ks, vs,
-                   jnp.zeros((S, W), jnp.int32),
+                               keys='per-request-pos', **extra)
+        example = (self._params, self._buffers, *arrays, where,
                    jnp.zeros((S,), jnp.int64),
                    jnp.zeros((S,), jnp.int64),
                    jnp.zeros((S,), bool),
@@ -631,29 +631,29 @@ class ServingEngine:
         un-synced first-token device array [chunk bucket]."""
         import jax.numpy as jnp
         P = reqs[0].prompt_bucket
-        nblk = blocks_for(P, self.config.block_size)
         B = self._chunk_bucket(len(reqs))
         mod = self._prefill_module(P, B)
         ids = np.zeros((B, P), np.int64)
         t0s = np.ones((B,), np.int32)      # padding rows sample row 0
-        blocks = np.zeros((B, nblk), np.int32)   # padding -> trash
         seeds = np.zeros((B,), np.int64)
         for i, req in enumerate(reqs):
             ids[i, :req.prompt.size] = req.prompt
             t0s[i] = req.prompt.size
-            blocks[i] = self.cache.owned(req.rid)[:nblk]
             seeds[i] = req.seed or 0
-        ks, vs = (tuple(x) for x in zip(*self.cache.pools))
+        # padding rows write where nothing is kept (the trash block)
+        where = self.cache.prefill_where([r.rid for r in reqs], B, P)
         self._prefills += 1
-        tok, ks, vs = mod(self._params, self._buffers,
-                          jnp.asarray(ids), jnp.asarray(t0s),
-                          ks, vs, jnp.asarray(blocks),
-                          jnp.asarray(seeds))
-        self.cache.set_pools(list(zip(ks, vs)))
+        tok, *arrays = mod(self._params, self._buffers,
+                           jnp.asarray(ids), jnp.asarray(t0s),
+                           *self.cache.arrays(), jnp.asarray(where),
+                           jnp.asarray(seeds))
+        self.cache.set_arrays(arrays)
         now = self._clock()
-        for req in reqs:
+        for i, req in enumerate(reqs):
+            # a recurrent cache: the device row that holds the state
             req.trace_note('prefill', now, bucket=P, chunk=B,
-                           dispatch=self._prefills)
+                           dispatch=self._prefills,
+                           slot=int(where[i]) if self.recurrent else None)
         return tok
 
     def _prefill_finish(self, req, tok):
@@ -673,13 +673,13 @@ class ServingEngine:
     def _decode(self, plan):
         import jax.numpy as jnp
         mod = self._decode_module(plan.batch, plan.span)
-        ks, vs = (tuple(x) for x in zip(*self.cache.pools))
-        toks, valid, ks, vs = mod(
-            self._params, self._buffers, ks, vs,
-            jnp.asarray(plan.tables), jnp.asarray(plan.ctx),
+        toks, valid, *arrays = mod(
+            self._params, self._buffers, *self.cache.arrays(),
+            jnp.asarray(self.cache.decode_where(plan)),
+            jnp.asarray(plan.ctx),
             jnp.asarray(plan.tok), jnp.asarray(plan.active),
             jnp.asarray(plan.limit), jnp.asarray(plan.seed))
-        self.cache.set_pools(list(zip(ks, vs)))
+        self.cache.set_arrays(arrays)
         return toks, valid
 
     def _emit_serve_step(self, admitted, t_start, **fields):
@@ -834,6 +834,9 @@ class ServingEngine:
             read, table = plan.kv_blocks(self.config.block_size)
             self.kv_blocks_read += read * plan.span
             self.kv_blocks_table += table * plan.span
+            if self.recurrent:
+                # every valid token is one live row's state rewritten
+                self.state_rows_updated += n
             self._emit_serve_step(
                 admitted, t_start, live=len(plan.requests),
                 batch=plan.batch, span=plan.span, decoded=n,
@@ -855,6 +858,8 @@ class ServingEngine:
         fin0 = len(sched.finished)
         tok0 = self.decoded_tokens
         kv0 = (self.kv_blocks_read, self.kv_blocks_table)
+        state0 = (self.state_rows_updated,
+                  sched.counters.get('decode_steps', 0))
         # arrival offsets land on the engine clock at release time
         for r in pending:
             r.arrival_t = start + max(0.0, r.arrival_t)
@@ -896,11 +901,11 @@ class ServingEngine:
                 self._prof.close()
         return self.report(wall_s=self.now_fn() - t0,
                            finished_from=fin0, tokens_from=tok0,
-                           kv_from=kv0)
+                           kv_from=kv0, state_from=state0)
 
     # -- reporting / stats ---------------------------------------------------
     def report(self, wall_s=None, finished_from=0, tokens_from=0,
-               kv_from=(0, 0)):
+               kv_from=(0, 0), state_from=(0, 0)):
         """Aggregate latency/throughput report — over the whole engine
         life by default, or over one run()'s window (its requests, its
         decoded tokens and the KV blocks its dispatches read) when the
@@ -944,7 +949,22 @@ class ServingEngine:
             'modules': sorted(str(s) for s in self._modules),
             'audit': sched.audit(),
             **self._kv_read_stats(*kv_from),
+            **self._state_stats(*state_from),
         }
+
+    def _state_stats(self, rows_from=0, steps_from=0):
+        """A recurrent cache's counters (nothing for the paged pool):
+        live rows x token steps of the decode dispatches, the bytes the
+        state holds, and whether every decode module built so far took
+        the Pallas update."""
+        if not self.recurrent:
+            return {}
+        return {'state_rows_updated': self.state_rows_updated - rows_from,
+                'state_bytes': self.cache.state_bytes,
+                'state_bytes_per_row': self.cache.bytes_per_slot,
+                'token_steps': self.scheduler.counters.get(
+                    'decode_steps', 0) - steps_from,
+                'state_kernel': self._decode_paths == {'kernel'}}
 
     def _kv_read_stats(self, read_from=0, table_from=0):
         """What the decode dispatches read of what their tables hold
@@ -954,7 +974,8 @@ class ServingEngine:
         table = self.kv_blocks_table - table_from
         return {'kv_blocks_read': read, 'kv_blocks_table': table,
                 'kv_read_share': read / table if table else None,
-                'paged_kernel': self._paged_paths == {'kernel'}}
+                'paged_kernel': not self.recurrent
+                and self._decode_paths == {'kernel'}}
 
     def stats(self):
         return {'compile_count': self.compile_count,
@@ -963,7 +984,7 @@ class ServingEngine:
                 'decoded_tokens': self.decoded_tokens,
                 'free_blocks': self.cache.free_blocks,
                 'kv_frag': self.cache.frag_report(),
-                **self._kv_read_stats()}
+                **self._kv_read_stats(), **self._state_stats()}
 
     # -- AOT / declared bucket set -------------------------------------------
     def bucket_set(self):
@@ -988,29 +1009,27 @@ class ServingEngine:
         which buckets the live traffic hits.  Returns stats()."""
         import jax.numpy as jnp
         params, buffers = self._params, self._buffers
+        cache = self.cache
         for P in self.config.prompt_buckets:
-            nblk = blocks_for(P, self.config.block_size)
             for B in _pow2_chain(1, self.config.prefill_batch):
                 mod = self._prefill_module(P, B)
-                ks, vs = (tuple(x) for x in zip(*self.cache.pools))
-                tok, ks, vs = mod(
+                tok, *arrays = mod(
                     params, buffers, jnp.zeros((B, P), jnp.int64),
-                    jnp.full((B,), P, jnp.int32), ks, vs,
-                    jnp.zeros((B, nblk), jnp.int32),
+                    jnp.full((B,), P, jnp.int32), *cache.arrays(),
+                    jnp.asarray(cache.prefill_where((), B, P)),
                     jnp.zeros((B,), jnp.int64))
-                self.cache.set_pools(list(zip(ks, vs)))
+                cache.set_arrays(arrays)
                 np.asarray(tok)
         W = self.scheduler.table_width
         for S in self.config.batch_buckets:
             mod = self._decode_module(S, self.config.decode_span)
-            ks, vs = (tuple(x) for x in zip(*self.cache.pools))
-            toks, _valid, ks, vs = mod(
-                params, buffers, ks, vs,
-                jnp.zeros((S, W), jnp.int32),
+            toks, _valid, *arrays = mod(
+                params, buffers, *cache.arrays(),
+                jnp.asarray(cache.idle_where(S, W)),
                 jnp.zeros((S,), jnp.int64), jnp.zeros((S,), jnp.int64),
                 jnp.zeros((S,), bool), jnp.zeros((S,), jnp.int64),
                 jnp.zeros((S,), jnp.int64))
-            self.cache.set_pools(list(zip(ks, vs)))
+            cache.set_arrays(arrays)
             np.asarray(toks)
         if self.live is not None:
             # every declared module just built+ran: compiles from here

@@ -1,5 +1,14 @@
 """Paged KV cache: fixed-size blocks in one preallocated pool.
 
+Two kinds of per-sequence memory live here, behind one set of calls
+(`ensure`, `free_seq`, `owned`, `owners`, `table_row`, `audit`, the
+counts of free and total; on the device side `arrays`, `set_arrays`,
+`prefill_caches`, `store_prefill`, `decode_views`, `arrays_of`), so
+that the scheduler and the engine keep one code path: the paged KV
+pool below, blocks that grow with a sequence's length, and
+`RecurrentStateCache` at the end of this file, one state of fixed size
+a sequence.  The model says which it needs (`model.serving_state`).
+
 The serving engine never allocates per-sequence KV buffers.  Instead
 each layer owns ONE device pool ``[num_blocks, num_heads, block_size,
 head_dim]`` allocated once at engine construction, and every live
@@ -22,10 +31,11 @@ the attention weights, applied by the engine's compiled steps via
 ``maybe_shard`` when a mesh is installed.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-__all__ = ['PagedKVCache', 'PagedCacheView', 'TRASH_BLOCK',
-           'blocks_for']
+__all__ = ['PagedKVCache', 'PagedCacheView', 'RecurrentStateCache',
+           'RecurrentStateView', 'TRASH_BLOCK', 'blocks_for']
 
 TRASH_BLOCK = 0
 
@@ -82,7 +92,7 @@ class PagedKVCache:
 
     Device state: ``pools`` — one (k_pool, v_pool) pair per layer,
     updated functionally by the engine after each compiled step
-    (``set_pools``).  Host state: a free list and the per-sequence
+    (``set_arrays``).  Host state: a free list and the per-sequence
     owned-block lists.  Allocation never partially succeeds: asking
     for more blocks than are free changes nothing and returns False.
     """
@@ -119,6 +129,10 @@ class PagedKVCache:
 
     def owned(self, seq_id):
         return list(self._owned.get(seq_id, ()))
+
+    def owners(self):
+        """The sequences that own at least one block."""
+        return [sid for sid, blocks in self._owned.items() if blocks]
 
     def can_cover(self, seq_id, num_positions):
         need = blocks_for(num_positions, self.block_size) \
@@ -244,10 +258,316 @@ class PagedKVCache:
         return problems
 
     # -- device pools -------------------------------------------------------
-    def set_pools(self, pools):
+    # What the engine's compiled modules see of the cache: its arrays
+    # as ONE pytree (donated in, returned whole), and how a prefill
+    # fills them and a decode step views them.  `where` is the block
+    # ids [B, blocks of the bucket] of a prefill and the tables
+    # [S, table width] of a decode.
+    def arrays(self):
+        ks, vs = (tuple(x) for x in zip(*self.pools))
+        return ks, vs
+
+    def set_arrays(self, arrays):
         """Functional write-back after a compiled step."""
-        self.pools = list(pools)
+        self.pools = list(zip(*arrays))
+
+    def prefill_where(self, seq_ids, rows, bucket):
+        """Block ids [rows, blocks of the bucket] for a prefill chunk;
+        rows past `seq_ids` are padding and point at the trash block."""
+        nblk = blocks_for(bucket, self.block_size)
+        where = np.zeros((rows, nblk), np.int32)
+        for i, sid in enumerate(seq_ids):
+            where[i] = self.owned(sid)[:nblk]
+        return where
+
+    def decode_where(self, plan):
+        return plan.tables
+
+    def idle_where(self, batch, width):
+        """A decode's `where` that touches nothing kept (warm-up and
+        example arguments): every row on the trash block."""
+        return np.zeros((batch, width), np.int32)
+
+    # what the decode modules' fingerprints call their attention path
+    path_key = 'paged'
+
+    def decode_path(self, model, batch, width):
+        """'kernel' or 'gather': the same gate, on the same operands,
+        that paged_attention asks when a decode module is traced."""
+        from ..ops.paged_attention import can_use_pallas
+        del model
+        return 'kernel' if can_use_pallas(
+            self.pools[0][0], jax.ShapeDtypeStruct(
+                (batch, width), jnp.int32)) else 'gather'
+
+    def prefill_caches(self, model, rows, bucket, lengths):
+        """Dense per-layer buffers for the bucket, block-rounded."""
+        del lengths
+        nblk = blocks_for(bucket, self.block_size)
+        return model.init_decode_caches(rows, nblk * self.block_size)
+
+    def store_prefill(self, arrays, caches, where):
+        """Every row's block-rounded KV scattered through its own
+        block-table row."""
+        from ..parallel.api import maybe_shard
+        from ..ops.paged_attention import POOL_SPEC
+        ks, vs = arrays
+        B, nblk = where.shape
+        nh, bs, hd = self.num_heads, self.block_size, self.head_dim
+        new_ks, new_vs = [], []
+        for (kbuf, vbuf), kp, vp in zip(caches, ks, vs):
+            kbuf = kbuf.value if hasattr(kbuf, 'value') else kbuf
+            vbuf = vbuf.value if hasattr(vbuf, 'value') else vbuf
+            # [B, nh, Pc, hd] -> [B, nblk, nh, bs, hd] block rows
+            kb = jnp.transpose(
+                kbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 1, 3, 4))
+            vb = jnp.transpose(
+                vbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 1, 3, 4))
+            kp = maybe_shard(kp, POOL_SPEC)
+            vp = maybe_shard(vp, POOL_SPEC)
+            new_ks.append(kp.at[where].set(kb.astype(kp.dtype)))
+            new_vs.append(vp.at[where].set(vb.astype(vp.dtype)))
+        return tuple(new_ks), tuple(new_vs)
+
+    def constrain(self, arrays):
+        from ..parallel.api import maybe_shard
+        from ..ops.paged_attention import POOL_SPEC
+        ks, vs = arrays
+        return (tuple(maybe_shard(k, POOL_SPEC) for k in ks),
+                tuple(maybe_shard(v, POOL_SPEC) for v in vs))
+
+    def decode_views(self, arrays, where, ctx, active):
+        del active
+        ks, vs = arrays
+        return [PagedCacheView(k, v, where, ctx, ctx + 1)
+                for k, v in zip(ks, vs)]
+
+    def arrays_of(self, views):
+        return (tuple(v.k_pool for v in views),
+                tuple(v.v_pool for v in views))
 
     def layer_view(self, layer, block_tables, slots, lens):
         k, v = self.pools[layer]
         return PagedCacheView(k, v, block_tables, slots, lens)
+
+
+# -- one state of fixed size a sequence -----------------------------------------
+@jax.tree_util.register_pytree_node_class
+class RecurrentStateView:
+    """One layer's recurrent state as a compiled module sees it.
+
+    In a decode step: the whole arrays `S [slots, ...]` and `z`, the
+    rows' `slots` (distinct) and which rows are `active`.  In a
+    prefill: `S` and `z` are None on the way in (the empty state) and
+    the rows' final states on the way out, `lengths` the rows' true
+    lengths.  `updated()` is the functional write-back.
+    """
+
+    def __init__(self, S=None, z=None, slots=None, active=None,
+                 lengths=None):
+        self.S, self.z = S, z
+        self.slots, self.active, self.lengths = slots, active, lengths
+
+    def updated(self, S, z):
+        return RecurrentStateView(S, z, self.slots, self.active,
+                                  self.lengths)
+
+    def tree_flatten(self):
+        return ((self.S, self.z, self.slots, self.active,
+                 self.lengths), None)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        del aux
+        return cls(*children)
+
+
+class RecurrentStateCache:
+    """Per layer `S [slots, kv heads, value_dim, features]` and
+    `z [slots, kv heads, features]`, float32 (an accumulator over the
+    whole sequence), one slot a live sequence whatever its length.
+
+    It answers the calls the scheduler makes of the paged pool, with
+    one "block" a sequence: `ensure` succeeds once a slot is held, so
+    nothing is ever reserved a span and nothing is ever preempted.
+    Ids run 1..slots as block ids do (0 is what a plan's padding rows
+    carry, `TRASH_BLOCK`; no memory stands behind it): the device row
+    of id `i` is `i - 1`.  A slot is overwritten whole by the prefill
+    that takes it, so a freed slot is not zeroed.
+    """
+
+    # no parameter: nothing serves another dtype yet, and a benchmark
+    # cell's probe holds the state to this one (its `state_rel_tol`)
+    dtype = jnp.float32
+
+    def __init__(self, num_layers, num_kv_heads, value_dim, features, *,
+                 slots, max_model_len, device_init=True):
+        self.num_layers = int(num_layers)
+        self.num_kv_heads = int(num_kv_heads)
+        self.value_dim = int(value_dim)
+        self.features = int(features)
+        self.slots = int(slots)
+        # one "block" covers a whole sequence
+        self.block_size = int(max_model_len)
+        self.num_blocks = self.slots + 1
+        if device_init:
+            s_shape = (self.slots, self.num_kv_heads, self.value_dim,
+                       self.features)
+            self.states = [(jnp.zeros(s_shape, self.dtype),
+                            jnp.zeros(s_shape[:2] + s_shape[3:],
+                                      self.dtype))
+                           for _ in range(self.num_layers)]
+        else:
+            self.states = None
+        self._free = list(range(self.slots, 0, -1))
+        self._owner = {}             # seq_id -> slot id
+        self._high_water = 0
+
+    @property
+    def bytes_per_slot(self):
+        return self.num_layers * self.num_kv_heads * self.features \
+            * (self.value_dim + 1) * jnp.dtype(self.dtype).itemsize
+
+    @property
+    def state_bytes(self):
+        return self.slots * self.bytes_per_slot
+
+    # -- allocator ----------------------------------------------------------
+    @property
+    def free_blocks(self):
+        return len(self._free)
+
+    @property
+    def high_water_blocks(self):
+        return self._high_water
+
+    def owned(self, seq_id):
+        return [self._owner[seq_id]] if seq_id in self._owner else []
+
+    def owners(self):
+        return list(self._owner)
+
+    def ensure(self, seq_id, num_positions):
+        """True once `seq_id` holds a slot, whatever the length."""
+        if seq_id in self._owner:
+            return True
+        if not self._free:
+            return False
+        self._owner[seq_id] = self._free.pop()
+        self._high_water = max(self._high_water, len(self._owner))
+        return True
+
+    def free_seq(self, seq_id):
+        slot = self._owner.pop(seq_id, None)
+        if slot is None:
+            return 0
+        self._free.append(slot)
+        return 1
+
+    def table_row(self, seq_id, width):
+        row = np.full((width,), TRASH_BLOCK, np.int32)
+        row[:1] = self.owned(seq_id)
+        return row
+
+    def frag_report(self):
+        return {'num_blocks': self.num_blocks, 'usable_blocks': self.slots,
+                'free_blocks': len(self._free),
+                'owned_blocks': len(self._owner),
+                'owned_seqs': len(self._owner),
+                'frag_frac': 0.0, 'largest_free_run': len(self._free),
+                'high_water_blocks': self._high_water}
+
+    def audit(self):
+        """A slot with two owners, a slot both free and owned, an
+        illegal id, a leak.  (An owner without a live request and a
+        live request without a slot are the scheduler's to see: it
+        knows the requests.)"""
+        problems = []
+        seen = {}
+        for sid, slot in self._owner.items():
+            if not 0 < slot <= self.slots:
+                problems.append(f'seq {sid} owns illegal slot {slot}')
+            if slot in seen:
+                problems.append(
+                    f'slot {slot} aliased by seqs {seen[slot]} and {sid}')
+            seen[slot] = sid
+        free = set(self._free)
+        if len(free) != len(self._free):
+            problems.append('free list holds duplicates')
+        if free & set(seen):
+            problems.append(
+                f'slots {sorted(free & set(seen))} both free and owned')
+        if len(free) + len(seen) != self.slots:
+            problems.append(
+                f'leak: {self.slots - len(free) - len(seen)} slot(s) '
+                'neither free nor owned')
+        return problems
+
+    # -- device side ----------------------------------------------------------
+    def arrays(self):
+        Ss, zs = (tuple(x) for x in zip(*self.states))
+        return Ss, zs
+
+    def set_arrays(self, arrays):
+        self.states = list(zip(*arrays))
+
+    def prefill_where(self, seq_ids, rows, bucket):
+        """Device rows [rows] a prefill chunk writes: the sequences'
+        own; a padding row names the row past the last, which the
+        write drops."""
+        del bucket
+        where = np.full((rows,), self.slots, np.int32)
+        where[:len(seq_ids)] = [self._owner[sid] - 1 for sid in seq_ids]
+        return where
+
+    def decode_where(self, plan):
+        """Device rows [batch] of a decode plan, DISTINCT (the decode
+        update rewrites each row's slot in place): a padding row names
+        a slot no live sequence holds, which its inactive update
+        leaves as it was."""
+        ids = [int(t) for t in plan.tables[:len(plan.requests), 0]]
+        held = set(ids)
+        spare = (s for s in range(1, self.slots + 1) if s not in held)
+        ids += [next(spare) for _ in range(plan.batch - len(ids))]
+        return np.asarray(ids, np.int32) - 1
+
+    def idle_where(self, batch, width):
+        del width
+        return np.arange(batch, dtype=np.int32)
+
+    path_key = 'state'
+
+    def decode_path(self, model, batch, width):
+        """'kernel' or 'plain': the gate retention_decode asks."""
+        from ..ops.power_retention import can_use_pallas
+        del width
+        cfg = model.config
+        return 'kernel' if can_use_pallas(
+            self.states[0][0], jax.ShapeDtypeStruct(
+                (batch, cfg.num_heads, cfg.head_dim), jnp.float32)) \
+            else 'plain'
+
+    def prefill_caches(self, model, rows, bucket, lengths):
+        del model, rows, bucket
+        return [RecurrentStateView(lengths=lengths)
+                for _ in range(self.num_layers)]
+
+    def store_prefill(self, arrays, caches, where):
+        """Each row's final (S, z) into its slot, whole."""
+        Ss, zs = arrays
+        with jax.named_scope('state.write'):
+            return (tuple(S.at[where].set(c.S.astype(S.dtype), mode='drop')
+                          for S, c in zip(Ss, caches)),
+                    tuple(z.at[where].set(c.z.astype(z.dtype), mode='drop')
+                          for z, c in zip(zs, caches)))
+
+    def constrain(self, arrays):
+        return arrays
+
+    def decode_views(self, arrays, where, ctx, active):
+        del ctx
+        return [RecurrentStateView(S, z, where, active)
+                for S, z in zip(*arrays)]
+
+    def arrays_of(self, views):
+        return tuple(v.S for v in views), tuple(v.z for v in views)

@@ -415,4 +415,9 @@ class ContinuousBatchingScheduler:
             if self.cache.owned(req.rid):
                 problems.append(
                     f'finished request {req.rid} still owns blocks')
+        live = {req.rid for req in self.running}
+        for sid in self.cache.owners():
+            if sid not in live:
+                problems.append(
+                    f'sequence {sid} owns cache but is not running')
         return problems

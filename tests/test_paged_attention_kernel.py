@@ -329,3 +329,126 @@ def test_a_plan_counts_one_block_for_a_row_that_is_not_active():
     assert plan.kv_blocks(8) == (1 + 3 + 1 + 1, 4 * 8)
     plan.ctx[1] = 70            # past the table: no more than it holds
     assert plan.kv_blocks(8) == (1 + 8 + 1 + 1, 4 * 8)
+
+
+# -- the retention decoder's modules for the same described chip --------------
+# (kept in this file: one worker describes the topology, PERF.md PR 26)
+def _retention_param_shapes(cfg):
+    h, d, inter = cfg.hidden_size, cfg.head_dim, cfg.intermediate_size
+    hq, hkv = cfg.num_heads * d, cfg.num_kv_heads * d
+    shapes = {'model.embed.weight': (cfg.vocab_size, h),
+              'lm_head.weight': (cfg.vocab_size, h),
+              'model.norm.weight': (h,)}
+    for i in range(cfg.num_layers):
+        pre = f'model.layers.{i}.'
+        shapes.update({
+            pre + 'input_norm.weight': (h,), pre + 'post_norm.weight': (h,),
+            pre + 'attn.q_proj.weight': (h, hq),
+            pre + 'attn.k_proj.weight': (h, hkv),
+            pre + 'attn.v_proj.weight': (h, hkv),
+            pre + 'attn.g_proj.weight': (h, cfg.num_kv_heads),
+            pre + 'attn.o_proj.weight': (hq, h),
+            pre + 'attn.q_norm.weight': (d,),
+            pre + 'attn.k_norm.weight': (d,),
+            pre + 'mlp.gate_proj.weight': (h, inter),
+            pre + 'mlp.up_proj.weight': (h, inter),
+            pre + 'mlp.down_proj.weight': (inter, h)})
+    return shapes
+
+
+def test_the_shapes_below_are_the_retention_models_own():
+    from paddle_tpu.models.retention import retention_tiny
+    paddle.seed(0)
+    model = retention_tiny()
+    params, _ = model.functional_state()
+    assert {k: tuple(v.shape) for k, v in params.items()} \
+        == _retention_param_shapes(model.config)
+
+
+def _uncached(compile_fn):
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        return compile_fn()
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+
+
+def test_mosaic_compiles_the_retention_update_at_serving_widths(one_chip):
+    """Brumby-14B's decode: 16 rows, 8 key/value heads of 128, groups
+    of 5 query heads padded to 8, 8,320 features.  Compiled, not run;
+    the state goes in and comes out in place (a caller that does not
+    donate it, as here, gets XLA's copy in front)."""
+    from paddle_tpu.ops import power_retention as pr
+    R, hkv, g8, d, D = 16, 8, 8, 128, 8320
+
+    def sd(shape, dt='float32'):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    compiled = _uncached(lambda: pr._retention_decode.lower(
+        sd((R,), 'int32'), sd((R, hkv, g8, D)), sd((R, hkv, 1, D)),
+        sd((R, hkv, d, 1)), sd((R, hkv, 1, 1)), sd((R, hkv, d, D)),
+        tile=pr._tile(d)).compile())
+    text = compiled.as_text()
+    assert 'tpu_custom_call' in text and 'retention_decode' in text
+    # the kernel's second result is its sixth operand, rewritten
+    assert 'output_to_operand_aliasing={{1}: (5, {})}' in text
+
+
+def test_the_decode_module_holds_the_recurrent_state_once(one_chip,
+                                                          monkeypatch):
+    """serve.decode[16x8] of the retention decoder at Brumby-14B's
+    widths and 2 layers, through the engine's own builder, for the
+    described chip: the states are donated in and aliased out, nothing
+    of a state's size is copied inside the scan (PR 24's finding 2 was
+    a pool held twice), and the temporaries stay far under one layer's
+    state."""
+    from paddle_tpu.models.retention import (RetentionConfig,
+                                             RetentionForCausalLM)
+    from paddle_tpu.ops import power_retention as pr
+    from paddle_tpu.serving import ServeConfig, ServingEngine
+    from paddle_tpu.serving.kv_cache import RecurrentStateCache
+    slots, span = 16, 8
+    cfg = RetentionConfig(num_layers=2)
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    params = {k: sd(v, 'bfloat16')
+              for k, v in _retention_param_shapes(cfg).items()}
+    # the engine's builder on a model and a cache that hold no arrays
+    model = RetentionForCausalLM.__new__(RetentionForCausalLM)
+    model.config = cfg
+    eng = ServingEngine.__new__(ServingEngine)
+    eng.model, eng.recurrent = model, True
+    eng.config = ServeConfig(
+        max_slots=slots, decode_span=span, prompt_buckets=(256,),
+        batch_buckets=(slots,), prefill_batch=1, max_model_len=2048,
+        temperature=0.0).resolved(cfg)
+    eng.cache = RecurrentStateCache(
+        **model.state_spec(), slots=slots, max_model_len=2048,
+        device_init=False)
+    # the chip's path: a TPU is what the module is compiled for
+    monkeypatch.setattr(_gating, 'pallas_backend_ok', lambda: True)
+    S = sd((slots, 8, 128, 8320), 'float32')
+    z = sd((slots, 8, 8320), 'float32')
+    assert pr.can_use_pallas(S, sd((slots, 40, 128), 'float32'))
+    row = sd((slots,), 'int32')
+    fn = eng._decode_build(slots, span)
+    compiled = _uncached(lambda: jax.jit(fn, donate_argnums=(2, 3)).lower(
+        params, {}, (S, S), (z, z), row, row, row, sd((slots,), 'bool'),
+        row, row).compile())
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 1
+    assert 'retention_decode' in text
+    state = 2 * slots * 8 * 8320 * 129 * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state
+    one_layer = state // 2
+    assert memory.temp_size_in_bytes < one_layer // 2, memory
+    copies = [line for line in text.splitlines()
+              if 'f32[16,8,128,8320]' in line.split('=')[0]
+              and (' copy(' in line or 'copy-start' in line)]
+    assert not copies, copies[:3]
