@@ -162,8 +162,8 @@ def _target_gptserve(mesh):
     nb = S * mb + 1                       # pool incl. trash block
     return DecodeAuditLayer(model), (
         _ids_batch((S, 1), 128),
-        jax.ShapeDtypeStruct((L, nb, nh, bs, hd), jnp.float32),
-        jax.ShapeDtypeStruct((L, nb, nh, bs, hd), jnp.float32),
+        jax.ShapeDtypeStruct((L, nb, bs, nh, hd), jnp.float32),
+        jax.ShapeDtypeStruct((L, nb, bs, nh, hd), jnp.float32),
         _ids_batch((S, mb), 0),
         _ids_batch((S,), 0))
 

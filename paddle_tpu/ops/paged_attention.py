@@ -9,6 +9,19 @@ attends a whole batch of wildly different-length sequences at once:
 read each sequence's blocks through its table, mask columns past
 its length, softmax, weight.
 
+The pool's order.  One layer's pool is stored, stated and passed as
+``[num_blocks, block_size, num_heads, head_dim]``: positions outside
+heads.  That is the order `write_kv`'s scatter writes (one
+``[num_heads, head_dim]`` row at a (block, position)), the order a
+decode module's ``lax.scan`` carries and the order the kernel's async
+copies read a block in, so a module's arguments, its carry and its
+results have one layout and XLA has nothing to copy round the scan.
+(Stated with heads outside positions, as until PR 28, XLA kept the
+carry in this order all the same and copied every pool in front of
+the scan and back behind it: a quarter to a third of an intervention,
+PERF.md section 6, PR 28.  PR 26's finding — a kernel has to read its
+operand in the layout the scan carries — now holds by construction.)
+
 Two paths, one signature; `can_use_pallas` chooses by what it can
 observe (a TPU or interpret mode, no mesh, the shapes below):
 
@@ -49,16 +62,16 @@ from . import _gating
 __all__ = ['write_kv', 'paged_attention', 'gather_dense',
            'can_use_pallas', 'POOL_SPEC']
 
-# sharding of one layer's pool [num_blocks, num_heads, block_size,
+# sharding of one layer's pool [num_blocks, block_size, num_heads,
 # head_dim]: heads ride the tp axis (same Megatron head split as the
 # attention weights), blocks/positions replicated
-POOL_SPEC = (None, 'tp', None, None)
+POOL_SPEC = (None, None, 'tp', None)
 
 
 def write_kv(k_pool, v_pool, k_new, v_new, block_tables, slots):
     """Scatter one new token's k/v per sequence into the paged pool.
 
-    k_pool/v_pool : [num_blocks, num_heads, block_size, head_dim]
+    k_pool/v_pool : [num_blocks, block_size, num_heads, head_dim]
     k_new/v_new   : [S, num_heads, head_dim] — this step's k/v rows
     block_tables  : [S, max_blocks] int — pool indices per sequence
     slots         : [S] int — the ABSOLUTE position being written
@@ -70,28 +83,26 @@ def write_kv(k_pool, v_pool, k_new, v_new, block_tables, slots):
     live sequences.
     """
     with jax.named_scope('paged.write_kv'):
-        bs = k_pool.shape[2]
+        bs = k_pool.shape[1]
         idx = (slots // bs).astype(jnp.int32)
         bids = jnp.take_along_axis(
             block_tables, idx[:, None], axis=1)[:, 0]
         offs = (slots % bs).astype(jnp.int32)
-        k_pool = k_pool.at[bids, :, offs].set(
-            k_new.astype(k_pool.dtype))
-        v_pool = v_pool.at[bids, :, offs].set(
-            v_new.astype(v_pool.dtype))
+        k_pool = k_pool.at[bids, offs].set(k_new.astype(k_pool.dtype))
+        v_pool = v_pool.at[bids, offs].set(v_new.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
 def gather_dense(pool, block_table):
     """One sequence-major dense view of the pooled cache:
-    [num_blocks, nh, bs, hd] gathered through [S, max_blocks] tables
+    [num_blocks, bs, nh, hd] gathered through [S, max_blocks] tables
     -> [S, nh, max_blocks*bs, hd] (position-contiguous per sequence).
     """
     S, mb = block_table.shape
-    _, nh, bs, hd = pool.shape
+    _, bs, nh, hd = pool.shape
     with jax.named_scope('paged.gather_dense'):
-        g = pool[block_table]                  # [S, mb, nh, bs, hd]
-        g = jnp.transpose(g, (0, 2, 1, 3, 4))  # [S, nh, mb, bs, hd]
+        g = pool[block_table]                  # [S, mb, bs, nh, hd]
+        g = jnp.transpose(g, (0, 3, 1, 2, 4))  # [S, nh, mb, bs, hd]
         return g.reshape(S, nh, mb * bs, hd)
 
 
@@ -115,7 +126,7 @@ def _reference(q, k_pool, v_pool, block_tables, lens):
 
 # -- the Pallas decode kernel ------------------------------------------------
 # Bytes of K, and again of V, that one async copy round fetches: 8
-# blocks of a float32 pool at 16 heads x 16 positions x 128.  Twice
+# blocks of a float32 pool at 16 positions x 16 heads x 128.  Twice
 # for the double buffer: 4 MB of the 16 MB scoped VMEM default, the
 # rest is the body's own temporaries.
 ROUND_BYTES = 1 << 20
@@ -131,7 +142,7 @@ def can_use_pallas(k_pool, block_tables):
     vregs, the heads a whole number of sublane tiles of the pool's
     dtype, one fetch round inside its VMEM budget, the tables inside
     SMEM.  Everything else takes the reference path."""
-    _, nh, bs, hd = k_pool.shape
+    _, bs, nh, hd = k_pool.shape
     if k_pool.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     return (_gating.pallas_backend_ok()
@@ -143,7 +154,7 @@ def can_use_pallas(k_pool, block_tables):
 
 
 def _blocks_a_round(k_pool, block_tables):
-    _, nh, bs, hd = k_pool.shape
+    _, bs, nh, hd = k_pool.shape
     block_bytes = nh * bs * hd * jnp.dtype(k_pool.dtype).itemsize
     return min(ROUND_BYTES // block_bytes, block_tables.shape[1])
 
@@ -241,16 +252,6 @@ def _decode_kernel(tbl_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _positions_major(pool):
-    """The pool as the kernel reads it, [num_blocks, bs, nh, hd].  No
-    data moves: `write_kv` scatters [nh, hd] rows at (block, position),
-    so XLA already keeps a pool that a decode module carries with
-    positions outside heads, and a kernel that asked for heads outside
-    positions would have the whole pool transposed in front of every
-    call (twice the kernel's own time, PERF.md section 6, PR 26)."""
-    return jnp.transpose(pool, (0, 2, 1, 3))
-
-
 # A jit of its own: a decode module calls this once a layer with the
 # same shapes, and jax then traces the kernel and lowers it to Mosaic
 # once a module, not once a layer (24.1 s against 3.5 s of tracing a
@@ -260,7 +261,7 @@ def _positions_major(pool):
 def _paged_decode(q, k_pool, v_pool, block_tables, lens, *, chunk,
                   interpret=False):
     S, nh, hd = q.shape
-    _, _, bs, _ = k_pool.shape
+    _, bs, _, _ = k_pool.shape
     width = block_tables.shape[1]
     out_dtype = jnp.result_type(q.dtype, k_pool.dtype)
     kernel = functools.partial(_decode_kernel, width=width,
@@ -287,8 +288,7 @@ def _paged_decode(q, k_pool, v_pool, block_tables, lens, *, chunk,
         interpret=interpret,
         name='paged_decode',
     )(block_tables.reshape(-1).astype(jnp.int32),
-      lens.astype(jnp.int32), q, _positions_major(k_pool),
-      _positions_major(v_pool))
+      lens.astype(jnp.int32), q, k_pool, v_pool)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, lens):
@@ -296,7 +296,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, lens):
 
     q            : [S, num_heads, head_dim] — ONE query token per
                    sequence (the continuous-batching decode shape)
-    k_pool/v_pool: [num_blocks, num_heads, block_size, head_dim]
+    k_pool/v_pool: [num_blocks, block_size, num_heads, head_dim]
     block_tables : [S, max_blocks] int — every entry a block of the
                    pool: the kernel copies by them unchecked, where a
                    gather would clamp

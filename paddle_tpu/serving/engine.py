@@ -481,9 +481,11 @@ class ServingEngine:
         # the module signature changed from one batch PRNGKey to
         # per-row seeds, and _fingerprint does not hash example avals
         # — without the marker a pre-discipline AOT artifact would
-        # deserialize against the new call signature
+        # deserialize against the new call signature; layout= marks
+        # the order of the cache's arrays for the same reason
         fp = self._fingerprint('serve-prefill', bucket=P, nblk=nblk,
-                               chunk=B, keys='per-request-pos')
+                               chunk=B, keys='per-request-pos',
+                               layout=self.cache.layout_key)
         example = (self._params, self._buffers,
                    jnp.zeros((B, P), jnp.int64),
                    jnp.full((B,), P, jnp.int32), *self.cache.arrays(),
@@ -552,7 +554,8 @@ class ServingEngine:
         extra = {self.cache.path_key: path}
         where = jnp.asarray(self.cache.idle_where(S, W))
         fp = self._fingerprint('serve-decode', batch=S, span=K,
-                               keys='per-request-pos', **extra)
+                               keys='per-request-pos',
+                               layout=self.cache.layout_key, **extra)
         example = (self._params, self._buffers, *arrays, where,
                    jnp.zeros((S,), jnp.int64),
                    jnp.zeros((S,), jnp.int64),
@@ -1080,7 +1083,9 @@ class DecodeAuditLayer(nn.Layer):
     a Layer whose forward runs the serving engine's per-step math
     (paged views + ragged attention over the pool) so ``tpu_lint
     --hlo``/``--plan`` can lower and audit the serving path with the
-    same machinery as the train steps."""
+    same machinery as the train steps.  `k_pools`/`v_pools` are the
+    layers' pools stacked: ``[num_layers, num_blocks, block_size,
+    num_heads, head_dim]``."""
 
     def __init__(self, model):
         super().__init__()

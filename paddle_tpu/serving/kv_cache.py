@@ -10,9 +10,16 @@ pool below, blocks that grow with a sequence's length, and
 a sequence.  The model says which it needs (`model.serving_state`).
 
 The serving engine never allocates per-sequence KV buffers.  Instead
-each layer owns ONE device pool ``[num_blocks, num_heads, block_size,
+each layer owns ONE device pool ``[num_blocks, block_size, num_heads,
 head_dim]`` allocated once at engine construction, and every live
 sequence owns an ordered list of pool blocks (its *block table*).
+Positions lie outside heads because that is the order every user of a
+pool wants: `write_kv` scatters one ``[num_heads, head_dim]`` row at a
+(block, position), the decode modules' ``lax.scan`` carries the pools
+that way, and `paged_decode` copies a block as it lies.  Stated in
+that order a decode module's arguments, carry and results share one
+layout, and XLA copies no pool round the scan (PERF.md section 6,
+PR 28; ``ops/paged_attention.py``).
 Admission allocates blocks, eviction frees them — memory churn is a
 host-side free-list operation, never a device reallocation, so the
 compiled decode step's shapes never change (the zero-recompile
@@ -51,6 +58,8 @@ class PagedCacheView:
 
     A pytree of (k_pool, v_pool, block_table, slots, lens):
 
+    - ``k_pool``/``v_pool`` ``[num_blocks, block_size, num_heads,
+      head_dim]``: the layer's whole pools;
     - ``slots`` [S]: the absolute position this step WRITES (each
       sequence's context length before its new token);
     - ``lens`` [S]: the valid length the attention READS (slots + 1 —
@@ -110,7 +119,7 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.dtype = dtype or jnp.float32
         if device_init:
-            shape = (self.num_blocks, self.num_heads, self.block_size,
+            shape = (self.num_blocks, self.block_size, self.num_heads,
                      self.head_dim)
             self.pools = [(jnp.zeros(shape, self.dtype),
                            jnp.zeros(shape, self.dtype))
@@ -290,6 +299,10 @@ class PagedKVCache:
 
     # what the decode modules' fingerprints call their attention path
     path_key = 'paged'
+    # the order of a pool's axes, in every module's fingerprint: a
+    # module exported against another order has the same avals at
+    # block_size == num_heads and must not be loaded against this one
+    layout_key = 'block,position,head,dim'
 
     def decode_path(self, model, batch, width):
         """'kernel' or 'gather': the same gate, on the same operands,
@@ -318,11 +331,11 @@ class PagedKVCache:
         for (kbuf, vbuf), kp, vp in zip(caches, ks, vs):
             kbuf = kbuf.value if hasattr(kbuf, 'value') else kbuf
             vbuf = vbuf.value if hasattr(vbuf, 'value') else vbuf
-            # [B, nh, Pc, hd] -> [B, nblk, nh, bs, hd] block rows
+            # [B, nh, Pc, hd] -> [B, nblk, bs, nh, hd] block rows
             kb = jnp.transpose(
-                kbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 1, 3, 4))
+                kbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 3, 1, 4))
             vb = jnp.transpose(
-                vbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 1, 3, 4))
+                vbuf.reshape(B, nh, nblk, bs, hd), (0, 2, 3, 1, 4))
             kp = maybe_shard(kp, POOL_SPEC)
             vp = maybe_shard(vp, POOL_SPEC)
             new_ks.append(kp.at[where].set(kb.astype(kp.dtype)))
@@ -536,6 +549,7 @@ class RecurrentStateCache:
         return np.arange(batch, dtype=np.int32)
 
     path_key = 'state'
+    layout_key = 'slot,head,value,feature'
 
     def decode_path(self, model, batch, width):
         """'kernel' or 'plain': the gate retention_decode asks."""

@@ -654,10 +654,15 @@ class TestShardedPoolTP2:
         assert audit1 == [] and audit2 == []
         assert t1 == t2
         # the pool is genuinely sharded, not replicated: its head
-        # axis rides 'tp'
+        # axis, the third of [blocks, positions, heads, head_dim],
+        # rides 'tp'
         k0 = eng2.cache.pools[0][0]
         spec = getattr(k0.sharding, 'spec', None)
-        assert spec is not None and 'tp' in str(spec), spec
+        assert spec is not None and tuple(spec)[:3] == (None, None, 'tp'), \
+            spec
+        nb, bs, nh, hd = k0.shape
+        assert {s.data.shape for s in k0.addressable_shards} \
+            == {(nb, bs, nh // 2, hd)}
 
 
 # =============================================================================

@@ -70,8 +70,9 @@ def _ref_tokens(model, prompt, n):
 class TestPagedAttentionOp:
     def _pool(self, rs, nb=9, nh=2, bs=4, hd=8):
         import jax.numpy as jnp
-        k = jnp.asarray(rs.randn(nb, nh, bs, hd).astype(np.float32))
-        v = jnp.asarray(rs.randn(nb, nh, bs, hd).astype(np.float32))
+        # [num_blocks, block_size, num_heads, head_dim]
+        k = jnp.asarray(rs.randn(nb, bs, nh, hd).astype(np.float32))
+        v = jnp.asarray(rs.randn(nb, bs, nh, hd).astype(np.float32))
         return k, v
 
     def test_write_then_gather_roundtrip(self):
@@ -90,8 +91,45 @@ class TestPagedAttentionOp:
             np.asarray(gather_dense(v2, tables)[1, :, 2]),
             np.asarray(vn[1]))
         # untouched slots unchanged
-        np.testing.assert_array_equal(np.asarray(k2[1, :, 0]),
-                                      np.asarray(k[1, :, 0]))
+        np.testing.assert_array_equal(np.asarray(k2[1, 0]),
+                                      np.asarray(k[1, 0]))
+        # the rows lie at (block, position): slot 5 of row 0 is
+        # position 1 of its second block, slot 2 of row 1 position 2
+        # of its first
+        np.testing.assert_array_equal(np.asarray(k2[2, 1]),
+                                      np.asarray(kn[0]))
+        np.testing.assert_array_equal(np.asarray(v2[3, 2]),
+                                      np.asarray(vn[1]))
+
+    @pytest.mark.parametrize('bucket', [8, 16])
+    def test_store_prefill_then_gather_returns_the_dense_rows(
+            self, bucket):
+        """A prefill's dense buffers [B, nh, Pc, hd], stored through
+        each row's block ids and gathered back through the same ids
+        as a table, come back row for row, value for value; blocks no
+        row names are untouched."""
+        import jax.numpy as jnp
+        rs = np.random.RandomState(4)
+        L, nh, hd, bs, B = 2, 2, 8, 4, 2
+        nblk = bucket // bs
+        cache = PagedKVCache(L, nh, hd, block_size=bs,
+                             num_blocks=1 + 2 * B * nblk)
+        assert cache.pools[0][0].shape == (1 + 2 * B * nblk, bs, nh, hd)
+        dense = [(jnp.asarray(rs.randn(B, nh, bucket, hd), jnp.float32),
+                  jnp.asarray(rs.randn(B, nh, bucket, hd), jnp.float32))
+                 for _ in range(L)]
+        where = jnp.asarray(rs.permutation(
+            np.arange(1, 1 + 2 * B * nblk))[:B * nblk]
+            .reshape(B, nblk).astype(np.int32))
+        ks, vs = cache.store_prefill(cache.arrays(), dense, where)
+        named = np.zeros(cache.num_blocks, bool)
+        named[np.asarray(where).ravel()] = True
+        for (kd, vd), kp, vp in zip(dense, ks, vs):
+            np.testing.assert_array_equal(
+                np.asarray(gather_dense(kp, where)), np.asarray(kd))
+            np.testing.assert_array_equal(
+                np.asarray(gather_dense(vp, where)), np.asarray(vd))
+            assert not np.asarray(kp)[~named].any()
 
     def test_bitexact_vs_dense_masked_attention(self):
         """paged_attention == the dense -1e9-masked softmax attention
@@ -661,9 +699,10 @@ class TestServingAnalysis:
         cfg = _tiny_config()
         eng = ServingEngine(m, cfg)
         W = eng.scheduler.table_width
-        shape = (eng.cache.num_blocks, m.config.num_heads,
-                 cfg.block_size,
+        shape = (eng.cache.num_blocks, cfg.block_size,
+                 m.config.num_heads,
                  m.config.hidden_size // m.config.num_heads)
+        assert eng.cache.pools[0][0].shape == shape
         for S in cfg.batch_buckets:
             fn = eng._decode_build(S, cfg.decode_span)
             pools = tuple(
@@ -691,9 +730,9 @@ class TestServingAnalysis:
         out = layer(
             paddle.to_tensor(np.zeros((S, 1), 'int64')),
             paddle.to_tensor(
-                rs.randn(L, nb, nh, bs, hd).astype(np.float32)),
+                rs.randn(L, nb, bs, nh, hd).astype(np.float32)),
             paddle.to_tensor(
-                rs.randn(L, nb, nh, bs, hd).astype(np.float32)),
+                rs.randn(L, nb, bs, nh, hd).astype(np.float32)),
             paddle.to_tensor(
                 np.arange(1, 1 + S * mb).reshape(S, mb)
                 .astype('int32')),
@@ -702,4 +741,4 @@ class TestServingAnalysis:
         assert tuple(np.asarray(
             logits.value if hasattr(logits, 'value')
             else logits).shape) == (S, 1, 128)
-        assert np.asarray(nk).shape == (L, nb, nh, bs, hd)
+        assert np.asarray(nk).shape == (L, nb, bs, nh, hd)

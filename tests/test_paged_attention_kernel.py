@@ -15,7 +15,9 @@ import paddle_tpu as paddle  # noqa: F401
 from paddle_tpu.ops import _gating
 from paddle_tpu.ops import paged_attention as pa
 
-NH, BS, HD, WIDTH = 16, 16, 128, 6
+# heads and positions differ, so a pool in the other order is another
+# shape (at the serving widths both are 16: the compile tests below)
+NH, BS, HD, WIDTH = 16, 8, 128, 6
 NUM_BLOCKS = 48
 FULL = WIDTH * BS
 # 1, one under, at and one over a block boundary, mid-table, the table
@@ -30,7 +32,7 @@ def interpret_mode(monkeypatch):
 
 def _pools(dtype, seed=0, num_blocks=NUM_BLOCKS, hd=HD):
     rs = np.random.RandomState(seed)
-    shape = (num_blocks, NH, BS, hd)
+    shape = (num_blocks, BS, NH, hd)
     return (jnp.asarray(rs.randn(*shape), dtype),
             jnp.asarray(rs.randn(*shape), dtype))
 
@@ -141,7 +143,7 @@ def test_nothing_past_a_length_reaches_the_result(interpret_mode, dtype):
             poison[tables[i, b]] = False
         if n % BS:
             poison[tables[i, n // BS], n % BS:] = True
-    mask = jnp.asarray(poison)[:, None, :, None]
+    mask = jnp.asarray(poison)[:, :, None, None]
     kp2 = jnp.where(mask, jnp.nan, kp)
     vp2 = jnp.where(mask, jnp.nan, vp)
     assert bool(jnp.isnan(kp2).any())
@@ -206,7 +208,7 @@ class TestGate:
                   width=WIDTH)
         kw.update(kwargs)
         pool = jax.ShapeDtypeStruct(
-            (NUM_BLOCKS, kw['nh'], kw['bs'], kw['hd']),
+            (NUM_BLOCKS, kw['bs'], kw['nh'], kw['hd']),
             jnp.dtype(kw['dtype']))
         tables = jax.ShapeDtypeStruct((kw['batch'], kw['width']),
                                       jnp.int32)
@@ -226,33 +228,135 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _uncached(compile_fn):
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        return compile_fn()
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+
+
 @pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
 @pytest.mark.parametrize('batch', [8, 32])
 def test_mosaic_compiles_the_kernel_at_serving_widths(one_chip, dtype,
                                                       batch):
     """Cerebras-GPT-1.3B's decode: 16 heads of 128, blocks of 16, a
     pool of 832 blocks, tables of 128.  Compiled, not run."""
-    from jax.experimental.compilation_cache import compilation_cache
     nb, nh, bs, hd, width = 832, 16, 16, 128, 128
 
     def sd(shape, dt):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
                                     sharding=one_chip)
 
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update('jax_enable_compilation_cache', False)
-    compilation_cache.reset_cache()
-    try:
-        pool, tables = sd((nb, nh, bs, hd), dtype), sd((batch, width),
-                                                       'int32')
-        compiled = pa._paged_decode.lower(
-            sd((batch, nh, hd), 'float32'), pool, pool, tables,
-            sd((batch,), 'int32'),
-            chunk=pa._blocks_a_round(pool, tables)).compile()
-    finally:
-        jax.config.update('jax_enable_compilation_cache', cached)
+    pool, tables = sd((nb, bs, nh, hd), dtype), sd((batch, width), 'int32')
+    compiled = _uncached(lambda: pa._paged_decode.lower(
+        sd((batch, nh, hd), 'float32'), pool, pool, tables,
+        sd((batch,), 'int32'),
+        chunk=pa._blocks_a_round(pool, tables)).compile())
     text = compiled.as_text()
     assert 'tpu_custom_call' in text and 'paged_decode' in text
+
+
+# -- the GPT serving modules for the same described chip ---------------------
+def _results_of(text, shape, ops):
+    """The instructions of an HLO text whose operation is one of `ops`
+    (or whose name says so: a fusion is named after what it fuses) and
+    whose result holds an array of `shape`."""
+    import re
+    line_re = re.compile(
+        r'^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(')
+    found = []
+    for line in text.splitlines():
+        m = line_re.match(line)
+        if m and shape in m.group(2) and any(
+                op == m.group(3) or op in m.group(1) for op in ops):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.fixture(scope='module')
+def gpt_serving_engine():
+    """Cerebras-GPT-1.3B's serving widths at 2 layers (and a small
+    vocabulary: the head is no part of what is asked here) under the
+    benchmark's ServeConfig, pool of 832 blocks and all."""
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import ServeConfig, ServingEngine
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=1024, hidden_size=2048, num_layers=2, num_heads=16,
+        intermediate_size=8192, max_seq_len=2048, dropout=0.0))
+    model.eval()
+    model.to('bfloat16')
+    return ServingEngine(model, ServeConfig(
+        block_size=16, max_model_len=2048, max_slots=32, decode_span=8,
+        prompt_buckets=(128, 256, 512, 1024), prefill_batch=1,
+        batch_buckets=(8, 16, 32), temperature=0.0, num_blocks=832))
+
+
+@pytest.mark.parametrize('module', ['decode[32x8]', 'decode[8x8]',
+                                    'prefill[1024x1]'])
+def test_the_serving_modules_hold_the_kv_pool_once(
+        one_chip, monkeypatch, gpt_serving_engine, module):
+    """The engine's own module bodies, compiled for the described chip
+    with the pools donated: every pool is aliased from argument to
+    result, nothing of a pool's size is copied, sliced or joined round
+    the scan (PR 24's finding 2: with heads outside positions XLA kept
+    the carry in another layout and held every pool twice), and the
+    temporaries stay under one pool.  Both orders read
+    f32[832,16,16,128] at these widths, so the shape cannot tell."""
+    eng = gpt_serving_engine
+    # the chip's path: a TPU is what the module is compiled for
+    monkeypatch.setattr(_gating, 'pallas_backend_ok', lambda: True)
+    if module.startswith('decode'):
+        batch = int(module[7:].split('x')[0])
+        fn, _, example, name, donate = eng._decode_spec(batch, 8)
+        assert eng.cache.decode_path(eng.model, batch, 128) == 'kernel'
+    else:
+        fn, _, example, name, donate = eng._prefill_spec(1024, 1)
+    assert name == f'serve.{module}'
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), example)
+    compiled = _uncached(lambda: jax.jit(
+        fn, donate_argnums=donate).lower(*avals).compile())
+    text = compiled.as_text()
+    if module.startswith('decode'):
+        assert 'paged_decode' in text and 'tpu_custom_call' in text
+    pool = eng.cache.pools[0][0]
+    assert pool.shape == (832, 16, 16, 128) and pool.dtype == jnp.float32
+    one_pool = pool.size * 4
+    pools = 2 * eng.cache.num_layers * one_pool
+    moved = _results_of(text, 'f32[832,16,16,128]',
+                        ('copy', 'copy-start', 'slice-start',
+                         'ConcatBitcast'))
+    assert not moved, moved[:4]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pools, memory
+    assert memory.temp_size_in_bytes < one_pool, memory
+
+
+@pytest.mark.parametrize('kind', ['decode', 'prefill'])
+def test_a_modules_fingerprint_carries_the_pools_order(kind):
+    """An artifact the exec tier kept for a pool with heads outside
+    positions has the same avals at 16 heads of 16 positions;
+    `_fingerprint` hashes no avals, so the marker is what keeps it
+    from being loaded against this pool."""
+    eng = _engine()
+    assert eng.cache.layout_key == 'block,position,head,dim'
+    if kind == 'decode':
+        fp = eng._decode_spec(4, 2)[1]
+        before = eng._fingerprint(
+            'serve-decode', batch=4, span=2, keys='per-request-pos',
+            paged=eng.cache.decode_path(eng.model, 4, 8))
+    else:
+        fp = eng._prefill_spec(16, 1)[1]
+        before = eng._fingerprint(
+            'serve-prefill', bucket=16, nblk=2, chunk=1,
+            keys='per-request-pos')
+    assert fp is not None and before is not None and fp != before
 
 
 # -- the engine on the kernel path, and the counter of what it reads ---------
@@ -365,17 +469,6 @@ def test_the_shapes_below_are_the_retention_models_own():
         == _retention_param_shapes(model.config)
 
 
-def _uncached(compile_fn):
-    from jax.experimental.compilation_cache import compilation_cache
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update('jax_enable_compilation_cache', False)
-    compilation_cache.reset_cache()
-    try:
-        return compile_fn()
-    finally:
-        jax.config.update('jax_enable_compilation_cache', cached)
-
-
 def test_mosaic_compiles_the_retention_update_at_serving_widths(one_chip):
     """Brumby-14B's decode: 16 rows, 8 key/value heads of 128, groups
     of 5 query heads padded to 8, 8,320 features.  Compiled, not run;
@@ -448,7 +541,6 @@ def test_the_decode_module_holds_the_recurrent_state_once(one_chip,
     assert memory.alias_size_in_bytes >= state
     one_layer = state // 2
     assert memory.temp_size_in_bytes < one_layer // 2, memory
-    copies = [line for line in text.splitlines()
-              if 'f32[16,8,128,8320]' in line.split('=')[0]
-              and (' copy(' in line or 'copy-start' in line)]
+    copies = _results_of(text, 'f32[16,8,128,8320]',
+                         ('copy', 'copy-start'))
     assert not copies, copies[:3]
