@@ -104,7 +104,12 @@ class TraceWindow:
         t0 = time.monotonic()
         shutil.rmtree(self.dir, ignore_errors=True)
         os.makedirs(self.dir, exist_ok=True)
-        jax.profiler.start_trace(self.dir)
+        # the program names its own spans (PR 25), so jax's Python
+        # tracer is off: with it a window of 4 s at 13 requests/s held
+        # the engine 33 s and took minutes to read (PR 30)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=options)
         from benchmark.reduce_trace import TRACED_SPAN
         self._span = jax.profiler.TraceAnnotation(TRACED_SPAN)
         self._span.__enter__()
@@ -147,9 +152,12 @@ def read_layer_metrics(specs, ctx):
 def result_line(cell, run, trace_on):
     """The one JSON object a run prints last.  `run` is the runner's
     dict: correct, attempted, failed, end_to_end {name: (value, unit)},
-    counters, and (traced) trace."""
+    counters, (traced) trace, and where the runner gives them
+    `compared` and `device`."""
     import jax
-    device = device_info()
+    # a runner that runs a reference after its window hands over the
+    # device as it read it before that
+    device = run.get('device') or device_info()
     on_tpu = jax.default_backend() == 'tpu'
     line = {'correct': bool(run['correct']),
             'attempted': int(run['attempted']),
@@ -160,7 +168,7 @@ def result_line(cell, run, trace_on):
                    'unit': run['end_to_end'][name][1]}
             for name in cell['end_to_end']}
         line['device'] = device
-        return line
+        return compared_last(line, run)
     from benchmark import reduce_trace
     trace = run.get('trace')
     summary = reduce_trace.summary(trace) if trace is not None else None
@@ -177,6 +185,16 @@ def result_line(cell, run, trace_on):
     line['device'] = device
     if summary is not None:
         line['breakdown'] = summary['breakdown']
+    return compared_last(line, run)
+
+
+def compared_last(line, run):
+    """The numbers `correct` was decided by, each beside its limit
+    ({name: [number, limit]}), as the line's last key."""
+    if run.get('compared'):
+        line['compared'] = {
+            name: [float(v), float(limit)]
+            for name, (v, limit) in run['compared'].items()}
     return line
 
 

@@ -5,11 +5,17 @@ jax.numpy from the published description: no kernel, no cache, no
 batching tricks, matmuls at precision 'highest' (on a TPU a float32
 matmul otherwise runs in bf16 passes).
 
-It reads the program's parameter dictionary (names as
-`model.functional_state()` gives them) and upcasts each tensor where it
-is used, one layer at a time, so no float32 copy of a bf16 model is
-ever held.  Each piece is one small jitted program, compiled once
-(every layer has the same shapes) and kept in jax's persistent cache.
+It reads a parameter dictionary under the names the program's
+`set_state_dict` loads, and upcasts each tensor where it is used, one
+layer at a time, so no float32 copy of a bf16 model is ever held.  Each
+piece is one small jitted program, compiled once (every layer has the
+same shapes) and kept in jax's persistent cache.
+
+`weights` draws that dictionary from a seed, by the source's own
+initialisation (Cerebras-GPT `config.json`: `initializer_range` 0.02,
+the residual projections by 1/sqrt(2 L) as GPT-2 does), in one jitted
+call on the device: the serve runner loads it into the program and
+hands the reference this copy, never what the program holds.
 
 Departures from the published model: the vocabulary's rows are padded
 to the program's 50304 and the extra rows take part in the softmax, as
@@ -81,30 +87,99 @@ def _row_loss_sum(x_row, ids_row, lnw, lnb, wte, *, eps):
     return -jnp.take_along_axis(logp, ids_row[1:, None], axis=1).sum()
 
 
+def shapes(*, vocab_size, hidden_size, num_layers, intermediate_size,
+           max_seq_len):
+    """{name: shape} of every tensor of the model, from its sizes."""
+    h, f = hidden_size, intermediate_size
+    block = {'ln1.weight': (h,), 'ln1.bias': (h,),
+             'attn.qkv.weight': (h, 3 * h), 'attn.qkv.bias': (3 * h,),
+             'attn.proj.weight': (h, h), 'attn.proj.bias': (h,),
+             'ln2.weight': (h,), 'ln2.bias': (h,),
+             'mlp.fc.weight': (h, f), 'mlp.fc.bias': (f,),
+             'mlp.proj.weight': (f, h), 'mlp.proj.bias': (h,)}
+    out = {'gpt.wte.weight': (vocab_size, h),
+           'gpt.wpe.weight': (max_seq_len, h)}
+    for i in range(num_layers):
+        out.update({f'gpt.blocks.{i}.{k}': s for k, s in block.items()})
+    out.update({'gpt.ln_f.weight': (h,), 'gpt.ln_f.bias': (h,)})
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=('sizes', 'dtype', 'std'))
+def _draw(seed, *, sizes, dtype, std):
+    sizes = dict(sizes)
+    layers = sizes['num_layers']
+    key = jax.random.key(seed)
+    out, stacks = {}, {}
+    for n, (name, shape) in enumerate(shapes(**sizes).items()):
+        layered = name.startswith('gpt.blocks.')
+        kind = name.split('.', 3)[-1] if layered else name
+        if kind not in stacks:
+            # one draw a kind of tensor, all layers' at once
+            x = std * jax.random.normal(
+                jax.random.fold_in(key, n),
+                (layers,) + shape if layered else shape, F32)
+            if kind.endswith('proj.weight'):
+                x = x / (2.0 * layers) ** 0.5
+            if kind.endswith(('ln1.weight', 'ln2.weight', 'ln_f.weight')):
+                x = 1.0 + x
+            stacks[kind] = x.astype(dtype)
+        out[name] = stacks[kind][int(name.split('.')[2])] if layered \
+            else stacks[kind]
+    return out
+
+
+def weights(seed, dtype, std=0.02, **sizes):
+    """Every tensor of the model from `seed` ({name: array} on the
+    device, in `dtype`): normal with deviation `std`, the residual
+    projections' by 1/sqrt(2 L) smaller.  Biases and the norms' shifts
+    are drawn too and the norms' scales round 1, where the source
+    starts them at 0 and 1: a tensor that is all 0 or all 1 would let a
+    program that dropped it pass."""
+    return _draw(jnp.uint32(int(seed) % 2 ** 32),
+                 sizes=tuple(sorted(sizes.items())), dtype=dtype, std=std)
+
+
 def _layer(params, i):
     pre = f'gpt.blocks.{i}.'
     return {k[len(pre):]: v for k, v in params.items()
             if k.startswith(pre)}
 
 
-def hidden(params, ids, *, num_layers, num_heads, eps):
+def _rounded(p, weights_as):
+    """The control's weights: every matrix rounded to `weights_as` and
+    back (a tensor at a time, so no second copy of the model is held);
+    biases and norms as they are."""
+    if weights_as is None:
+        return p
+    if isinstance(p, dict):
+        return {k: _rounded(v, weights_as) for k, v in p.items()}
+    return p.astype(weights_as).astype(p.dtype) if p.ndim == 2 else p
+
+
+def hidden(params, ids, *, num_layers, num_heads, eps, weights_as=None):
     """[B, T] ids -> [B, T, H] float32 states before the final norm."""
     with jax.default_matmul_precision('highest'):
-        x = _embed(params['gpt.wte.weight'], params['gpt.wpe.weight'],
+        x = _embed(_rounded(params['gpt.wte.weight'], weights_as),
+                   _rounded(params['gpt.wpe.weight'], weights_as),
                    jnp.asarray(ids, jnp.int32))
         for i in range(num_layers):
-            x = _block(x, _layer(params, i), heads=num_heads, eps=eps)
+            x = _block(x, _rounded(_layer(params, i), weights_as),
+                       heads=num_heads, eps=eps)
     return x
 
 
-def logits_at(params, ids, positions, **model):
-    """Float32 logits at `positions` [B, K] of right-padded `ids`."""
-    x = hidden(params, ids, **model)
+def logits_at(params, ids, positions, weights_as=None, **model):
+    """Float32 logits at `positions` [B, K] of right-padded `ids`.
+    `weights_as` (a dtype below the configuration's, say
+    'float8_e4m3fn') makes this the control of the serve runners'
+    `correct`: the same reference in the nearest lower precision."""
+    x = hidden(params, ids, weights_as=weights_as, **model)
     with jax.default_matmul_precision('highest'):
         return _logits_at(
             x, params['gpt.ln_f.weight'], params['gpt.ln_f.bias'],
-            params['gpt.wte.weight'], jnp.asarray(positions, jnp.int32),
-            eps=model['eps'])
+            _rounded(params['gpt.wte.weight'], weights_as),
+            jnp.asarray(positions, jnp.int32), eps=model['eps'])
 
 
 def lm_loss(params, ids, **model):

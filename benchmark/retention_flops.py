@@ -46,3 +46,18 @@ def prefill_call(model, length):
     moved = STATE_BYTES * (2 * hq * T * d + 2 * hkv * T * d + hkv * T
                            + hkv * D * (d + 1))
     return ops, moved
+
+
+def decode_step_ops_per_token(model):
+    """Operations ONE served token needs in a decode step of the whole
+    decoder: two a weight of every matrix a token meets (q, k, v, gate
+    and output projections, the gated MLP's three, the untied head over
+    the published vocabulary; the embedding is a lookup) and the state's
+    update and read-out in every layer."""
+    h, d = int(model['hidden_size']), int(model['head_dim'])
+    hq, hkv = int(model['num_heads']), int(model['num_kv_heads'])
+    layer = (2 * h * hq * d + 2 * h * hkv * d + h * hkv
+             + 3 * h * int(model['intermediate_size']))
+    weights = int(model['num_layers']) * layer \
+        + h * int(model['published_vocab_size'])
+    return 2 * weights + int(model['num_layers']) * decode_update(model)[0]

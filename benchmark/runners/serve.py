@@ -7,12 +7,23 @@ nothing else in the engine, on a schedule this file fixes: one prompt a
 prompt bucket, one after another; every token the engine chose greedily
 must lie within a logit gap of the float32 reference's best at its
 position (tokens are never compared with tokens: at random weights the
-best logit changes on rounding); and (b) invariants that hold under
-every interleaving: every request is accounted for once, delivered
-tokens add up, the allocator's audit is empty and the pool is whole
-again.  Lateness, missed limits, requests cut by the window, compiles
-in the window and everything read from the trace are numbers, never
-`correct`.
+best logit changes on rounding); (b) since PR 30, once the window has
+closed and outside every clock, the same gap over every token of a
+sample, drawn from the seed, of the requests the WINDOW finished, the
+longest among them (`served`): what the timed path produced at its own
+batch, with its own slots and blocks in use; and (c) invariants that
+hold under every interleaving: every request is accounted for once,
+delivered tokens add up, the allocator's audit is empty and the pool is
+whole again.  Lateness, missed limits, requests cut by the window,
+compiles in the window and everything read from the trace are numbers,
+never `correct`.  `compared` carries each number beside its limit.
+The gap, the sample and the decision are `benchmark/logit_gap.py`'s,
+shared with the `serve_recurrent` runner.
+
+The weights are the benchmark's own (`build`): drawn from the seed by
+`reference/gpt_ref.py::weights`, loaded into the program through its
+`set_state_dict`, and handed to the reference as they were drawn, so a
+tensor the program initialises, casts or loads wrongly shows as a gap.
 
 What the probe can see is set by the reference's own margin between
 its best and second-best logit, printed beside the gaps: a fault that
@@ -30,11 +41,18 @@ TRACE_SECONDS = 4.0
 
 
 def build(config, seed, clock):
+    """The model with the benchmark's own weights loaded into it, its
+    engine, and those weights as the benchmark drew them
+    (`gpt_ref.weights`: from the seed, on the device, in the dtype the
+    configuration serves): what the references read.  The program's
+    loader keeps a tensor it is handed in its own dtype as it is, so the
+    two hold the same buffers until the program changes one."""
     import jax.numpy as jnp
     import paddle_tpu as paddle
     from paddle_tpu.distributed import env as dist_env
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
     from paddle_tpu.serving import ServeConfig, ServingEngine
+    from benchmark.reference import gpt_ref
 
     dist_env.set_mesh(None)
     paddle.seed(seed)
@@ -43,21 +61,49 @@ def build(config, seed, clock):
     model = GPTForCausalLM(GPTConfig(**model_cfg))
     if config['weights_dtype'] != 'float32':
         model.to(config['weights_dtype'])
+    weights = gpt_ref.weights(
+        seed, config['weights_dtype'], std=model_cfg['initializer_range'],
+        **{k: model_cfg[k] for k in (
+            'vocab_size', 'hidden_size', 'num_layers',
+            'intermediate_size', 'max_seq_len')})
+    missing, unexpected = model.set_state_dict(
+        {name: paddle.to_tensor(w) for name, w in weights.items()})
+    if missing or unexpected:
+        raise ValueError(f'the model has no weights for {missing} and no '
+                         f'tensor named {unexpected}')
     engine = ServingEngine(model, ServeConfig(**config['serve']),
                            now_fn=clock)
     pool_dtype = str(jnp.dtype(engine.cache.dtype))
     if pool_dtype != config['kv_pool']['dtype']:
         raise ValueError(f'the engine\'s KV pool is {pool_dtype}, the '
                          f'configuration states {config["kv_pool"]}')
-    return model, engine
+    return model, engine, weights
 
 
-def probe(config, engine, seed, say, perturb=0.0):
+def reference(config, weights, perturb=0.0, weights_as=None):
+    """`logits_at(ids, positions)` of the float32 reference over the
+    benchmark's `weights`; for the tests with `perturb` added to every
+    tensor; for the control with every matrix rounded to `weights_as`,
+    the precision below the configuration's."""
+    import functools
+    from benchmark.reference import gpt_ref
+    m = config['model']
+    if perturb:
+        weights = {k: v + np.asarray(perturb, v.dtype)
+                   for k, v in weights.items()}
+    return functools.partial(
+        gpt_ref.logits_at, weights, weights_as=weights_as,
+        num_layers=m['num_layers'], num_heads=m['num_heads'],
+        eps=m.get('layer_norm_epsilon', 1e-5))
+
+
+def probe(config, engine, logits_at, seed, say, compared):
     """One prompt a bucket through the empty engine, then the
     reference's forward of prompt + tokens.  Returns ok."""
     from paddle_tpu.serving.scheduler import Request
-    from benchmark.reference import gpt_ref
+    from benchmark import logit_gap
     m, p = config['model'], config['probe']
+    compared['probe_logit_gap'] = [float('inf'), float(p['logit_gap_tol'])]
     new = int(p['new_tokens'])
     rng = np.random.default_rng([int(seed), 2])
     id_limit = int(m['published_vocab_size'])
@@ -75,42 +121,36 @@ def probe(config, engine, seed, say, perturb=0.0):
                 f'with {len(req.tokens)} tokens')
             return False
         rows.append((prompt, list(req.tokens)))
-    t1 = time.monotonic()
-    width = max(len(pr) + new for pr, _ in rows)
-    ids = np.zeros((len(rows), width), np.int64)
-    positions = np.zeros((len(rows), new), np.int64)
-    for i, (pr, toks) in enumerate(rows):
-        ids[i, :len(pr)] = pr
-        ids[i, len(pr):len(pr) + new - 1] = toks[:-1]
-        positions[i] = len(pr) - 1 + np.arange(new)
-    params = engine._params
-    if perturb:
-        params = {k: v + np.asarray(perturb, v.dtype)
-                  for k, v in params.items()}
-    logits = np.asarray(gpt_ref.logits_at(
-        params, ids, positions, num_layers=m['num_layers'],
-        num_heads=m['num_heads'],
-        eps=m.get('layer_norm_epsilon', 1e-5)), np.float32)
-    chosen = np.asarray([toks for _, toks in rows])
-    gaps = logits.max(-1) - np.take_along_axis(
-        logits, chosen[:, :, None], axis=2)[:, :, 0]
-    same = int((logits.argmax(-1) == chosen).sum())
-    top2 = np.sort(logits, axis=-1)[:, :, -2:]
-    margin = top2[:, :, 1] - top2[:, :, 0]
+    say(f'probe: engine {time.monotonic() - t0:.1f}s')
+    ok, _gaps = logit_gap.check(
+        'probe_logit_gap', logits_at, rows, p['logit_gap_tol'], say,
+        compared, width=max(len(pr) + new for pr, _ in rows), keep=new,
+        block=len(rows), id_limit=id_limit,
+        what='prompts, one a bucket through the empty engine')
     audit = engine.scheduler.audit()
     whole = engine.cache.free_blocks == engine.cache.num_blocks - 1
-    say(f'probe: worst logit gap {gaps.max():.4f} (tol '
-        f'{p["logit_gap_tol"]}), per bucket '
-        f'{[round(float(g), 4) for g in gaps.max(1)]}, {same} of '
-        f'{chosen.size} tokens are the reference\'s best, whose margin '
-        f'over its second is median {np.median(margin):.4f}, least '
-        f'{margin.min():.4f}, and whose logits spread '
-        f'{logits[:, :, :id_limit].std():.3f}; audit '
-        f'{audit or "empty"}, pool whole {whole}; engine '
-        f'{t1 - t0:.1f}s, reference {time.monotonic() - t1:.1f}s')
-    return bool(np.isfinite(gaps).all()
-                and gaps.max() <= p['logit_gap_tol']
-                and not audit and whole)
+    say(f'probe: audit {audit or "empty"}, pool whole {whole}')
+    return bool(ok and not audit and whole)
+
+
+def served(config, traffic, rows, logits_at, say, compared, judged=None):
+    """After the window, outside every clock: the tokens of `rows`, a
+    sample of the requests the window finished (`logit_gap.sample`), by
+    the probe's gap and limit: every token, or where the configuration
+    gives `probe.served_tokens` a row's first and last half of that
+    many.  A probe never reaches batch buckets above the smallest,
+    slots and blocks in use by others, a slot that is used again or a
+    preempted request's second prefill; these rows do.  `judged` is the
+    control's: other tokens in the served ones' place.  Returns ok."""
+    from benchmark import logit_gap
+    p, most = config['probe'], int(traffic['new_tokens']['hi'])
+    ok, _gaps = logit_gap.check(
+        'served_logit_gap', logits_at, rows, p['logit_gap_tol'], say,
+        compared, width=int(traffic['prompt_len']['hi']) + most,
+        keep=int(p.get('served_tokens', most)), judged=judged,
+        id_limit=int(config['model']['published_vocab_size']),
+        what='requests the window finished')
+    return ok
 
 
 class EngineClock:
@@ -131,9 +171,18 @@ class EngineClock:
         self.trace_at = None
         self.counted = None
         self.before_trace = None
+        self.last = None
+        self.gaps = []      # the longest times between two readings
 
     def __call__(self):
         now = self.base()
+        # the engine reads its clock several times an intervention: a
+        # long time between two readings is a stall inside one
+        if self.last is not None and (
+                len(self.gaps) < 3 or now - self.last > self.gaps[-1][0]):
+            self.gaps = sorted(self.gaps + [(now - self.last, self.last)],
+                               reverse=True)[:3]
+        self.last = now
         tr = self.tracer
         if tr is not None and self.trace_at is not None \
                 and not tr.done:
@@ -150,18 +199,20 @@ def run(cell, seed, seconds, trace_on, t_start, say,
         clock=time.monotonic, reference_perturb=0.0):
     import jax
     from paddle_tpu.serving.scheduler import Request
-    from benchmark import harness
+    from benchmark import harness, logit_gap
     config, traffic = cell['config'], cell['traffic']
     compiles = harness.CompileCounter()
     eclock = EngineClock(clock)
     t0 = time.monotonic()
-    _model, engine = build(config, seed, eclock)
+    _model, engine, weights = build(config, seed, eclock)
+    logits_at = reference(config, weights, reference_perturb)
     t1 = time.monotonic()
     engine.warmup()
     t2 = time.monotonic()
     say(f'model and engine {t1 - t0:.1f}s, warm-up of '
         f'{engine.compile_count} modules {t2 - t1:.1f}s')
-    probe_ok = probe(config, engine, seed, say, perturb=reference_perturb)
+    compared = {}
+    probe_ok = probe(config, engine, logits_at, seed, say, compared)
     requests = importlib.import_module(
         'benchmark.generators.' + traffic['generator']).make(
             traffic, seed, seconds)
@@ -181,6 +232,7 @@ def run(cell, seed, seconds, trace_on, t_start, say,
         eclock.tracer = harness.TraceWindow(cell['name'])
     eclock.counted = counted
     arrivals_in_window = requests[-1].arrival_t > 0
+    eclock.last, eclock.gaps = None, []
     t_window = time.monotonic()
     setup_s = t_window - t_start
     before = counted(clock())
@@ -248,7 +300,13 @@ def run(cell, seed, seconds, trace_on, t_start, say,
     say(f'window: {len(requests)} offered, {done} done, {failed} failed, '
         f'{cut} cut; {report["decoded_tokens"]} tokens in {wall_s:.3f}s; '
         f'accounted {accounted}, tokens add up {tokens_add_up} '
-        f'({delivered}), audit {audit or "empty"}, pool whole {whole}')
+        f'({delivered}), audit {audit or "empty"}, pool whole {whole}; '
+        f'kv_read_share {report.get("kv_read_share")}, paged kernel '
+        f'{report.get("paged_kernel")}')
+    say('host: the longest times between two readings of the engine\'s '
+        'clock (an intervention is one or more), s at s into the window: '
+        + ', '.join(f'{gap:.3f} at {at - before["t"]:.1f}'
+                    for gap, at in eclock.gaps))
     if ttft_ms:
         pct = harness.percentile
         say(f'tails (ms): ttft p50 {pct(ttft_ms, .5):.1f} p95 '
@@ -256,6 +314,21 @@ def run(cell, seed, seconds, trace_on, t_start, say,
             f'{pct(tpot_ms, .5):.2f} p95 {pct(tpot_ms, .95):.2f} max '
             f'{max(tpot_ms):.2f}')
 
+    # read before the served tokens' reference compiles and allocates
+    compiles_in_window = (compiles.built - compiled_before) \
+        + (engine.compile_count - modules_before)
+    device = harness.device_info()
+    served_ok = served(
+        config, traffic, logit_gap.sample(
+            requests, seed, config['probe']['served_requests']),
+        logits_at, say, compared)
+    compared.update({
+        'requests_unaccounted': [0 if accounted else 1, 0],
+        'tokens_not_adding_up': [abs(delivered
+                                     - report['decoded_tokens']), 0],
+        'audit_findings': [len(audit), 0],
+        'pool_blocks_missing': [engine.cache.num_blocks - 1
+                                - engine.cache.free_blocks, 0]})
     interventions = upto['interventions'] - before['interventions']
     decoded = upto['decoded_tokens'] - before['decoded_tokens']
     if tracer is not None:
@@ -271,8 +344,9 @@ def run(cell, seed, seconds, trace_on, t_start, say,
         end_to_end['tpot_p95_ms'] = (
             harness.percentile(tpot_ms, 0.95), 'ms')
     return {
-        'correct': bool(probe_ok and accounted and tokens_add_up
-                        and not audit and whole),
+        'correct': bool(probe_ok and served_ok and accounted
+                        and tokens_add_up and not audit and whole),
+        'compared': compared, 'device': device,
         'attempted': attempted, 'failed': failed,
         'end_to_end': end_to_end,
         'counters': {
@@ -284,9 +358,8 @@ def run(cell, seed, seconds, trace_on, t_start, say,
             'preemptions': upto['preempted'] - before['preempted'],
             'ttft_p50_ms': harness.percentile(early_ttft_ms, 0.5)
             if early_ttft_ms else None,
-            'compiles_in_window': (compiles.built - compiled_before)
-            + (engine.compile_count - modules_before),
-            'peak_hbm_bytes': harness.device_info()['memory_peak_bytes'],
+            'compiles_in_window': compiles_in_window,
+            'peak_hbm_bytes': device['memory_peak_bytes'],
         },
         'trace': tracer.load() if tracer else None,
     }

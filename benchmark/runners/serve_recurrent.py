@@ -2,11 +2,12 @@
 is one recurrent state a sequence (a retention decoder) where the
 `serve` runner's is the paged KV pool.  ServingEngine.warmup(), then
 run(requests, timeout_s) inside one span, then its own report; the
-engine's loop is not rebuilt here.  `runners/serve.py` builds
-GPTForCausalLM and calls gpt_ref, and may not be edited by the PR that
-added this file, so `run` below is its `run` with another `build` and
-`probe` and three more counters; the clock that opens the profiler
-(`EngineClock`) is imported.
+engine's loop is not rebuilt here.  `run` below is `runners/serve.py`'s
+`run` with another `build`, `reference` and `probe` and three more
+counters (a copy: PERF.md section 7); the clock that opens the profiler
+(`EngineClock`), the served tokens' check (`served`) and, through
+`benchmark/logit_gap.py`, the gap, the sample and the decision are
+shared with it.
 
 correct = the same two parts, and a third.  (a) A probe before the
 window and the profiler, on a schedule this file fixes: one prompt a
@@ -25,8 +26,12 @@ float32.  (b) Invariants that hold under every interleaving: every
 request accounted for once, delivered tokens add up, the scheduler's
 audit empty, every slot free again.  (c) After the window, outside
 every clock, a sample of what was served under load against the
-reference by the same gap (`served`): a row's tokens do not depend on
-its batch, so this holds under every interleaving too.
+reference by the same gap (`serve.served`: the longest request the
+window finished and others drawn from the seed, of each the first and
+last `probe.served_tokens` / 2 tokens: a state the prefill did not
+overwrite shows in the first, and the gate forgets it within some
+twenty tokens): a row's tokens do not depend on its batch, so this
+holds under every interleaving too.
 
 A third architecture would bring: a model file under paddle_tpu/models
 with `prefill`/`decode_step` and, if its memory is neither of the two
@@ -39,7 +44,7 @@ import time
 
 import numpy as np
 
-from benchmark.runners.serve import EngineClock
+from benchmark.runners.serve import EngineClock, served
 
 
 def build(config, seed, clock):
@@ -84,37 +89,18 @@ def slot_of(req):
             if row['stage'] == 'prefill'][-1]
 
 
-def logit_gaps(config, engine, reqs, picks, width, perturb=0.0):
-    """For each request the float32 reference's logits at the positions
-    that chose its tokens `picks` [K] (indices into its tokens, from the
-    end where negative), one forward of prompt + tokens a request,
-    right-padded to `width` (one shape, one compilation).  Returns the gaps [B, K] between the
-    reference's best and the logit of the token the engine chose, how
-    often the two are the same token, and the margins [B, K] of the
-    reference's best over its second."""
+def reference(config, engine, perturb=0.0):
+    """`logits_at(ids, positions)` of the float32 reference over the
+    engine's weights (the program's own still: PERF.md section 7); for
+    the tests with `perturb` added to every tensor."""
+    import functools
     from benchmark.reference import brumby_ref
-    m = config['model']
     params = engine._params
     if perturb:
         params = {k: v + np.asarray(perturb, v.dtype)
                   for k, v in params.items()}
-    gaps, margins, same = [], [], 0
-    for req in reqs:
-        n = req.prompt.size
-        ids = np.zeros((1, width), np.int64)
-        ids[0, :n] = req.prompt
-        ids[0, n:n + len(req.tokens) - 1] = req.tokens[:-1]
-        # token j was chosen from the logits at position n - 1 + j
-        idx = picks % len(req.tokens)
-        logits = np.asarray(brumby_ref.logits_at(
-            params, ids, n - 1 + idx[None], **reference_kwargs(m))[0],
-            np.float32)
-        chosen = np.asarray(req.tokens)[idx]
-        gaps.append(logits.max(-1) - logits[np.arange(idx.size), chosen])
-        same += int((logits.argmax(-1) == chosen).sum())
-        top2 = np.sort(logits, axis=-1)[:, -2:]
-        margins.append(top2[:, 1] - top2[:, 0])
-    return np.asarray(gaps), same, np.asarray(margins)
+    return functools.partial(brumby_ref.logits_at, params,
+                             **reference_kwargs(config['model']))
 
 
 def state_errors(config, engine, reqs, seed):
@@ -185,10 +171,11 @@ def state_errors(config, engine, reqs, seed):
     return worst
 
 
-def probe(config, engine, seed, say, perturb=0.0):
+def probe(config, engine, logits_at, seed, say, compared):
     """One prompt a bucket, all live together, then the reference's
     forward of prompt + tokens and the states they left.  Returns ok."""
     from paddle_tpu.serving.scheduler import Request
+    from benchmark import logit_gap
     m, p = config['model'], config['probe']
     new = int(p['new_tokens'])
     rng = np.random.default_rng([int(seed), 2])
@@ -208,78 +195,40 @@ def probe(config, engine, seed, say, perturb=0.0):
     t1 = time.monotonic()
     # nothing has been admitted since: the slots hold what the probe left
     num_err, den_err = state_errors(config, engine, reqs, seed)
-    t2 = time.monotonic()
-    gaps, same, margin = logit_gaps(
-        config, engine, reqs, np.arange(new),
-        max(r.prompt.size for r in reqs) + new, perturb)
+    say(f'probe: engine {t1 - t0:.1f}s, states '
+        f'{time.monotonic() - t1:.1f}s')
+    ok, gaps = logit_gap.check(
+        'probe_logit_gap', logits_at,
+        [(r.prompt, list(r.tokens)) for r in reqs], p['logit_gap_tol'],
+        say, compared, width=max(r.prompt.size for r in reqs) + new,
+        keep=new, block=1, id_limit=id_limit,
+        what='prompts, one a bucket, live together')
     audit = engine.scheduler.audit()
     whole = engine.cache.free_blocks == engine.cache.slots
-    say(f'probe: worst logit gap {gaps.max():.4f} (tol '
-        f'{p["logit_gap_tol"]}), per prompt '
-        f'{[round(float(g), 4) for g in gaps.max(1)]}, in the last '
-        f'quarter of the tokens {gaps[:, -(new // 4):].max():.4f}, '
-        f'{same} of {gaps.size} tokens are the reference\'s best, '
-        f'whose margin over its second is median '
-        f'{np.median(margin):.4f}, least {margin.min():.4f}; held state '
-        f'of layer 0 against its definition: numerators {num_err:.3e}, '
+    say(f'probe: worst logit gap in the last quarter of the tokens '
+        f'{gaps[:, -(new // 4):].max():.4f}; held state of layer 0 '
+        f'against its definition: numerators {num_err:.3e}, '
         f'denominators {den_err:.3e} (tol {p["state_rel_tol"]}); audit '
-        f'{audit or "empty"}, slots free again {whole}; engine '
-        f'{t1 - t0:.1f}s, states {t2 - t1:.1f}s, reference '
-        f'{time.monotonic() - t2:.1f}s')
-    return bool(np.isfinite(gaps).all()
-                and gaps.max() <= p['logit_gap_tol']
-                and num_err <= p['state_rel_tol']
+        f'{audit or "empty"}, slots free again {whole}')
+    compared.update({
+        'state_numerator_rel': [num_err, float(p['state_rel_tol'])],
+        'state_denominator_rel': [den_err, float(p['state_rel_tol'])]})
+    return bool(ok and num_err <= p['state_rel_tol']
                 and den_err <= p['state_rel_tol']
                 and not audit and whole)
-
-
-def served(config, traffic, finished, engine, say, perturb=0.0):
-    """After the window, outside every clock: tokens the engine served
-    UNDER LOAD (every slot live, slots reused, the decode module's rows
-    3 and above, which the probe's three requests never reach) against
-    the reference, by the probe's gap.  The newest finished request of
-    each of `probe.served_requests` different slots; of each the first
-    and the last `probe.served_tokens` / 2 tokens (a state the prefill
-    did not overwrite shows in the first, and the gate forgets it within
-    some twenty tokens).  Returns ok."""
-    from paddle_tpu.serving.scheduler import Request
-    p = config['probe']
-    half = int(p['served_tokens']) // 2
-    t0 = time.monotonic()
-    picked = {}
-    for req in reversed(finished):
-        if req.state == Request.DONE and len(req.tokens) >= 2 * half:
-            picked.setdefault(slot_of(req), req)
-        if len(picked) == int(p['served_requests']):
-            break
-    if not picked:
-        say('served: no request finished in the window, nothing to hold '
-            'against the reference')
-        return True
-    reqs = list(picked.values())
-    picks = np.concatenate([np.arange(half), -1 - np.arange(half)[::-1]])
-    width = int(traffic['prompt_len']['hi'] + traffic['new_tokens']['hi'])
-    gaps, same, _margin = logit_gaps(config, engine, reqs, picks, width,
-                                     perturb)
-    say(f'served: {len(reqs)} requests from slots {sorted(picked)}, '
-        f'{gaps.size} tokens, worst logit gap {gaps.max():.4f} (tol '
-        f'{p["logit_gap_tol"]}), per request '
-        f'{[round(float(g), 4) for g in gaps.max(1)]}, {same} are the '
-        f'reference\'s best; reference {time.monotonic() - t0:.1f}s')
-    return bool(np.isfinite(gaps).all()
-                and gaps.max() <= p['logit_gap_tol'])
 
 
 def run(cell, seed, seconds, trace_on, t_start, say,
         clock=time.monotonic, reference_perturb=0.0):
     import jax
     from paddle_tpu.serving.scheduler import Request
-    from benchmark import harness
+    from benchmark import harness, logit_gap
     config, traffic = cell['config'], cell['traffic']
     compiles = harness.CompileCounter()
     eclock = EngineClock(clock)
     t0 = time.monotonic()
     _model, engine = build(config, seed, eclock)
+    logits_at = reference(config, engine, reference_perturb)
     t1 = time.monotonic()
     engine.warmup()
     t2 = time.monotonic()
@@ -288,7 +237,8 @@ def run(cell, seed, seconds, trace_on, t_start, say,
     # every module has run once beside the weights and the state: the
     # engine's own peak, before the reference allocates anything
     peak_hbm_bytes = harness.device_info()['memory_peak_bytes']
-    probe_ok = probe(config, engine, seed, say, perturb=reference_perturb)
+    compared = {}
+    probe_ok = probe(config, engine, logits_at, seed, say, compared)
     requests = importlib.import_module(
         'benchmark.generators.' + traffic['generator']).make(
             traffic, seed, seconds)
@@ -390,9 +340,10 @@ def run(cell, seed, seconds, trace_on, t_start, say,
     # before the served tokens' reference compiles its own shapes
     compiles_in_window = (compiles.built - compiled_before) \
         + (engine.compile_count - modules_before)
-    served_ok = served(config, traffic,
-                       engine.scheduler.finished[finished_before:],
-                       engine, say, perturb=reference_perturb)
+    served_ok = served(
+        config, traffic, logit_gap.sample(
+            requests, seed, config['probe']['served_requests']),
+        logits_at, say, compared)
     say(f'allocator peak: {peak_hbm_bytes} B after warm-up (the engine '
         f'alone), {harness.device_info()["memory_peak_bytes"]} B at the '
         'end (with the reference)')
@@ -411,6 +362,13 @@ def run(cell, seed, seconds, trace_on, t_start, say,
         end_to_end['tpot_p95_ms'] = (
             harness.percentile(tpot_ms, 0.95), 'ms')
     return {
+        'compared': dict(
+            compared,
+            requests_unaccounted=[0 if accounted else 1, 0],
+            tokens_not_adding_up=[abs(delivered
+                                      - report['decoded_tokens']), 0],
+            audit_findings=[len(audit), 0],
+            slots_not_free=[0 if whole else 1, 0]),
         'correct': bool(probe_ok and served_ok and accounted
                         and tokens_add_up and not audit and whole),
         'attempted': attempted, 'failed': failed,

@@ -80,7 +80,7 @@ def build(config, seed, mesh_axes=None):
     return model, trainer
 
 
-def probe(config, trainer, ids, say, perturb=0.0):
+def probe(config, trainer, ids, say, compared, perturb=0.0):
     """Reference loss on the trainer's parameters as they stand, then
     the warm-up steps on the same batch.  Returns (ok, detail)."""
     import jax
@@ -102,6 +102,9 @@ def probe(config, trainer, ids, say, perturb=0.0):
         f'rel {rel:.2e} (tol {p["loss_rel_tol"]:.0e}); warm-up losses '
         f'{[round(v, 4) for v in losses]}; reference {t1 - t0:.1f}s, '
         f'{p["warmup_steps"]} warm-up steps {t2 - t1:.1f}s')
+    compared['first_loss_rel'] = [float(np.nan_to_num(rel, nan=np.inf)),
+                                  float(p['loss_rel_tol'])]
+    compared['warmup_loss_not_falling'] = [0 if falling else 1, 0]
     return rel <= p['loss_rel_tol'] and falling
 
 
@@ -117,7 +120,8 @@ def run(cell, seed, seconds, trace_on, t_start, say,
         'benchmark.generators.' + traffic['generator']).make(traffic, seed)
     t1 = time.monotonic()
     say(f'model and trainer {t1 - t0:.1f}s')
-    probe_ok = probe(config, trainer, batches.batch(0), say,
+    compared = {}
+    probe_ok = probe(config, trainer, batches.batch(0), say, compared,
                      perturb=reference_perturb)
     say(f'set-up compile cache: {compiles.hits} hits, {compiles.misses} '
         f'misses of {compiles.built} programs')
@@ -180,6 +184,7 @@ def run(cell, seed, seconds, trace_on, t_start, say,
             for i in slow[:8]))
     return {
         'correct': probe_ok and failed == 0,
+        'compared': dict(compared, non_finite_losses=[failed, 0]),
         'attempted': len(losses), 'failed': failed,
         'end_to_end': {'train_tokens_per_s': (rate, 'tokens/s'),
                        'setup_s': (setup_s, 's')},

@@ -1,22 +1,25 @@
 #!/usr/bin/env python3
 """Find the knee of a serving cell once, on the chip:
 
-    python3 benchmark/sweep.py --workload serve_chat_steady --rates 1.5,1.75,2,2.25,2.5 --span 60
+    python3 benchmark/sweep.py --workload serve_chat_steady --rates 13,14,15,16,17,18,19 --span 60
 
 One engine is warmed once; each rate is then offered for `span` seconds
 (the cell's own traffic file with `rate_rps` replaced, made by the
 cell's own generator) and cut there.  One JSON line a rate: offered,
 completed, still running and still waiting at the cut, tokens/s offered
 and decoded, the mean number of requests waiting for their first token
-in the second and in the last quarter of the span, and the TTFT p95 of
-the requests due in each half.
+in the second and in the last quarter of the span, the pace's 95th
+percentile over the requests that finished, and the TTFT p95 of the
+requests due in each half.
 
 The knee is the highest rate whose backlog does not grow: with every
 lower rate, the mean number waiting in the last quarter is at most
 GROWTH more than in the second quarter.  Beyond capacity the queue
 gains (rate - capacity) * span / 2 requests between the two, some 7 at
 a quarter of a request a second too many over 60 s; below it both are
-the few that arrived during the running intervention.  The cell's
+the few that arrived during the running intervention (under 2.5 up to
+16 requests/s in PR 30's sweeps, 29 at 17: the criterion read the knee
+at twelve times PR 24's rates as it stands).  The cell's
 `rate_rps` is four fifths of the knee, written into the traffic file by
 hand; the table goes into PERF.md.
 """
@@ -63,8 +66,8 @@ def main(argv=None):
     from paddle_tpu.core import compile_cache
     compile_cache.setup_xla_cache()
     cell = harness.load_cell(args.workload)
-    _model, engine = serve.build(cell['config'], args.seed,
-                                 time.monotonic)
+    _model, engine, _weights = serve.build(cell['config'], args.seed,
+                                           time.monotonic)
     engine.warmup()
     knee, growing = None, False
     for i, rate in enumerate(float(r) for r in args.rates.split(',')):
@@ -82,6 +85,8 @@ def main(argv=None):
                 halves[due[r.rid] >= args.span / 2].append(
                     (r.first_token_t - r.arrival_t) * 1e3)
         cut = [r for r in reqs if r.state != 'done']
+        tpot = [(r.finish_t - r.first_token_t) / (len(r.tokens) - 1) * 1e3
+                for r in reqs if r.state == 'done' and len(r.tokens) > 1]
         quarter = args.span / 4
         early = mean_waiting(reqs, start + quarter, start + 2 * quarter)
         late = mean_waiting(reqs, start + 3 * quarter,
@@ -99,6 +104,7 @@ def main(argv=None):
             'offered_tokens_per_s': sum(r.max_new_tokens for r in reqs)
             / args.span,
             'tokens_per_s': report['decoded_tokens'] / report['wall_s'],
+            'tpot_p95_ms': harness.percentile(tpot, .95) if tpot else None,
             'ttft_p95_ms_first_half': harness.percentile(halves[0], .95)
             if halves[0] else None,
             'ttft_p95_ms_second_half': harness.percentile(halves[1], .95)

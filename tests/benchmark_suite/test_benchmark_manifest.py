@@ -141,6 +141,27 @@ def test_train_mfu_divides_by_the_chips_of_the_cell():
     assert train_mfu.read({}, dict(ctx, on_tpu=False)) is None
 
 
+def test_retention_step_mfu_is_the_decoders_operations_over_the_peak():
+    """Beside the retention kernel's roofline stands the whole decode
+    step's share of the chip's peak, moving the same metric: 7.7 GFLOP
+    a served token (6.84 G of it the weights' two operations a
+    parameter met) at the ledger's 555 tokens/s is 2.2% of 197 TFLOP/s,
+    a step bound by bytes."""
+    from benchmark import retention_flops
+    from benchmark.readers import retention_step_mfu
+    cfg = json.load(open(os.path.join(
+        REPO, 'benchmark/configs/brumby_14b_serve.json')))
+    per_token = retention_flops.decode_step_ops_per_token(cfg['model'])
+    weights = 2 * (8 * 330.35e6 + 5120 * 151936)
+    assert weights < per_token < 1.15 * weights
+    ctx = {'counters': {'decoded_tokens': 555.5 * 45, 'window_ms': 45e3},
+           'on_tpu': True, 'device_kind': 'TPU v5 lite', 'config': cfg,
+           'chips': 1}
+    assert 2.1 < retention_step_mfu.read({}, ctx) < 2.3
+    assert retention_step_mfu.read({}, dict(ctx, on_tpu=False)) is None
+    assert retention_step_mfu.read({}, dict(ctx, counters={})) is None
+
+
 def test_configuration_files_say_what_they_stand_for(manifest):
     for c in manifest['configs']:
         cfg = json.load(open(os.path.join(REPO, c['file'])))
@@ -168,3 +189,16 @@ def test_configuration_files_say_what_they_stand_for(manifest):
     assert pool['positions'] == pool['num_blocks'] \
         * serve['serve']['block_size']
     assert pool['bytes'] == pool['positions'] * per_pos
+    # "a pool that fills the rest of the chip": the weights (12 h^2 a
+    # layer with n_inner 4 h, their biases and norms, the tied embedding
+    # and the positions, two bytes each) and the pool are at least 70%
+    # of the chip, and leave 1 GB of what the compiler may use
+    h, layers = m['hidden_size'], m['num_layers']
+    assert serve['weights_dtype'] == 'bfloat16'
+    weights = 2 * (layers * (12 * h * h + 13 * h)
+                   + (m['vocab_size'] + m['max_seq_len']) * h + 2 * h)
+    assert 2.62e9 < weights < 2.64e9
+    hbm = json.load(open(os.path.join(
+        REPO, 'benchmark/peaks.json')))['TPU v5 lite']['hbm_bytes']
+    assert weights + pool['bytes'] >= 0.70 * hbm
+    assert weights + pool['bytes'] <= 15.75 * 2 ** 30 - 1e9

@@ -14,7 +14,7 @@ import pytest
 
 from bench_helpers import HERE
 
-KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device'}
+KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device', 'compared'}
 CELL = 'serve_backlog_retention'
 
 
@@ -39,6 +39,10 @@ def run_tiny(seed=2147495993, seconds=1.0, trace=0, cell=None,
 def test_the_cell_runs_and_is_correct(how):
     line = run_tiny(seconds=1.5, trace=int(how == 'traced'))
     assert set(line) == KEYS
+    assert list(line)[-1] == 'compared'
+    assert {'probe_logit_gap', 'state_numerator_rel', 'served_logit_gap',
+            'state_denominator_rel'} <= set(line['compared'])
+    assert all(v <= limit for v, limit in line['compared'].values())
     assert line['correct'] is True
     assert line['failed'] == 0 and 0 < line['attempted'] < 2048
     assert line['device']['platform'] == 'cpu'
@@ -115,48 +119,36 @@ def test_a_fault_in_the_retention_path_turns_correct_false(monkeypatch,
     assert line['failed'] == 0
 
 
-def _said(capfd, *heads):
-    """What the run just made said on stderr under each head."""
-    err = capfd.readouterr().err.splitlines()
-    return [[line for line in err if line.startswith(f'[bench] {head}:')]
-            for head in heads]
-
-
-def test_a_state_held_in_bfloat16_turns_correct_false(monkeypatch, capfd):
+def test_a_state_held_in_bfloat16_turns_correct_false(monkeypatch):
     """The control of `probe.state_rel_tol`: the precision below the
     one the configuration states.  The logit gap does not see it (that
     is why the second limit exists); the held state's distance from its
     definition does, by more than ten times the limit, where a float32
     state stays a hundred times under it."""
-    import re
     import jax.numpy as jnp
     from paddle_tpu.serving.kv_cache import RecurrentStateCache
     line = run_tiny(seconds=0.5)
-    (sound,), = _said(capfd, 'probe')
     monkeypatch.setattr(RecurrentStateCache, 'dtype', jnp.bfloat16)
     cell = tiny_cell()
     cell['config']['state']['dtype'] = 'bfloat16'
-    tol = cell['config']['probe']['state_rel_tol']
     broken = run_tiny(seconds=0.5, cell=cell)
-    (rounded,), = _said(capfd, 'probe')
     assert line['correct'] is True and broken['correct'] is False
 
-    def readings(said):
-        gap = float(re.search(r'worst logit gap ([0-9.]+)', said).group(1))
-        errs = re.search(r'numerators ([0-9.e+-]+), denominators '
-                         r'([0-9.e+-]+)', said).groups()
-        return gap, max(float(e) for e in errs)
+    def readings(compared):
+        gap, gap_tol = compared['probe_logit_gap']
+        assert gap <= gap_tol
+        (num, tol), (den, _) = (compared['state_numerator_rel'],
+                                compared['state_denominator_rel'])
+        return max(num, den), tol
 
-    gap, err = readings(sound)
-    assert gap <= cell['config']['probe']['logit_gap_tol']
+    err, tol = readings(line['compared'])
     assert err < tol / 30             # float32 rounding, 1e-6
-    gap, err = readings(rounded)
-    assert gap <= cell['config']['probe']['logit_gap_tol']
+    err, tol = readings(broken['compared'])
     assert err > 10 * tol             # bfloat16 rounding, 2e-3
 
 
 def test_a_state_that_survives_its_slots_reuse_turns_correct_false(
-        monkeypatch, capfd):
+        monkeypatch):
     """A fault only load can show: the prefill's write is dropped for a
     slot that has held a sequence before, so its next sequence decodes
     from the last one's state.  The probe's three requests take three
@@ -182,11 +174,12 @@ def test_a_state_that_survives_its_slots_reuse_turns_correct_false(
     monkeypatch.setattr(RecurrentStateCache, 'store_prefill', stale)
     line = run_tiny(seconds=1.0)
     assert line['correct'] is False and line['failed'] == 0
-    (probe,), (served,) = _said(capfd, 'probe', 'served')
-    assert 'worst logit gap 0.0' in probe
-    # three slots sampled, each reused by then: most tokens are not
-    # the reference's best
-    assert 'worst logit gap 0.0' not in served, served
+    probe, limit = line['compared']['probe_logit_gap']
+    assert probe <= limit
+    # three requests sampled, their slots reused by then: most tokens
+    # are not the reference's best
+    served, limit = line['compared']['served_logit_gap']
+    assert served > 2 * limit
 
 
 def test_retention_flops_against_a_count_by_hand():

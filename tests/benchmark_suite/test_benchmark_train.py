@@ -15,7 +15,11 @@ def test_train_result_line():
     line = run_tiny('train_seq2048', seconds=1.0)
     json.dumps(line)
     assert set(line) == {'correct', 'attempted', 'failed', 'metrics',
-                         'device'}
+                         'device', 'compared'}
+    assert list(line)[-1] == 'compared'
+    assert set(line['compared']) == {
+        'first_loss_rel', 'warmup_loss_not_falling', 'non_finite_losses'}
+    assert all(v <= limit for v, limit in line['compared'].values())
     assert line['correct'] is True and line['failed'] == 0
     assert line['attempted'] >= 1
     assert set(line['metrics']) == {'train_tokens_per_s', 'setup_s'}
@@ -25,13 +29,29 @@ def test_train_result_line():
 def test_train_traced_reports_counters_and_no_device_number():
     line = run_tiny('train_seq2048', seconds=1.0, trace=1)
     assert set(line) == {'correct', 'attempted', 'failed', 'metrics',
-                         'device'}
+                         'device', 'compared'}
     assert line['correct'] is True
     assert {'train_step_ms', 'compiles_in_window.train'} \
         <= set(line['metrics'])
     assert line['metrics']['compiles_in_window.train']['value'] == 0
     assert not {'train_mfu', 'device_idle.train', 'flash_fwd_roofline',
                 'peak_hbm_gb.train'} & set(line['metrics'])
+
+
+@pytest.mark.parametrize('trace', [0, 1])
+def test_the_wait_for_the_chip_is_a_metric_of_its_own(trace):
+    """`setup_s` leaves out the runtime's opening of the device, which
+    a traced run reports beside it as `chip_open_s`."""
+    import time
+    from benchmark import run
+    from bench_helpers import tiny_cell
+    line = run.run_cell(tiny_cell('train_seq2048'), 2147495993, 0.3,
+                        trace, time.monotonic() - 100.0, chip_open_s=60.0)
+    if trace:
+        assert line['metrics']['chip_open_s'] == {'value': 60.0,
+                                                  'unit': 's'}
+    else:
+        assert 40.0 < line['metrics']['setup_s']['value'] < 100.0
 
 
 def test_a_wrong_reference_turns_correct_false():
