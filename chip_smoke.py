@@ -16,7 +16,7 @@ One process drives the main path once at the full width of GPT-2 small
   trainer   ParallelTrainer.step on a fixed [8, 1024] batch, AMP O2,
             AdamW: loss finite and falling, Pallas custom calls present
             in the compiled step
-  server    ServingEngine at the bench serve config behind
+  server    ServingEngine at serve_setup()'s config behind
             ServingFrontend on loopback: HTTP requests over both prompt
             buckets, streamed and unstreamed; no compile after warm-up,
             empty audit, greedy tokens agree with the dense path
@@ -53,6 +53,25 @@ MESH_LOSS_TOL = 2e-2
 TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 8, 1024, 6
 SERVE_PROMPT_LENS = (24, 32, 48, 64, 20, 30, 40, 60)
 SERVE_NEW_TOKENS = 16
+
+
+def serve_setup():
+    """The server leg's model and engine config: GPT-2 small in eval
+    mode, 64 slots of continuous batching over two prompt and two batch
+    buckets, greedy.  SERVE_PROMPT_LENS cover both prompt buckets and
+    fit max_model_len with SERVE_NEW_TOKENS."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import gpt_small
+    from paddle_tpu.serving import ServeConfig
+
+    paddle.seed(0)
+    model = gpt_small(max_seq_len=256, dropout=0.0)
+    model.eval()
+    cfg = ServeConfig(block_size=16, max_slots=64, decode_span=8,
+                      prompt_buckets=(32, 64), batch_buckets=(8, 64),
+                      max_model_len=160, temperature=0.0)
+    return model, cfg
+
 
 FLASH_KERNELS = ('flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv')
 LN_KERNEL = 'layer_norm_fwd'
@@ -240,7 +259,7 @@ def leg_kernels():
 # -- trainer ------------------------------------------------------------------
 
 def train_gpt(mesh=None):
-    """The `gpt` bench shape through ParallelTrainer: returns (losses,
+    """GPT-2 small at [8, 1024] through ParallelTrainer: returns (losses,
     compile_s, steady step ms, compiled HLO text, trainer)."""
     import jax
     import numpy as np
@@ -335,14 +354,13 @@ def leg_server():
     import jax
     import numpy as np
     import paddle_tpu as paddle
-    from bench import _serve_setup
     from paddle_tpu.distributed import env as dist_env
     from paddle_tpu.jit import functional_call
     from paddle_tpu.serving import ServingEngine
     from paddle_tpu.serving.frontend import ServingFrontend
 
     dist_env.set_mesh(None)
-    model, cfg, _load = _serve_setup(smoke=False)
+    model, cfg = serve_setup()
     eng = ServingEngine(model, cfg)
     t0 = time.perf_counter()
     eng.warmup()

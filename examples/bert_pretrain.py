@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""BERT masked-LM pretraining steps — the bench.py `bert` config as a
-user script: fused MLM head (no [B·T, V] logits tensor), bf16 AMP O2,
+"""BERT masked-LM pretraining steps as a user script: fused MLM head (no [B·T, V] logits tensor), bf16 AMP O2,
 whole step in one XLA module.
 
     python examples/bert_pretrain.py                 # tiny config

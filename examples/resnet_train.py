@@ -1,13 +1,12 @@
 #!/usr/bin/env python
-"""ResNet-50 bf16(AMP O2) training — the headline throughput config
-(bench.py `resnet`), written the way a user would: DataLoader feeding
-a ParallelTrainer whose whole fwd+bwd+update step is ONE XLA module.
+"""ResNet-50 bf16(AMP O2) training, written the way a user would:
+DataLoader feeding a ParallelTrainer whose whole fwd+bwd+update step is ONE XLA module.
 
     python examples/resnet_train.py [--steps 30] [--batch-size 256]
     python examples/resnet_train.py --depth 18 --image 64  # small run
 
 --space-to-depth enables the MLPerf-TPU stem (exact same function,
-measured on chip via tools/perf_experiments.py)."""
+tests/test_vision_text.py; its speed on the chip is not measured)."""
 import argparse
 import time
 

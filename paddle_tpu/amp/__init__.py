@@ -28,7 +28,7 @@ __all__ = ['auto_cast', 'amp_guard', 'decorate', 'amp_decorate',
 WHITE_LIST = frozenset({
     'matmul', 'bmm', 'mv', 'dot', 'mul', 'linear', 'conv1d', 'conv2d',
     'conv3d', 'conv2d_transpose', 'conv1d_transpose', 'conv3d_transpose',
-    'einsum', 'addmm', 'fused_linear_gelu', 'flash_attention',
+    'einsum', 'addmm', 'flash_attention',
 })
 
 # Numerically-sensitive ops kept in float32 (reference black list:

@@ -254,8 +254,8 @@ def emit(report, mode='warn'):
     mode: falsy -> silent; 'warn'/True -> one LintWarning per report;
     'error' -> LintError on any high-severity finding (lower ones
     still warn).  Findings additionally land as telemetry
-    ``lint_finding`` events (countable per run, and part of the bench
-    artifact's evidence chain) regardless of warn/error mode."""
+    ``lint_finding`` events (countable per run) regardless of
+    warn/error mode."""
     if not mode or not report:
         return report
     _telemetry_findings(report)

@@ -12,8 +12,8 @@ layers make compiled work durable across processes:
   -> ``<checkout>/.jax_cache``.  The path is part of jax's cache key,
   so it is fixed: never ``~``, a temporary name, a pid or the time.
 * **the exec and text tiers** of this module serve only when
-  ``PADDLE_TPU_COMPILE_CACHE`` names a directory (tests,
-  ``bench.py --cache-smoke`` and ``tools/precompile.py`` do):
+  ``PADDLE_TPU_COMPILE_CACHE`` names a directory (tests and
+  ``tools/precompile.py`` do):
 
   - *exec tier* — serialized ``jax.export`` artifacts (StableHLO +
     calling convention) of a jitted function.  A warm process

@@ -10,7 +10,7 @@ and `jax.device_put` happens at dequeue so H2D copy overlaps compute
 device-bound, so threads (which release the GIL inside numpy) replace
 the reference's process workers for typical decode/augment loads.
 
-Worker-mode boundary (measured, tools/bench_dataloader_workers.py):
+Worker-mode boundary (measured on the host, an earlier round):
 threads are the default — numpy-releasing-GIL augments run at sync
 speed or better with zero IPC cost.  PIL/Python-heavy transforms hold
 the GIL, so threads serialize; `use_process_workers=True` forks child
@@ -653,7 +653,7 @@ class DataLoader:
         escape hatch for PIL/Python-heavy transforms where threads
         serialize on the GIL (reference
         io/dataloader/dataloader_iter.py forks workers for the same
-        reason; see tools/bench_dataloader_workers.py for the measured
+        reason; the module docstring has the measured
         thread-vs-process crossover).  Start method: `fork` where the
         platform has it (like the reference — no main-module guard
         needed, closures allowed, no per-child re-import; safe here

@@ -117,9 +117,6 @@ class BertLayer(nn.Layer):
 
     def forward(self, x, attn_mask=None):
         x = self.ln1(x + self.drop(self.attn(x, attn_mask)))
-        # single chip: fused matmul+GELU epilogue kernel whose backward
-        # recomputes the pre-activation instead of saving the [B,T,4H]
-        # tensor (ops/fused_gelu_linear.py); mesh: tp-sharded path
         from ..ops.fused_gelu_linear import mlp_gelu
         h = mlp_gelu(x, self.fc, shard_spec=('dp', None, 'tp'))
         h = self.proj(h)

@@ -83,9 +83,9 @@ class GPTConfig:
         # measurement (stacks hoisted out of the token body): compile
         # -33%, runtime +70% — CPU materializes each layer's param
         # slice as a copy per token, which TPU's while-loop HBM reads
-        # do not; OPT-IN until the chip A/B
-        # (tools/bench_scan_decode.py) shows the compile shrink is
-        # worth the TPU runtime delta.  Token-exact parity with the
+        # do not; OPT-IN until one A/B inside a serve cell
+        # (ROADMAP D3, S8) shows the compile shrink is worth the TPU
+        # runtime delta: not measured on the chip.  Token-exact parity with the
         # unrolled path is locked in tests/test_kv_cache.py.  Ignored
         # for heterogeneous stacks (MoE every-k blocks).
         self.scan_decode_blocks = scan_decode_blocks
@@ -315,7 +315,6 @@ class GPTMLP(nn.Layer):
         self.cfg = cfg
 
     def forward(self, x):
-        # fused matmul+GELU on single chip, tp-sharded path on a mesh
         from ..ops.fused_gelu_linear import mlp_gelu
         h = mlp_gelu(x, self.fc, shard_spec=('dp', None, 'tp'))
         h = self.proj(h)

@@ -43,10 +43,9 @@ class _FusedSparseEmbedding(nn.Layer):
 class _PerFieldSparseEmbedding(nn.Layer):
     """Reference-style per-field tables — F separate gathers + stack
     (the shape of the reference's per-slot lookup_table calls,
-    fleet/runtime/the_one_ps.py:417).  Kept as the baseline arm of the
-    fused-vs-per-field gather A/B (tools/bench_widedeep_gather.py,
-    PERF round-3 lead 3); the fused single-table gather is the
-    default."""
+    fleet/runtime/the_one_ps.py:417).  Kept as the reference the fused
+    single-table gather, the default, is compared with
+    (tests/test_models.py)."""
 
     def __init__(self, field_dims, embed_dim):
         super().__init__()

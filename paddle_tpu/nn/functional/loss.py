@@ -35,9 +35,9 @@ def _softmax_nll(x, lab):
     The autodiff backward of the take_along_axis gather is a
     scatter-add into the full [N, V] buffer — serialized on TPU; the
     unfused GPT-2 train step measured ~8x slower than expected at
-    vocab shape [8192, 50304] with it on the path (PERF.md round-4
-    chip session 2; tools/bench_ce_backward.py isolates the
-    formulations on hardware).  The custom backward emits the
+    vocab shape [8192, 50304] with it on the path (an earlier round's
+    chip session; tests/test_fused_ce.py::TestDenseCEBackward compares
+    the two).  The custom backward emits the
     classic softmax-CE gradient (softmax - one_hot) * g as dense
     elementwise math, and recomputes softmax from the saved logits
     instead of keeping the f32 log-probs residual alive.

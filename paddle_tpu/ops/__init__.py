@@ -4,7 +4,5 @@ jnp reference implementation off-TPU or for unsupported shapes."""
 from .flash_attention import flash_attention  # noqa: F401
 from .fused_norm import fused_layer_norm  # noqa: F401
 from .fused_softmax import fused_softmax  # noqa: F401
-from .fused_gelu_linear import fused_linear_gelu  # noqa: F401
 
-__all__ = ['flash_attention', 'fused_layer_norm', 'fused_softmax',
-           'fused_linear_gelu']
+__all__ = ['flash_attention', 'fused_layer_norm', 'fused_softmax']

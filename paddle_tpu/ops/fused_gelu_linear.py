@@ -1,219 +1,20 @@
-"""Fused matmul + bias + GELU epilogue (Pallas), with a recompute-fused
-backward.
+"""The fc + GELU half of a transformer MLP, shared by the GPT and BERT
+blocks.
 
-Reference analogue: the reference's fused GEMM+activation CUDA ops
-(paddle/fluid/operators/fused/, e.g. fused_gemm_epilogue /
-fc_elementwise_layernorm); SURVEY.md §2 item 36's fourth kernel.
-
-TPU-native design: the step is HBM-bound (see PERF.md), so the win is
-NOT the epilogue itself (XLA fuses bias+GELU into the matmul already) —
-it is the BACKWARD: instead of saving the [M, N] pre-activation z for
-gelu'(z), the backward RE-computes z inside a second fused kernel that
-emits dz = dy * gelu'(x@w + b) directly.  Residuals shrink from
-(x, w, z) to (x, w, b): one full [M, N] HBM write + read traded for one
-extra MXU matmul — the right trade on a bandwidth-bound chip.
-
-    forward : y  = gelu(x @ w + b)          one kernel, no z in HBM
-    backward: dz = dy * gelu'(x @ w + b)    one kernel, recomputes z
-              dx = dz @ w.T ; dw = x.T @ dz ; db = sum(dz)   (XLA)
+XLA fuses bias + GELU into the matmul's epilogue on its own.  A
+hand-written Pallas matmul+GELU kernel with a recompute-fused backward
+was measured on the v5e at 5.2 TFLOP/s against XLA's 11.1 at
+[8192, 768] x [768, 3072] bf16 and was deleted: do not write it again.
 """
-import functools
-import math
-
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from . import _gating
-
-__all__ = ['fused_linear_gelu']
-
-_BM, _BN, _BK = 256, 256, 512
-
-_SQRT_2_OVER_PI = math.sqrt(2.0 / math.pi)
-
-
-def _gelu_f32(z, approximate):
-    if approximate:
-        inner = _SQRT_2_OVER_PI * (z + 0.044715 * z * z * z)
-        return 0.5 * z * (1.0 + jnp.tanh(inner))
-    return 0.5 * z * (1.0 + jax.lax.erf(z / math.sqrt(2.0)))
-
-
-def _gelu_grad_f32(z, approximate):
-    if approximate:
-        inner = _SQRT_2_OVER_PI * (z + 0.044715 * z * z * z)
-        t = jnp.tanh(inner)
-        sech2 = 1.0 - t * t
-        dinner = _SQRT_2_OVER_PI * (1.0 + 3 * 0.044715 * z * z)
-        return 0.5 * (1.0 + t) + 0.5 * z * sech2 * dinner
-    cdf = 0.5 * (1.0 + jax.lax.erf(z / math.sqrt(2.0)))
-    pdf = jnp.exp(-0.5 * z * z) / math.sqrt(2.0 * math.pi)
-    return cdf + z * pdf
-
-
-def _reference(x, w, b, approximate):
-    z = (x @ w).astype(jnp.float32)
-    if b is not None:
-        z = z + b.astype(jnp.float32)
-    return _gelu_f32(z, approximate).astype(x.dtype)
-
-
-def _accumulate(x_ref, w_ref, acc_ref, k):
-    @pl.when(k == 0)
-    def _zero():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    acc_ref[:] += jnp.dot(x_ref[:], w_ref[:],
-                          preferred_element_type=jnp.float32)
-
-
-def _fwd_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, nk, approximate):
-    """y = gelu(x @ w + b): f32 VMEM accumulator, epilogue on the last
-    K step — the pre-activation never touches HBM."""
-    k = pl.program_id(2)
-    _accumulate(x_ref, w_ref, acc_ref, k)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        z = acc_ref[:] + b_ref[:].astype(jnp.float32)
-        o_ref[:] = _gelu_f32(z, approximate).astype(o_ref.dtype)
-
-
-def _bwd_kernel(x_ref, w_ref, b_ref, dy_ref, o_ref, acc_ref, *, nk,
-                approximate):
-    """dz = dy * gelu'(x @ w + b): recomputes z instead of reading a
-    saved copy from HBM."""
-    k = pl.program_id(2)
-    _accumulate(x_ref, w_ref, acc_ref, k)
-
-    @pl.when(k == nk - 1)
-    def _epilogue():
-        z = acc_ref[:] + b_ref[:].astype(jnp.float32)
-        dy = dy_ref[:].astype(jnp.float32)
-        o_ref[:] = (dy * _gelu_grad_f32(z, approximate)) \
-            .astype(o_ref.dtype)
-
-
-def _mm_epilogue(x, w, b, dy, approximate, bm, bn, bk):
-    M, K = x.shape
-    _, N = w.shape
-    nk = K // bk
-    grid = (M // bm, N // bn, nk)
-    in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-        pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        # bias rides as (1, N): Mosaic rejects 1-D bf16 operands whose
-        # XLA tiling disagrees with the kernel's (seen on v5e), and a 2-D
-        # row broadcasts against the (bm, bn) accumulator for free
-        pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-    ]
-    operands = [x, w, b.reshape(1, -1)]
-    if dy is None:
-        kernel = functools.partial(_fwd_kernel, nk=nk,
-                                   approximate=approximate)
-    else:
-        kernel = functools.partial(_bwd_kernel, nk=nk,
-                                   approximate=approximate)
-        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)))
-        operands.append(dy)
-    return pl.pallas_call(
-        kernel,
-        interpret=_gating.INTERPRET,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
-    )(*operands)
-
-
-def _pick_blocks(M, K, N):
-    def fit(dim, pref):
-        b = pref
-        while b > 128 and dim % b != 0:
-            b //= 2
-        return b if dim % b == 0 else None
-
-    bm = fit(M, _BM)
-    bn = fit(N, _BN)
-    bk = fit(K, _BK)
-    if None in (bm, bn, bk):
-        return None
-    return bm, bn, bk
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _fused(x2d, w, b, approximate, blocks):
-    bm, bn, bk = blocks
-    return _mm_epilogue(x2d, w, b, None, approximate, bm, bn, bk)
-
-
-def _fused_fwd(x2d, w, b, approximate, blocks):
-    return _fused(x2d, w, b, approximate, blocks), (x2d, w, b)
-
-
-def _fused_bwd(approximate, blocks, res, dy):
-    x2d, w, b = res
-    bm, bn, bk = blocks
-    dz = _mm_epilogue(x2d, w, b, dy, approximate, bm, bn, bk)
-    dzf = dz.astype(jnp.float32)
-    dx = (dz @ w.T).astype(x2d.dtype)
-    dw = (x2d.T @ dz).astype(w.dtype)
-    db = dzf.sum(axis=0).astype(b.dtype)
-    return dx, dw, db
-
-
-_fused.defvjp(_fused_fwd, _fused_bwd)
-
-
-USE_PALLAS_MLP = False  # measured on v5e: the Pallas kernel runs the
-# [8192, 768]x[768, 3072] bf16 MLP at 5.2 TFLOPS vs XLA's 11.1 — XLA's
-# own matmul+epilogue fusion wins at transformer shapes (PERF.md), so
-# the kernel stays opt-in (flip this, or call fused_linear_gelu
-# directly) and the default path lets the compiler fuse.
 
 
 def mlp_gelu(x, fc, shard_spec=None):
-    """Shared model-side dispatch for the fc+GELU half of a transformer
-    MLP: XLA matmul + fused GELU epilogue by default (measured faster
-    than the hand-written kernel — see USE_PALLAS_MLP); under a mesh the
-    tp-sharded column-parallel path additionally applies shardings.
-
-    x: Tensor [..., H]; fc: a Linear-like Layer with .weight/.bias;
-    shard_spec: the activation PartitionSpec for the mesh path."""
-    from ..distributed import env as _env
-    from ..core.dispatch import apply
-    if USE_PALLAS_MLP and _env.get_mesh() is None:
-        return apply(lambda xv, wv, bv: fused_linear_gelu(
-            xv, wv, bv, approximate=True),
-            x, fc.weight, fc.bias, op_name='fused_linear_gelu')
+    """x: Tensor [..., H]; fc: a Linear-like Layer with .weight/.bias;
+    shard_spec: the activation PartitionSpec for the mesh path (under a
+    mesh the tp-sharded column-parallel output is constrained to it)."""
     from ..nn import functional as F
     from ..parallel.api import maybe_shard
     h = fc(x)
     if shard_spec is not None:
         h = maybe_shard(h, shard_spec)   # identity without a mesh
     return F.gelu(h, approximate=True)
-
-
-def fused_linear_gelu(x, w, b, approximate=True):
-    """gelu(x @ w + b) with the fused Pallas path on TPU.
-
-    x: [..., K]; w: [K, N]; b: [N].  Falls back to the jnp reference
-    off-TPU, under a mesh, or for non-tileable shapes.
-    """
-    from ._gating import pallas_backend_ok
-    K = x.shape[-1]
-    N = w.shape[-1]
-    lead = x.shape[:-1]
-    M = 1
-    for s in lead:
-        M *= s
-    blocks = _pick_blocks(M, K, N)
-    if not (pallas_backend_ok() and b is not None and blocks):
-        return _reference(x, w, b, approximate)
-    y = _fused(x.reshape(M, K), w, b, approximate, blocks)
-    return y.reshape(lead + (N,))

@@ -23,7 +23,7 @@ and on a dev machine against an archived trace.
     # rows are ready to emit as ``collective_observed`` events
 
 ``telemetry.profile.StepProfiler`` drives exactly this pipeline on a
-sampled schedule; ``tools/profile_run.py`` is the one-shot driver.
+sampled schedule.
 """
 import glob
 import gzip

@@ -48,9 +48,9 @@ directory (and optionally the run's telemetry events) it verifies the
 resilience invariant set — restore() can only ever yield a committed,
 verifiable step; committed steps are monotonic; preemptions exited
 PREEMPTED_EXIT_CODE; restarts stayed within budget.  tools/chaos_run.py
-drives a training script under a plan and gates on it; bench.py's
-``--chaos-smoke`` preflight runs one short plan before spending chip
-time.
+drives a training script under a plan and gates on it;
+``tools/soak_run.py --smoke`` runs one short 2-process plan
+(tests/test_chaos_cluster.py::TestChaosClusterE2E).
 """
 import contextlib
 import errno as _errno
@@ -306,8 +306,7 @@ class ServingFaultInjector:
     two seams — the serving counterpart of ChaosEngine (which patches
     file/step/collective seams a serving drill never crosses).
 
-    The drill driver (``bench.py --frontdoor-smoke``, the frontdoor
-    tests) calls:
+    The drill driver (tests/test_engine_frontdoor.py) calls:
 
     * :meth:`fleet_faults` from its on_token tap: replica-side kinds
       (replica_kill / replica_hang) due at this stream offset — the
@@ -968,9 +967,8 @@ class ChaosCluster:
         # publishes stats frames over the cluster's own KV transport,
         # rank 0 aggregates and serves /cluster/status.json on an
         # ephemeral 127.0.0.1 port written to
-        # <workdir>/cluster_port.json so the supervisor (or a test /
-        # the --cluster-obs-smoke gate) can scrape a LIVE view of the
-        # chaos run.  The plane must survive every fault the plan
+        # <workdir>/cluster_port.json so the supervisor (or a test)
+        # can scrape a LIVE view of the chaos run.  The plane must survive every fault the plan
         # injects: a killed rank degrades the view (stale-marked),
         # never crashes it.
         self.cluster_stats = bool(cluster_stats)

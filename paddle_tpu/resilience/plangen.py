@@ -49,8 +49,8 @@ GENERATABLE_KINDS = (
 # is the supervisor-migration class (generate_plan(supervisor=True));
 # 'collective_skip' is the SPMD-contract-violation class the
 # collective flight recorder attributes (pass kinds= explicitly);
-# the SERVING_FAULT_KINDS are the fleet-drill class (bench.py
-# --frontdoor-smoke / ServingFaultInjector) — their drills have no
+# the SERVING_FAULT_KINDS are the fleet-drill class
+# (ServingFaultInjector) — their drills have no
 # training step, so their clock is stream progress (after_tokens).
 OPTIN_KINDS = ('drift', 'collective_skip') + SERVING_FAULT_KINDS
 

@@ -42,13 +42,13 @@ Ties the whole PR-7..11 runway into live decode throughput:
   server exposes ``/healthz`` ``/status.json`` ``/metrics``
   ``/requests/<rid>`` — scrapes read host-side rolling state only,
   so a live scrape changes no numerics and adds no syncs (pinned by
-  test and ``bench.py --obs-smoke``); every request carries a full
+  tests/test_event_live.py); every request carries a full
   lifecycle trace (``serve_trace`` events).
 
 The decode math runs through the SAME ``GPTForCausalLM.prefill`` /
 ``decode_step`` functional forwards that ``generate()`` uses, so on
 the reference path greedy engine output is bit-exact with sequential
-batch-1 generate — pinned by test and by ``bench.py --serve-smoke``.
+batch-1 generate — pinned by tests/test_engine_serving.py.
 """
 import json
 import math

@@ -34,7 +34,7 @@ module is the training-side sensor substrate (ROADMAP item 3):
   - **degraded-view semantics**: a dead or hung rank's frame goes
     stale and is *marked* stale (age, last step, heartbeat age) —
     the view degrades, it never crashes.  Chaos-validated by
-    ``bench.py --cluster-obs-smoke`` (SIGKILL mid-run).
+    tests/test_cluster_obs.py::TestClusterObsE2E (SIGKILL mid-run).
 
   The view is served through the PR-13 ``MetricsServer`` as
   ``/cluster/status.json`` + ``/metrics`` families

@@ -31,7 +31,8 @@ response is computed from the aggregator's host-side rolling state
 under its lock — a scrape NEVER touches a device array, a compiled
 module, or the engine's scheduler structures, which is what makes
 "scraping /metrics mid-run changes no numerics and adds no syncs"
-provable (bench ``--obs-smoke`` and the bit-exactness test pin it).
+provable (the bit-exactness test in tests/test_event_live.py pins
+it).
 
 Security note: binds ``127.0.0.1`` by default — metrics can leak
 prompts' shape/timing and the trace view leaks rids; exporting the

@@ -30,7 +30,7 @@ Consumers: :class:`telemetry.httpd.MetricsServer` renders
 ``/metrics``; ``telemetry.monitors`` attaches SLO/drift monitors that
 observe the same routed records.  The aggregator itself emits nothing
 and syncs nothing — attaching it to a training loop is free (proven by
-the transfer-guard test and ``bench.py --obs-smoke``).
+the transfer-guard test in tests/test_event_live.py).
 
 Thread-safety: one RLock around all state; the HTTP server's scrape
 threads read snapshots while the engine thread routes events.  A

@@ -31,8 +31,8 @@ serving choke points and warm-start deserializes pay an extra
 by the persistent XLA cache when it is on), so they extract only under
 ``PADDLE_TPU_MEMSTATS``.  The sampler itself never syncs the step
 path: ``memory_stats()`` is a host-side read and the live-arrays
-census touches only avals — ``bench --mem-smoke`` proves the armed
-posture under a device→host transfer guard.
+census touches only avals — tests/test_event_memory.py proves the
+armed posture under a device→host transfer guard.
 
 Consumers: ``tools/run_report.py`` renders the per-module three-way
 table (predicted/compiled ratio, calibratable like

@@ -24,8 +24,8 @@ module is the producer:
 
 The cost contract: OUTSIDE a window, ``observe()`` is one integer
 compare — no host sync, no device traffic (the PR-3 transfer-guard
-proof holds with a profiler attached; ``bench.py --profile-smoke``
-gates it).  The window close pays one ``block_until_ready`` (the
+proof holds with a profiler attached; tests/test_event_profile.py
+holds it).  The window close pays one ``block_until_ready`` (the
 window's steps must land in the trace) plus host-side parse time.
 
 Schedule spec grammar (env var and string form)::

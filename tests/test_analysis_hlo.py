@@ -733,7 +733,7 @@ class TestCliHlo:
                                                 monkeypatch, capsys):
         """A broken lower must not discard the AST/jaxpr report or
         silently disable the rest of the gate: the JSON still lands on
-        stdout (bench's preflight parses stdout regardless of rc),
+        stdout (a caller may parse stdout regardless of rc),
         hlo_error is recorded, and the exit code says infra-failure."""
         spec = importlib.util.spec_from_file_location(
             'tpu_lint_crash_t', LINT_CLI)

@@ -730,13 +730,16 @@ class TestCalibrate:
 
 # -------------------------------------- goldens stay in sync
 class TestPlanGoldens:
-    def test_goldens_file_shape(self):
-        """bench --plan-smoke needs the committed goldens to parse
-        and cover the whole built-in suite.  (The expensive
-        winner-equality check is the bench gate itself.)"""
+    @staticmethod
+    def _goldens():
         with open(os.path.join(REPO, 'tools',
                                'plan_goldens.json')) as f:
-            doc = json.load(f)
+            return json.load(f)
+
+    def test_goldens_file_shape(self):
+        """The committed goldens parse and cover the whole built-in
+        suite."""
+        doc = self._goldens()
         assert doc['chips'] == 8
         assert set(doc['winners']) == set(targets.TARGETS)
         for t, w in doc['winners'].items():
@@ -746,3 +749,18 @@ class TestPlanGoldens:
             for s in sizes:
                 total *= s
             assert total == doc['chips'], t
+
+    @pytest.mark.parametrize('target', list(targets.TARGETS))
+    def test_top_ranked_plan_matches_golden(self, target):
+        """A diff means the cost model or the planner's scoring now
+        ranks shardings differently: update tools/plan_goldens.json
+        deliberately, or fix the regression."""
+        doc = self._goldens()
+        want = doc['winners'][target]
+        got = planner.plan_target(target, chips=doc['chips']) \
+            .to_json()['winner']
+        assert got is not None, target
+        assert ({a: s for a, s in got['mesh'].items() if s > 1}
+                == {a: s for a, s in want['mesh'].items() if s > 1})
+        assert got['assignment'] == want['assignment']
+        assert got.get('fallback') == want['fallback']

@@ -653,6 +653,51 @@ class TestEngineStepLedger:
         assert len(get_ledger(0)) == 0
 
 
+    @staticmethod
+    def _trainer_run(monkeypatch, ledger_on):
+        """Six steps of a real trainer after its compile, under a
+        device->host transfer guard: (losses, compile events, ledger
+        entries)."""
+        import jax
+        import paddle_tpu as paddle
+        from paddle_tpu import nn
+        from paddle_tpu.parallel import ParallelTrainer
+        monkeypatch.setenv(LEDGER_ENV, '1' if ledger_on else '0')
+        reset_ledgers()
+        telemetry.reset()
+        telemetry.enable(None, flush_interval=4)
+        try:
+            rs = np.random.RandomState(0)
+            X = rs.randn(8, 16).astype('float32')
+            Y = rs.randn(8, 4).astype('float32')
+            paddle.seed(0)
+            net = nn.Sequential(nn.Linear(16, 32), nn.ReLU(),
+                                nn.Linear(32, 4))
+            opt = paddle.optimizer.Momentum(
+                learning_rate=0.01, parameters=net.parameters())
+            tr = ParallelTrainer(net, opt,
+                                 lambda o, y: ((o - y) ** 2).mean())
+            tr.step(X, Y)               # compile outside the guard
+            with jax.transfer_guard_device_to_host('disallow'):
+                losses = [tr.step(X, Y) for _ in range(6)]
+            return ([float(np.asarray(v)) for v in losses],
+                    len(telemetry.events('compile')),
+                    len(get_ledger(0)))
+        finally:
+            telemetry.disable()
+
+    def test_ledger_on_trainer_is_sync_free_and_unperturbed(
+            self, monkeypatch):
+        """Recording every step's sync site reads no device value,
+        and changes neither the losses nor the compile count."""
+        monkeypatch.setenv('PADDLE_TRAINER_ID', '0')
+        on = self._trainer_run(monkeypatch, True)
+        off = self._trainer_run(monkeypatch, False)
+        assert on[2] >= 7 and off[2] == 0    # recorded / disarmed
+        assert on[0] == off[0]
+        assert on[1] == off[1]
+
+
 # ============================================= supervisor + vocabulary =====
 
 class TestRoutingAndVocabulary:
@@ -688,36 +733,50 @@ class TestRoutingAndVocabulary:
 
 # ====================================== cluster e2e attribution (slow) =====
 
-# slow: spins real worker interpreters.  The same spin gates every
-# bench run via `bench.py --spmd-smoke`.
+# slow: spins real worker interpreters; nothing automatic runs it.
 @pytest.mark.slow
 @pytest.mark.faultinject
 class TestClusterE2EAttribution:
+    @staticmethod
+    def _spin(tmp_path, faults):
+        """A 2-process soak under `faults`: (report, merged events)."""
+        from paddle_tpu.resilience.chaos import (
+            ChaosCluster, FaultPlan, load_run_events)
+        rep = ChaosCluster(
+            procs=2, plan=FaultPlan(seed=11, name='spmd-e2e',
+                                    faults=faults),
+            steps=10, workdir=str(tmp_path / 'cluster'), save_every=2,
+            collective_timeout_s=8.0, watchdog='step=60,grace=2',
+            deadline_s=150.0).run()
+        assert rep['ok'], rep['violations']
+        return rep, load_run_events(str(tmp_path / 'cluster'))
+
     def test_seeded_skip_is_attributed_to_call_site(self, tmp_path):
         """The runtime half of the both-ways acceptance: a seeded
         collective_skip on rank 1 must surface as a
         collective_mismatch naming the exact soak-loop allreduce call
         site, before the generic timeout escalation."""
-        from paddle_tpu.resilience.chaos import (
-            ChaosCluster, FaultPlan, load_run_events)
-        plan = FaultPlan(seed=11, name='spmd-e2e', faults=[
+        rep, evs = self._spin(tmp_path, [
             {'kind': 'collective_skip', 'at_step': 5, 'rank': 1,
              'count': 1}])
-        cluster = ChaosCluster(
-            procs=2, plan=plan, steps=10,
-            workdir=str(tmp_path / 'cluster'), save_every=2,
-            collective_timeout_s=8.0, watchdog='step=60,grace=2',
-            deadline_s=150.0)
-        rep = cluster.run()
-        assert rep['ok'], rep['violations']
         assert [e['fault'] for e in rep['injected']] == \
             ['collective_skip']
-        evs = load_run_events(str(tmp_path / 'cluster'))
         mm = [e for e in evs if e.get('kind') == 'collective_mismatch']
         assert mm, 'seeded skip produced no collective_mismatch'
         sites = {s for e in mm for s in (e.get('sites') or {}).values()
                  if s}
-        assert any(s.startswith('soak_run.py:') for s in sites), sites
+        with open(os.path.join(REPO, 'tools', 'soak_run.py')) as f:
+            seeded = next(f'soak_run.py:{no}'
+                          for no, line in enumerate(f, 1)
+                          if "transport.allreduce(w, 'mean'" in line)
+        assert seeded in sites, (seeded, sites)
         tmo = [e for e in evs if e.get('kind') == 'timeout']
         assert tmo and min(e['ts'] for e in mm) <= \
             min(e['ts'] for e in tmo)
+
+    def test_clean_twin_emits_no_mismatch(self, tmp_path):
+        """The same cluster shape with an empty plan: not one ghost
+        collective_mismatch."""
+        _rep, evs = self._spin(tmp_path, [])
+        assert not [e for e in evs
+                    if e.get('kind') == 'collective_mismatch']

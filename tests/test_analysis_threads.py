@@ -641,6 +641,66 @@ class TestChaosComposition:
         assert threading.Lock is lockcheck._REAL_LOCK
 
 
+class TestArmedWorkloads:
+    def test_trainer_and_engine_clean_and_unperturbed(self):
+        """The runtime half of the concurrency posture: a dp=8
+        trainer takes real steps and a serving engine completes a
+        Poisson load while every paddle_tpu lock is instrumented.  No
+        lock-order cycle, no unguarded access, a clean engine audit,
+        and the unarmed re-run of the same trainer yields bit-equal
+        losses (observation does not perturb training)."""
+        import jax
+        import paddle_tpu as paddle
+        from paddle_tpu import nn, distributed as dist
+        from paddle_tpu.distributed import env as dist_env
+        from paddle_tpu.models.gpt import gpt_tiny
+        from paddle_tpu.parallel import ParallelTrainer
+        from paddle_tpu.serving import (ServeConfig, ServingEngine,
+                                        poisson_requests)
+        if jax.device_count() < 8:
+            pytest.skip('needs 8 devices')
+        rs = np.random.RandomState(1)
+        X = rs.randn(16, 64).astype('float32')
+        Y = rs.randn(16, 64).astype('float32')
+
+        def trainer_losses(steps=6):
+            dist_env.set_mesh(None)
+            dist.init_parallel_env(axes={'dp': 8})
+            paddle.seed(0)
+            net = nn.Sequential(nn.Linear(64, 256), nn.ReLU(),
+                                nn.Linear(256, 64))
+            opt = paddle.optimizer.Momentum(
+                learning_rate=0.01, parameters=net.parameters())
+            tr = ParallelTrainer(net, opt,
+                                 lambda o, y: ((o - y) ** 2).mean())
+            return [float(np.asarray(tr.step(X, Y)))
+                    for _ in range(steps)]
+
+        try:
+            with lockcheck.install() as chk:
+                armed = trainer_losses()
+                dist_env.set_mesh(None)
+                paddle.seed(7)
+                model = gpt_tiny(num_layers=2, hidden_size=32,
+                                 num_heads=2, max_seq_len=64)
+                eng = ServingEngine(model, ServeConfig(
+                    block_size=4, max_slots=4, decode_span=2,
+                    prompt_buckets=(4, 8), batch_buckets=(1, 2, 4),
+                    prefill_batch=2, max_model_len=32))
+                rep = eng.run(poisson_requests(
+                    8, rate_rps=500.0, prompt_lens=(3, 5, 8),
+                    new_tokens=(4, 6),
+                    vocab_size=model.config.vocab_size, seed=3))
+                assert rep['decoded_tokens'] and rep['audit'] == []
+                assert chk.locks_created > 0
+                lrep = chk.report()
+                assert not [f for f in lrep if f.rule in (
+                    'lock-order-cycle', 'unguarded-access')], str(lrep)
+            assert trainer_losses() == armed
+        finally:
+            dist_env.set_mesh(None)
+
+
 # ============================================= loader thread-leak guard ====
 
 def _paddle_threads():

@@ -669,26 +669,6 @@ class TestCheckInvariants:
         assert any(v.startswith('I5') for v in out)
 
 
-# ------------------------------------------------- chaos_run driver --------
-# The two single-process subprocess driver cases that lived here
-# (sigkill smoke-plan + sigterm preemption) FOLDED into the 2-process
-# ChaosCluster smoke: tests/test_chaos_cluster.py::TestChaosClusterE2E
-# covers both exit paths across real process boundaries, and the same
-# spin gates every bench run via `bench.py --chaos-smoke`
-# (tools/soak_run.py --smoke).  chaos_run.py itself stays supported
-# for single-process script supervision.
-
-
-def _env(extra=None):
-    env = dict(os.environ)
-    env['JAX_PLATFORMS'] = 'cpu'
-    env['XLA_FLAGS'] = '--xla_force_host_platform_device_count=1'
-    env['PYTHONPATH'] = _REPO + os.pathsep + env.get('PYTHONPATH', '')
-    if extra:
-        env.update(extra)
-    return env
-
-
 # ------------------------------------------------- check_ckpt --deep -------
 @pytest.mark.faultinject
 class TestCheckCkptDeep:

@@ -20,7 +20,7 @@ Covers (fast, tier-1):
     fixtures soak_run --smoke gates on;
   * invariants I6/I7 + run_report's watchdog timeline/summary.
 
-Slow (bench --chaos-smoke territory): one 2-process ChaosCluster spin
+Slow (nothing automatic runs it): one 2-process ChaosCluster spin
 of the built-in smoke plan — the old single-process chaos_run driver
 cases folded into it — and a jax.distributed-initialized clean soak.
 """
@@ -820,8 +820,8 @@ class TestRunReportWatchdogTimeline:
 
 # ================================================ cluster e2e (slow) ========
 
-# slow: spins real worker interpreters.  The same spin gates every
-# bench run via `bench.py --chaos-smoke` -> tools/soak_run.py --smoke.
+# slow: spins real worker interpreters; nothing automatic runs it.
+# `tools/soak_run.py --smoke` makes the same spin by hand.
 @pytest.mark.slow
 @pytest.mark.faultinject
 class TestChaosClusterE2E:

@@ -1,7 +1,7 @@
 """CPU checks for the bring-up PR: chip_smoke.py refuses to run off the
 chip, the compile cache is placed by one rule, fleet workers inherit
-their platform, a dead engine thread closes the door, bench.py fails
-loudly, and the row-kernel gate is bounded by VMEM."""
+their platform, a dead engine thread closes the door, the smoke's
+serve set-up is coherent, and the row-kernel gate is bounded by VMEM."""
 import importlib.util
 import json
 import os
@@ -25,6 +25,9 @@ def _load(relpath):
 
 
 # ------------------------------------------------------------ chip_smoke.py --
+SMOKE = _load('chip_smoke.py')      # imports nothing but the stdlib
+
+
 def _run_smoke(**env):
     return subprocess.run(
         [sys.executable, os.path.join(REPO, 'chip_smoke.py')],
@@ -52,7 +55,6 @@ def test_smoke_refuses_interpret_mode():
 
 
 def test_smoke_finds_named_kernels_in_compiled_hlo():
-    smoke = _load('chip_smoke.py')
     hlo = (
         '%flash_fwd.1 = (bf16[96,1024,64]) custom-call(%a, %b), '
         'custom_call_target="tpu_custom_call", metadata={op_name='
@@ -61,10 +63,10 @@ def test_smoke_finds_named_kernels_in_compiled_hlo():
         'call", metadata={op_name="jit(step)/transpose(jvp(flash_bwd_'
         'dq))/pallas_call"}\n'
         '%y = f32[8] fusion(%c), metadata={op_name="layer_norm_fwd"}\n')
-    assert smoke.has_kernel(hlo, 'flash_fwd')
-    assert smoke.has_kernel(hlo, 'flash_bwd_dq')
-    assert not smoke.has_kernel(hlo, 'flash_bwd_dkv')
-    assert not smoke.has_kernel(hlo, 'layer_norm_fwd')   # not a call
+    assert SMOKE.has_kernel(hlo, 'flash_fwd')
+    assert SMOKE.has_kernel(hlo, 'flash_bwd_dq')
+    assert not SMOKE.has_kernel(hlo, 'flash_bwd_dkv')
+    assert not SMOKE.has_kernel(hlo, 'layer_norm_fwd')   # not a call
 
 
 # ----------------------------------------------------------- cache placement --
@@ -203,28 +205,32 @@ def test_engine_thread_failure_closes_the_door(monkeypatch):
         fe.stop()
 
 
-# ------------------------------------------------------------------- bench --
-def test_bench_config_that_raises_exits_nonzero(monkeypatch, capsys):
-    bench = _load('bench.py')
-
-    def boom(smoke):
-        raise RuntimeError('config blew up')
-
-    monkeypatch.setitem(bench.CONFIGS, 'lenet', boom)
-    monkeypatch.setattr(sys, 'argv', ['bench.py', '--smoke', '--config',
-                                      'lenet', '--single-json'])
-    with pytest.raises(RuntimeError, match='config blew up'):
-        bench.main()
-    assert capsys.readouterr().out.strip() == ''     # no null-value JSON
+# ------------------------------------------------------------ serve set-up --
+# What leg_server checks only once it is on the chip, held here first.
+@pytest.fixture(scope='module')
+def serve_engine():
+    from paddle_tpu.serving import ServingEngine
+    model, cfg = SMOKE.serve_setup()
+    return model, cfg, ServingEngine(model, cfg)
 
 
-def test_bench_full_shapes_refuse_off_chip(monkeypatch):
-    bench = _load('bench.py')
-    monkeypatch.setattr(sys, 'argv', ['bench.py', '--config', 'lenet',
-                                      '--single-json'])
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert 'refusing' in str(exc.value.code)
+def test_serve_setup_builds_an_engine(serve_engine):
+    model, cfg, eng = serve_engine
+    assert not model.training
+    assert eng.config is cfg and cfg.temperature == 0.0
+    assert cfg.max_model_len <= model.config.max_seq_len
+    assert cfg.batch_buckets == (8, 64) and cfg.max_slots == 64
+    assert not eng.scheduler.audit()
+    assert ({eng.prompt_bucket(n) for n in SMOKE.SERVE_PROMPT_LENS}
+            == set(cfg.prompt_buckets))
+
+
+@pytest.mark.parametrize('prompt_len', SMOKE.SERVE_PROMPT_LENS)
+def test_serve_prompt_fits_bucket_and_model_length(serve_engine,
+                                                   prompt_len):
+    _model, cfg, eng = serve_engine
+    assert eng.prompt_bucket(prompt_len) in cfg.prompt_buckets
+    assert prompt_len + SMOKE.SERVE_NEW_TOKENS <= cfg.max_model_len
 
 
 # -------------------------------------------------------------- kernel gate --

@@ -565,6 +565,170 @@ class TestFleetRouter:
             fleet.stop()
 
 
+# slow: spins three worker interpreters (tools/serve_fleet.py);
+# nothing automatic runs it.
+@pytest.mark.slow
+@pytest.mark.faultinject
+class TestRealFleetE2E:
+    """The fleet tests above script one side of each failure; here two
+    real replicas and a warm spare run behind the router."""
+
+    DOC = {'model': 'tiny',
+           'model_kwargs': {'num_layers': 2, 'num_heads': 2,
+                            'hidden_size': 32, 'vocab_size': 128,
+                            'max_seq_len': 128},
+           'block_size': 8, 'max_slots': 4, 'decode_span': 4,
+           'num_blocks': 64, 'temperature': 0.7, 'top_k': 8,
+           'seed': 13}
+
+    def test_overload_clean_twin_kill_and_drain(self, tmp_path):
+        import random
+        import signal
+        import sys
+        sys.path.insert(0, os.path.join(_REPO, 'tools'))
+        try:
+            import serve_fleet
+        finally:
+            sys.path.pop(0)
+        config_path = str(tmp_path / 'serve.json')
+        with open(config_path, 'w') as f:
+            json.dump(self.DOC, f)
+
+        def shape(rid):
+            r = random.Random(rid)
+            return ([r.randrange(1, 120)
+                     for _ in range(r.randrange(4, 9))],
+                    r.randrange(6, 10))
+
+        def run_many(router, rids, pace_s=0.002, on_token=None):
+            results, threads = {}, []
+
+            def one(rid):
+                prompt, n = shape(rid)
+                try:
+                    results[rid] = router.generate(
+                        prompt, n, rid,
+                        on_token=(None if on_token is None else
+                                  (lambda i, t: on_token(rid, i, t))))
+                except Exception as e:      # a crash IS the finding
+                    results[rid] = {'state': 'crashed',
+                                    'reason': repr(e)[:120]}
+            for rid in rids:
+                t = threading.Thread(target=one, args=(rid,),
+                                     daemon=True)
+                t.start()
+                threads.append(t)
+                time.sleep(pace_s)
+            for t in threads:
+                t.join(timeout=120)
+            assert not [t for t in threads if t.is_alive()]
+            return results
+
+        def shed_total(router):
+            n = 0
+            for rep in router.replicas + router.spares:
+                if rep.alive():
+                    try:
+                        n += sum((rep.status(timeout_s=2.0)
+                                  .get('shed_counts') or {}).values())
+                    except OSError:
+                        pass
+            return n
+
+        def reference(rids):
+            """Each rid alone through a fresh single engine."""
+            eng = serve_fleet.build_engine(self.DOC)
+            out = {}
+            for rid in rids:
+                prompt, n = shape(rid)
+                r = Request(rid, prompt, max_new_tokens=n)
+                eng.submit(r)
+                eng.run()
+                out[rid] = [int(t) for t in r.tokens]
+            return out
+
+        router = serve_fleet.launch_fleet(
+            config_path, replicas=2, spares=1,
+            workdir=str(tmp_path / 'fleet'))
+        try:
+            # overload: a burst far over pool and queue comes back
+            # with TYPED rejections only; nothing lost, nobody dies
+            res = run_many(router, [f'ov-{i}' for i in range(24)])
+            states = {r['state'] for r in res.values()}
+            assert not states & {'crashed', 'failed', 'in_flight'}, res
+            assert all(r['reason'] in RejectReason.ALL
+                       for r in res.values()
+                       if r['state'] == 'rejected'), res
+            assert shed_total(router) > 0
+            assert sum(r.alive() for r in router.replicas) == 2
+            assert router.check_invariants() == []
+
+            # the same shapes, gently paced: nothing shed, every
+            # stream bit-exact against a single engine
+            shed0 = shed_total(router)
+            rids = [f'cl-{i}' for i in range(4)]
+            res = run_many(router, rids, pace_s=0.4)
+            want = reference(rids)
+            assert [res[r]['state'] for r in rids] == ['finished'] * 4
+            assert shed_total(router) == shed0
+            assert {r: res[r]['tokens'] for r in rids} == want
+            assert router.check_invariants() == []
+
+            # a seeded SIGKILL of the serving replica mid-stream:
+            # every in-flight rid finishes, retried on the survivor,
+            # streams still bit-exact, the warm spare promoted
+            inj = ServingFaultInjector(FaultPlan(seed=0, faults=[
+                Fault('replica_kill', after_tokens=3, count=1)]))
+            kill_lock = threading.Lock()
+
+            def tap(rid, i, tok):
+                with kill_lock:
+                    fired = inj.fleet_faults(rid, i + 1)
+                for _f in fired:
+                    victim = router.replica(
+                        router.ledger[rid]['replicas'][-1])
+                    if victim is not None:
+                        victim.kill(signal.SIGKILL)
+
+            rids = [f'ki-{i}' for i in range(3)]
+            res = run_many(router, rids, pace_s=0.05, on_token=tap)
+            want = reference(rids)
+            assert inj.injected
+            assert [res[r]['state'] for r in rids] == ['finished'] * 3
+            assert sum(res[r].get('retried', 0) for r in rids) >= 1
+            assert {r: res[r]['tokens'] for r in rids} == want
+            assert [e for e in router.events
+                    if e['action'] == 'promote']
+            assert router.check_invariants() == []
+
+            # a forced slo_breach latch on the replica that holds a
+            # stream: it drains, the stream loses no token, the fleet
+            # keeps serving
+            out = {}
+            t = threading.Thread(
+                target=lambda: out.update(entry=router.generate(
+                    *shape('dr-0'), 'dr-0')), daemon=True)
+            t.start()
+            deadline = time.time() + 60
+            while time.time() < deadline and not (
+                    router.ledger.get('dr-0') or {}).get('replicas'):
+                time.sleep(0.01)
+            owner = router.replica(
+                router.ledger['dr-0']['replicas'][-1])
+            owner.post_json('/admin/alert/slo_breach')
+            router.health_tick()
+            t.join(timeout=120)
+            assert not t.is_alive()
+            assert owner.draining
+            assert out['entry']['state'] == 'finished'
+            assert out['entry']['tokens'] == reference(['dr-0'])['dr-0']
+            assert router.dispatchable()
+            assert [e for e in router.events if e['action'] == 'drain']
+            assert router.check_invariants() == []
+        finally:
+            router.stop()
+
+
 # =============================================================================
 # serving chaos kinds
 # =============================================================================

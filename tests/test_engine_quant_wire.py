@@ -668,6 +668,47 @@ class TestWireDtypeDimension:
             telemetry.disable()
 
 
+    def test_lenet_gate_and_observed_join(self, tmp_path):
+        """tools/quant_accuracy.compare on lenet, dp=8, a profile
+        window in the quantized run: it converges within a tenth of
+        full width's loss progress at under half the predicted wire
+        bytes, compiling once, and run_report joins the window's
+        s8-tagged collective_observed rows to collectives_cmp."""
+        import importlib.util
+        import os
+        from paddle_tpu import telemetry
+
+        def tool(name):
+            spec = importlib.util.spec_from_file_location(
+                name, os.path.join(os.path.dirname(__file__), '..',
+                                   'tools', f'{name}.py'))
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            return mod
+
+        d = str(tmp_path / 'tel')
+        telemetry.enable(d)
+        try:
+            row = tool('quant_accuracy').compare(
+                'lenet', {'block': 256, 'min_bytes': 0}, steps=25,
+                profile={'every': 100, 'steps': 2, 'start': 2,
+                         'dir': d})
+            observed = telemetry.events('collective_observed')
+        finally:
+            telemetry.disable()
+        assert row['loss_delta_rel'] <= 0.10, row
+        assert row['wire_reduction'] >= 2.0, row
+        assert [op for op, r in row['census_quant'].items()
+                if r.get('wire_dtype') == 's8']
+        assert row['compile_events_quant'] == 1
+        assert [e for e in observed if e.get('wire_dtype') == 's8']
+        rr = tool('run_report')
+        events, sources, skew = rr.load_events(*rr.discover([d]))
+        tagged = [r for r in rr.analyze(events, sources, skew)[
+            'collectives_cmp'].values() if r.get('wire_dtype') == 's8']
+        assert tagged and any(r.get('observed_us') for r in tagged)
+
+
 # =============================================================================
 # property sweeps over the pure cores (cheap, wide coverage)
 # =============================================================================

@@ -531,18 +531,32 @@ class TestServingEngine:
         assert done and done[-1]['tokens'] == 4
         assert done[-1]['ttft_s'] is not None
 
-    def test_warmup_builds_every_declared_module_up_front(self):
+    @pytest.mark.parametrize('exec_tier', [False, True])
+    def test_warmup_builds_every_declared_module_up_front(
+            self, exec_tier, tmp_path, monkeypatch):
         """warmup() = the deterministic deploy cold-start: afterwards
-        NO traffic pattern can trigger a compile."""
+        NO traffic pattern can trigger a compile — by the engine's own
+        count and, with the exec tier armed on an empty directory, by
+        the compile cache's: no serialize and no miss after it."""
+        from paddle_tpu.core import compile_cache as CC
+        if exec_tier:
+            monkeypatch.setenv('PADDLE_TPU_COMPILE_CACHE',
+                               str(tmp_path / 'cache'))
         m = _tiny_model()
         eng = ServingEngine(m, _tiny_config())
         eng.warmup()
         # prompts (4,8) x chunks (1,2) + decode batches (1,2,4)
         assert eng.compile_count == 7
+        before = CC.stats()
+        if exec_tier:
+            assert before.get('serialize_exec', 0) >= 7
         for i in range(5):
             eng.submit(np.arange(1, 3 + i), 3)
         eng.run()
         assert eng.compile_count == 7
+        after = CC.stats()
+        for k in ('serialize_exec', 'miss_exec'):
+            assert after.get(k, 0) == before.get(k, 0), k
 
     def test_moe_model_rejected(self):
         from paddle_tpu.models.gpt import gpt_moe_tiny

@@ -610,32 +610,36 @@ class TestCoordinatedReshape:
 
 # ----------------------------------- in-process live migration (headline) ---
 class TestLiveMigration:
+    @staticmethod
+    def _armed_dp8_trainer():
+        from paddle_tpu import distributed as dist
+        from paddle_tpu.parallel import ParallelTrainer
+        dist.init_parallel_env(axes={'dp': 8})
+        paddle.seed(0)
+        net = nn.Sequential(nn.Linear(64, 256), nn.ReLU(),
+                            nn.Linear(256, 64))
+        opt = paddle.optimizer.Momentum(
+            learning_rate=0.01, parameters=net.parameters())
+        tr = ParallelTrainer(
+            net, opt, lambda out, y: ((out - y) ** 2).mean(),
+            supervisor={'debounce_s': 0.05, 'cooldown_s': 120.0,
+                        'margin': 0.0})
+        rs = np.random.RandomState(1)
+        return (tr, rs.randn(16, 64).astype('float32'),
+                rs.randn(16, 64).astype('float32'))
+
     def test_dp8_migrates_under_injected_drift(self):
         """The tentpole end-to-end, in one process: a dp=8 trainer
         under 50x all-reduce drift re-plans onto a tp>1 layout, swaps
         at a step boundary with exactly one plan_swap, keeps the loss
         finite, and holds through the cooldown."""
-        from paddle_tpu import distributed as dist
         from paddle_tpu.distributed import env as dist_env
-        from paddle_tpu.parallel import ParallelTrainer
         if jax.device_count() < 8:
             pytest.skip('needs 8 devices')
         recs, hook = _capture()
         tr = None
         try:
-            dist.init_parallel_env(axes={'dp': 8})
-            paddle.seed(0)
-            net = nn.Sequential(nn.Linear(64, 256), nn.ReLU(),
-                                nn.Linear(256, 64))
-            opt = paddle.optimizer.Momentum(
-                learning_rate=0.01, parameters=net.parameters())
-            tr = ParallelTrainer(
-                net, opt, lambda out, y: ((out - y) ** 2).mean(),
-                supervisor={'debounce_s': 0.05, 'cooldown_s': 120.0,
-                            'margin': 0.0})
-            rs = np.random.RandomState(1)
-            X = rs.randn(16, 64).astype('float32')
-            Y = rs.randn(16, 64).astype('float32')
+            tr, X, Y = self._armed_dp8_trainer()
             for _ in range(3):
                 tr.step(X, Y)
             assert tr._supervisor is not None
@@ -677,6 +681,33 @@ class TestLiveMigration:
             if tr is not None:
                 tr.stop_supervisor()
             from paddle_tpu.distributed import env as dist_env
+            dist_env.set_mesh(None)
+
+    def test_armed_clean_run_never_actuates(self):
+        """The twin of the drift run: the same armed trainer with NO
+        trigger takes its steps (compile, step and flush events
+        included) without an incident, a plan_swap or a remediation,
+        and keeps its mesh."""
+        from paddle_tpu.distributed import env as dist_env
+        if jax.device_count() < 8:
+            pytest.skip('needs 8 devices')
+        recs, hook = _capture()
+        tr = None
+        try:
+            tr, X, Y = self._armed_dp8_trainer()
+            for _ in range(8):
+                tr.step(X, Y)
+            assert tr._supervisor is not None
+            time.sleep(0.2)                 # past the debounce
+            assert tr._supervisor.incidents == []
+            assert tr._supervisor.swaps == 0
+            assert not [r for r in recs
+                        if r['kind'] in ('plan_swap', 'remediation')]
+            assert dict(tr.mesh.shape) == {'dp': 8}
+        finally:
+            get_recorder().unsubscribe(hook)
+            if tr is not None:
+                tr.stop_supervisor()
             dist_env.set_mesh(None)
 
     def test_default_posture_is_off(self):

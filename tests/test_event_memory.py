@@ -435,6 +435,85 @@ class TestSupervisorActuation:
         assert host.replans == [None]      # 3-arg host, no hint
 
 
+# ------------------------------------- the observatory armed, e2e ---
+class TestArmedEndToEnd:
+    """PADDLE_TPU_MEMSTATS armed on a dp=8 mesh: every compile choke
+    point reports, the sampler reads no device value, and one breach
+    tightens exactly one re-plan."""
+
+    def test_every_module_reports_and_sampler_adds_no_syncs(
+            self, monkeypatch):
+        import numpy as np
+        from jax.sharding import Mesh
+        import paddle_tpu as paddle
+        from paddle_tpu import nn
+        from paddle_tpu.parallel import ParallelTrainer
+        monkeypatch.setenv(mem.MEMSTATS_ENV, 'interval=3600')
+        paddle.seed(0)
+        net = nn.Sequential(nn.Linear(16, 32), nn.ReLU(),
+                            nn.Linear(32, 4))
+        opt = paddle.optimizer.SGD(learning_rate=0.1,
+                                   parameters=net.parameters())
+        mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ('dp',))
+        tr = ParallelTrainer(net, opt, loss_fn=nn.MSELoss(), mesh=mesh)
+        rs = np.random.RandomState(0)
+        x = rs.randn(16, 16).astype('float32')
+        y = rs.randn(16, 4).astype('float32')
+        tr.step(x, y)
+        tr.compiled_text()
+        paddle.seed(1)
+        m2 = paddle.hapi.Model(nn.Linear(8, 2))
+        m2.prepare(optimizer=paddle.optimizer.SGD(
+            learning_rate=0.1, parameters=m2.network.parameters()),
+            loss=nn.MSELoss())
+        m2.train_batch(rs.randn(4, 8).astype('float32'),
+                       rs.randn(4, 2).astype('float32'))
+        noted = {e['name'] for e in telemetry.events('memory_compiled')}
+        assert {'ParallelTrainer.step', 'Model.train_batch'} <= noted
+        sampler = mem.ensure_sampler()
+        assert sampler is not None
+        with jax.transfer_guard_device_to_host('disallow'):
+            for _ in range(8):
+                tr.step(x, y)
+                sample = sampler.sample_once()
+        assert sample['source'] == 'live_arrays'
+
+    def test_near_budget_allocation_tightens_one_replan(self):
+        """Sampler -> MemoryMonitor -> PlanSupervisor, chained: the
+        census sits just under the watermark, one seeded allocation
+        crosses it, the latch holds on the next sample."""
+        import time
+        from paddle_tpu.telemetry import LiveAggregator
+        agg = LiveAggregator().install()
+        host = _MemHost()
+        sup = PlanSupervisor(host, SupervisorConfig(
+            debounce_s=0.01, cooldown_s=0.0, margin=0.1)).start()
+        try:
+            census = mem.live_arrays_bytes() or 0
+            budget = int((census + (4 << 20)) / 0.9)
+            budget_gb = budget / float(1 << 30)
+            agg.attach_monitor(MemoryMonitor(budget_bytes=budget))
+            probe = MemorySampler(MemConfig(budget_gb=budget_gb))
+            probe.sample_once()         # below the watermark
+            assert telemetry.events('memory_pressure') == []
+            ballast = jnp.ones((2 << 20,), jnp.float32)
+            ballast.block_until_ready()     # 8 MiB: twice the headroom
+            probe.sample_once()         # crosses: THE edge
+            probe.sample_once()         # latched
+            deadline = time.time() + 10
+            while time.time() < deadline and not sup.incidents:
+                time.sleep(0.05)
+            del ballast
+            assert len(telemetry.events('memory_pressure')) == 1
+            assert [i['outcome'] for i in sup.incidents] == ['swap']
+            assert len(host.replans) == 1
+            assert host.replans[0] is not None
+            assert host.replans[0] < budget_gb
+        finally:
+            sup.stop()
+            agg.uninstall()
+
+
 # ------------------------------------------- run_report section -----
 def _run_report_mod():
     sys.path.insert(0, os.path.join(_REPO, 'tools'))

@@ -382,6 +382,30 @@ class TestCaptureEndToEnd:
             fit['alpha_us'] * c['phases']
             + fit['beta_us_per_byte'] * c['wire_bytes'], rel=1e-3)
 
+    def test_attached_profiler_outside_window_is_sync_free(
+            self, tmp_path):
+        """A profiler attached with no window in range costs a step
+        nothing the host can see: no device->host transfer."""
+        from paddle_tpu.distributed import env as dist_env
+        prev = dist_env.get_mesh()
+        mesh = dist_env.build_mesh({'dp': 8})
+        dist_env.set_mesh(mesh)
+        try:
+            tr = self._trainer(mesh, profile={
+                'every': 1000, 'steps': 1, 'start': 900,
+                'dir': str(tmp_path)})
+            rs = np.random.RandomState(0)
+            x = rs.randn(16, 8).astype('float32')
+            y = rs.randn(16, 4).astype('float32')
+            tr.step(x, y)       # compile + census outside the guard
+            assert tr._profiler is not None
+            with jax.transfer_guard_device_to_host('disallow'):
+                for _ in range(4):
+                    tr.step(x, y)
+        finally:
+            dist_env.set_mesh(prev)
+        assert telemetry.events('profile_capture') == []
+
     def test_profile_off_is_inert(self):
         from paddle_tpu.distributed import env as dist_env
         os.environ.pop(tprofile.ENV_VAR, None)

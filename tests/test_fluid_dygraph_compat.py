@@ -8,6 +8,7 @@ fluid/io.py / initializer.py / clip.py __all__; checked against the
 per-op unittests (test_imperative_basic, test_gru_unit_op,
 test_nce).
 """
+import os
 import re
 
 import numpy as np
@@ -23,6 +24,9 @@ def _t(a, dt='float32'):
 
 
 class TestSurface:
+    @pytest.mark.skipif(not os.path.isdir('/root/reference'),
+                        reason='the reference checkout /root/reference '
+                               'is not mounted')
     def test_all_four_namespaces_complete(self):
         for label, path, mod in (
             ('dygraph', 'dygraph/nn.py', fluid.dygraph),

@@ -8,6 +8,7 @@ test_ctc_align, test_psroi_pool_op, ...).  Full-surface resolution is
 asserted against the reference __all__ lists.
 """
 import math
+import os
 import re
 
 import numpy as np
@@ -29,6 +30,9 @@ def _t(a, dt='float32'):
 
 
 class TestSurfaceComplete:
+    @pytest.mark.skipif(not os.path.isdir('/root/reference'),
+                        reason='the reference checkout /root/reference '
+                               'is not mounted')
     def test_reference_all_lists_resolve(self):
         total = missing = 0
         for mod in ('nn', 'tensor', 'control_flow', 'sequence_lod'):

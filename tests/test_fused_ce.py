@@ -319,7 +319,7 @@ class TestTpFusedCE:
 class TestDenseCEBackward:
     """F.cross_entropy's hard-label path carries a custom_vjp whose
     backward is dense (softmax - one_hot) math instead of the autodiff
-    scatter-add (serialized on TPU; tools/bench_ce_backward.py)."""
+    scatter-add (serialized on TPU)."""
 
     def test_grad_matches_autodiff_gather(self):
         import paddle_tpu.nn.functional as F

@@ -198,6 +198,33 @@ class TestTrainerFused:
         assert np.array_equal(key_after_1, key_after_2)
         assert t1._step_no == t2._step_no == k
 
+    def test_conv_model_k1_within_reassociation_tolerance(self):
+        """A length-1 scan over a conv model is the per-step loop up
+        to XLA reassociating the conv gradient inside the scan body
+        (about 1 ULP a step): allclose where the dense model above is
+        bit-exact."""
+        from paddle_tpu.vision.models import LeNet
+        rs = np.random.RandomState(0)
+        x = rs.randn(4, 1, 28, 28).astype('float32')
+        y = rs.randint(0, 10, size=(4, 1)).astype('int64')
+
+        def make(fused):
+            paddle.seed(0)
+            net = LeNet()
+            opt = paddle.optimizer.Adam(learning_rate=1e-3,
+                                        parameters=net.parameters())
+            ce = nn.CrossEntropyLoss()
+            return ParallelTrainer(net, opt, lambda o, t: ce(o, t),
+                                   fused_steps=fused)
+
+        t1, t2 = make(0), make(1)
+        per_step = [np.asarray(t1.step(x, y)) for _ in range(3)]
+        fused = [np.asarray(t2.step_fused(x[None], y[None]))[0]
+                 for _ in range(3)]
+        np.testing.assert_allclose(np.asarray(fused),
+                                   np.asarray(per_step),
+                                   rtol=1e-5, atol=1e-6)
+
     def test_nan_injected_chunk_rolls_back(self):
         k = 4
         xs, ys = batch_data(k)

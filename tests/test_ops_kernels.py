@@ -1,6 +1,6 @@
 """Pallas kernels (SURVEY.md §2 item 36): flash attention, fused
 LayerNorm, fused softmax — kernel logic validated in TPU-interpret mode
-on the CPU suite; on-device parity is covered by the bench/verify runs."""
+on the CPU suite; on-device parity is chip_smoke.py's kernels leg."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -13,8 +13,6 @@ from paddle_tpu.ops.fused_norm import (
     _ln, _reference as ln_ref, fused_layer_norm)
 from paddle_tpu.ops.fused_softmax import (
     _sm, _reference as sm_ref, fused_softmax)
-from paddle_tpu.ops.fused_gelu_linear import (
-    _fused, _reference as fg_ref, fused_linear_gelu)
 
 
 @pytest.fixture()
@@ -144,111 +142,6 @@ class TestGPTModel:
         for _ in range(10):
             last = tr.step(ids, ids)
         assert float(np.asarray(last)) < first
-
-
-class TestFusedLinearGelu:
-    @pytest.mark.parametrize('approximate', [True, False])
-    def test_forward_matches_reference(self, interp, approximate):
-        x = _rand(256, 512)
-        w = _rand(512, 256, seed=1) * 0.05
-        b = _rand(256, seed=2)
-        y = _fused(x, w, b, approximate, (256, 256, 512))
-        ref = fg_ref(x, w, b, approximate)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_multiblock_grid(self, interp):
-        x = _rand(512, 1024)
-        w = _rand(1024, 512, seed=1) * 0.05
-        b = _rand(512, seed=2)
-        y = _fused(x, w, b, True, (256, 256, 512))
-        ref = fg_ref(x, w, b, True)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_grads_match_reference(self, interp):
-        x = _rand(256, 512)
-        w = _rand(512, 256, seed=1) * 0.05
-        b = _rand(256, seed=2)
-
-        def lp(x, w, b):
-            return jnp.sum(_fused(x, w, b, True, (256, 256, 512)) ** 2)
-
-        def lr(x, w, b):
-            return jnp.sum(fg_ref(x, w, b, True) ** 2)
-
-        gp = jax.grad(lp, argnums=(0, 1, 2))(x, w, b)
-        gr = jax.grad(lr, argnums=(0, 1, 2))(x, w, b)
-        for a, r in zip(gp, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(r),
-                                       rtol=2e-3, atol=2e-3)
-
-    def test_public_api_fallback_on_cpu(self):
-        x = _rand(8, 64)
-        w = _rand(64, 32, seed=1)
-        b = _rand(32, seed=2)
-        y = fused_linear_gelu(x, w, b)
-        np.testing.assert_allclose(np.asarray(y),
-                                   np.asarray(fg_ref(x, w, b, True)),
-                                   rtol=1e-6)
-
-    def test_mlp_gelu_route_matches_unfused(self, monkeypatch):
-        # the OPT-IN Tensor-level apply route (fused kernel on TPU, jnp
-        # reference on CPU) must match explicit fc+gelu in value AND in
-        # grads on both the input and the fc parameters.  The default
-        # is the XLA path (USE_PALLAS_MLP=False, PERF.md), so force the
-        # apply route here to keep it covered.
-        import paddle_tpu as paddle
-        from paddle_tpu import nn
-        from paddle_tpu.nn import functional as F
-        from paddle_tpu.ops import fused_gelu_linear as fgl
-        from paddle_tpu.ops.fused_gelu_linear import mlp_gelu
-        monkeypatch.setattr(fgl, 'USE_PALLAS_MLP', True)
-        paddle.seed(0)
-        fc = nn.Linear(32, 64)
-        xv = np.random.RandomState(0).randn(4, 32).astype('float32')
-
-        x1 = paddle.to_tensor(xv, stop_gradient=False)
-        y1 = mlp_gelu(x1, fc)
-        y1.sum().backward()
-        g_x1 = np.asarray(x1.grad.numpy())
-        g_w1 = np.asarray(fc.weight.grad.numpy())
-        fc.weight.clear_grad()
-        fc.bias.clear_grad()
-
-        x2 = paddle.to_tensor(xv, stop_gradient=False)
-        y2 = F.gelu(fc(x2), approximate=True)
-        y2.sum().backward()
-
-        np.testing.assert_allclose(np.asarray(y1.numpy()),
-                                   np.asarray(y2.numpy()), rtol=1e-5,
-                                   atol=1e-6)
-        np.testing.assert_allclose(g_x1, np.asarray(x2.grad.numpy()),
-                                   rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(g_w1,
-                                   np.asarray(fc.weight.grad.numpy()),
-                                   rtol=1e-4, atol=1e-5)
-
-    def test_bert_mlp_grad_plumbing(self, monkeypatch):
-        # end-to-end: tiny BERT forward+backward through the OPT-IN
-        # apply route reaches the fc parameters (CPU hits the jnp
-        # fallback; kernel parity is covered by the interpret-mode
-        # tests above)
-        import paddle_tpu as paddle
-        from paddle_tpu.models.bert import bert_tiny
-        from paddle_tpu.ops import fused_gelu_linear as fgl
-        monkeypatch.setattr(fgl, 'USE_PALLAS_MLP', True)
-        paddle.seed(0)
-        m = bert_tiny()
-        ids = np.random.RandomState(0).randint(0, 128, (2, 16)) \
-            .astype('int64')
-        logits, nsp = m(paddle.to_tensor(ids))
-        lbl = np.where(np.random.RandomState(1).rand(2, 16) < 0.3,
-                       ids, -100).astype('int64')
-        loss = m.loss((logits, nsp), paddle.to_tensor(lbl))
-        loss.backward()
-        g = m.bert.layers[0].fc.weight.grad
-        assert g is not None and np.isfinite(np.asarray(g.value)).all()
 
 
 class TestFlashAutotuneTable:

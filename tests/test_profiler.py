@@ -12,8 +12,8 @@ from paddle_tpu import nn, profiler
 
 class TestOpSummary:
     def test_renders_for_resnet_bench_step(self, capsys):
-        """The table must render for the (bench.py-shaped) ResNet
-        trainer step: AMP O2 strategy, ParallelTrainer, NHWC."""
+        """The table must render for a ResNet trainer step: AMP O2
+        strategy, ParallelTrainer, NHWC."""
         from paddle_tpu.vision.models.resnet import ResNet, BasicBlock
         from paddle_tpu.parallel import ParallelTrainer
         from paddle_tpu.distributed import fleet

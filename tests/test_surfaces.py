@@ -1,7 +1,5 @@
 """API-surface parity: static.nn, hub, inference, onnx, incubate,
 LocalSGD (SURVEY.md §2 items 3, 33, 40 + aux surfaces)."""
-import os
-
 import numpy as np
 import pytest
 import jax
@@ -269,58 +267,3 @@ class TestUtilsNamespace:
         import paddle_tpu as paddle
         paddle.utils.run_check()
         assert 'successfully' in capsys.readouterr().out
-
-
-class TestBenchRegistry:
-    """Every bench config must be registered in every lookup table —
-    a missing key is a KeyError in the middle of a chip run."""
-
-    def test_config_tables_aligned(self):
-        bench = self._load_bench()
-        names = set(bench.CONFIGS)
-        assert set(bench.UNITS) == names
-        assert set(bench.BASELINES) == names
-        assert set(bench.METRIC_NAMES) == names
-        assert set(bench.TIMEOUT_SCALE) <= names
-
-    @staticmethod
-    def _load_module(relpath):
-        import importlib.util
-        import os
-        path = os.path.join(os.path.dirname(__file__), '..', relpath)
-        name = os.path.basename(relpath).rsplit('.', 1)[0]
-        spec = importlib.util.spec_from_file_location(name, path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    @classmethod
-    def _load_bench(cls):
-        return cls._load_module('bench.py')
-
-    def test_smoke_orchestration_end_to_end(self, tmp_path):
-        """The driver-facing path: `bench.py --smoke` spawns every
-        config in its own subprocess, one after another, and
-        assembles one JSON line whose results all name the platform
-        they ran on.  This is the test that fails BEFORE a broken
-        orchestration burns chip time."""
-        import json as _json
-        import subprocess
-        import sys as _sys
-        repo = os.path.join(os.path.dirname(__file__), '..')
-        env = dict(os.environ, JAX_PLATFORMS='cpu')
-        proc = subprocess.run(
-            [_sys.executable, 'bench.py', '--smoke'],
-            cwd=repo, env=env, capture_output=True, text=True,
-            timeout=1500)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        line = proc.stdout.strip().splitlines()[-1]
-        out = _json.loads(line)
-        assert out['metric'] == 'resnet50_bf16_train_throughput'
-        assert out['value'] and out['value'] > 0
-        got = {'resnet'} | set(out['extras'])
-        bench = self._load_bench()
-        assert got == set(bench.CONFIGS), got
-        for name, res in out['extras'].items():
-            assert res.get('value'), (name, res)
-            assert res.get('platform') == 'cpu'

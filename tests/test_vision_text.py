@@ -52,8 +52,8 @@ def test_resnet_nhwc_matches_nchw():
 def test_resnet_s2d_stem_matches_standard():
     """The MLPerf-TPU space-to-depth stem is the SAME function as the
     7x7/s2 stem under the exact weight re-lay
-    (space_to_depth_stem_weight) — proven here on CPU; the chip A/B
-    (tools/bench_resnet_s2d.py) measures whether it is faster."""
+    (space_to_depth_stem_weight) — proven here on CPU; whether it is
+    faster on the chip is not measured."""
     from paddle_tpu.vision.models.resnet import (
         space_to_depth_stem_weight)
     paddle.seed(0)

@@ -22,7 +22,7 @@ Usage:
     python tools/chaos_run.py                         # default plan
     python tools/chaos_run.py --plan plan.json        # your plan
     python tools/chaos_run.py --plan '{"seed":7,...}' # inline JSON
-    python tools/chaos_run.py --smoke --json          # CI gate (bench)
+    python tools/chaos_run.py --smoke --json          # CI gate
     python tools/chaos_run.py --script train.py a b   # your script
 
 With ``--script`` the plan is exported as PADDLE_TPU_CHAOS_PLAN and

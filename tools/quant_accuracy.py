@@ -10,7 +10,7 @@ reduction at negligible quality loss, and this harness is the
 chip time is spent.  The wire half rides along: each trainer's
 compiled module is censused (analysis.hlo.collective_census) so the
 report carries measured predicted-wire bytes per dtype, and
-``bench.py --quant-smoke`` joins the same evidence through
+tests/test_engine_quant_wire.py joins the same evidence through
 run_report.
 
     python tools/quant_accuracy.py                   # lenet + gpt

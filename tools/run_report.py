@@ -44,7 +44,7 @@ offsets land in ``clock_skew``; anchoring is skipped when any host
 never stepped (nothing trustworthy to anchor on).
 
 ``--json`` emits one stable dict (schema_version 1, additively
-extended) that bench.py and CI consume; tests/test_event_telemetry.py
+extended) that CI consumes; tests/test_event_telemetry.py
 schema-checks it.
 
 Stdlib-only on purpose: it must run on a dev machine against JSONL

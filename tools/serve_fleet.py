@@ -92,7 +92,7 @@ def launch_fleet(config_path, replicas=2, spares=0, workdir=None,
                  warmup_spares=True, extra_env=None):
     """Spawn the worker set and return a started
     (:class:`FleetRouter`, handles) pair — the importable form
-    bench.py's --frontdoor-smoke and the chaos drill use."""
+    tests/test_engine_frontdoor.py::TestRealFleetE2E uses."""
     from paddle_tpu.serving.router import FleetRouter, ReplicaHandle
     workdir = workdir or os.path.join('.', '_fleet')
     active, warm = [], []

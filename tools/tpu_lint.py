@@ -62,8 +62,8 @@ tools/calibrate_costmodel.py) into the cost model.
 Exit codes: 0 = no findings at/above --fail-on (default: high),
 1 = findings at/above --fail-on, 2 = usage error, or an --hlo
 infra failure (mesh build / lower crashed: the text/JSON report is
-still printed, with the error under "hlo_error").  CI and bench
-scripts consume --json; the tier-1 self-lint gates
+still printed, with the error under "hlo_error").  CI scripts
+consume --json; the tier-1 self-lint gates
 (tests/test_analysis.py, tests/test_analysis_hlo.py) run this over
 examples/ and paddle_tpu/models/ (AST and --hlo) and require exit 0.
 
@@ -502,8 +502,8 @@ def main(argv=None):
         except Exception as e:
             # do NOT discard the AST/jaxpr report already in hand: a
             # broken lower must not silently disable the rest of the
-            # gate (bench's preflight parses stdout JSON regardless of
-            # the exit code)
+            # gate (a caller may parse stdout JSON regardless of the
+            # exit code)
             hlo_error = repr(e)
             print(f'tpu_lint: --hlo audit failed: {hlo_error} — '
                   'AST/jaxpr findings below are still valid',
