@@ -202,15 +202,10 @@ def leg_kernels():
     rs = np.random.RandomState(0)
     t0 = time.perf_counter()
 
-    # flash attention at the trainer's shape: B*H=96, T=1024, d=64,
-    # causal, default blocks
-    bh, t, d = 96, 1024, 64
-    scale = 1.0 / d ** 0.5
-    bq, bk = fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K
-    q, k, v, w = (jnp.asarray(rs.randn(bh, t, d), jnp.bfloat16)
-                  for _ in range(4))
-
-    def fwd_bwd(attn):
+    # flash attention, causal, at the blocks each shape resolves: this
+    # leg's trainer (B*H=96, T=1024, d=64) and the benchmark's cell
+    # train_seq2048 (B*H=64, T=2048, d=128)
+    def fwd_bwd(attn, w):
         def f(q, k, v):
             def loss(q, k, v):
                 o = attn(q, k, v)
@@ -221,16 +216,23 @@ def leg_kernels():
             return (o,) + grads
         return f
 
-    got = run_compiled(
-        fwd_bwd(lambda q, k, v: fa._flash(q, k, v, True, scale, bq, bk)),
-        (q, k, v), FLASH_KERNELS)
-    with jax.default_matmul_precision('highest'):
-        want = run_compiled(
-            fwd_bwd(lambda q, k, v: fa._reference(q, k, v, True, scale)),
-            (q, k, v), ())
-    for name, g, r in zip(('out', 'dq', 'dk', 'dv'), got, want):
-        agree(f'flash {name} [{bh},{t},{d}] causal blocks ({bq},{bk})',
-              g, r, BF16_TOL)
+    for bh, t, d in ((96, 1024, 64), (64, 2048, 128)):
+        scale = 1.0 / d ** 0.5
+        bq, bk = fa._tuned_blocks(t, t, d, True)
+        q, k, v, w = (jnp.asarray(rs.randn(bh, t, d), jnp.bfloat16)
+                      for _ in range(4))
+        got = run_compiled(
+            fwd_bwd(lambda q, k, v: fa._flash(q, k, v, True, scale,
+                                              bq, bk), w),
+            (q, k, v), FLASH_KERNELS)
+        with jax.default_matmul_precision('highest'):
+            want = run_compiled(
+                fwd_bwd(lambda q, k, v: fa._reference(q, k, v, True,
+                                                      scale), w),
+                (q, k, v), ())
+        for name, g, r in zip(('out', 'dq', 'dk', 'dv'), got, want):
+            agree(f'flash {name} [{bh},{t},{d}] causal blocks '
+                  f'({bq},{bk})', g, r, BF16_TOL)
 
     # LayerNorm at the trainer's shape: [B*T, H] bf16, f32 affine
     x = jnp.asarray(rs.randn(8192, 768), jnp.bfloat16)

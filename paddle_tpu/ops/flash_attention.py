@@ -6,8 +6,13 @@ an online-softmax tiled kernel that never materialises the [T, T] score
 matrix, with a recompute-style Pallas backward (dq / dkv kernels) using
 the forward's logsumexp.  SURVEY.md §2 item 36.
 
-Layout: [B*H, T, D] (callers fold batch and heads).  f32 accumulation
-regardless of input dtype (bf16 inputs hit the MXU natively).
+Layout: [B*H, T, D] (callers fold batch and heads).  Every matmul
+takes its operands in the dtype q, k, v and do are stored in (p and ds
+are cast to it) and accumulates in float32; scores, max, sum, lse,
+delta and the accumulators are float32.  On the v5e this is what the
+MXU did anyway: Mosaic's default for float32 operands is one bfloat16
+pass, and a bfloat16 caller's outputs and gradients are bit for bit
+those of the float32-cast kernel (PERF.md section 6, PR 32).
 
 On non-TPU backends `flash_attention` falls back to a jnp reference
 implementation (same math, materialised scores) so tests/CPU runs work.
@@ -25,8 +30,10 @@ from . import _gating
 __all__ = ['flash_attention', 'flash_attention_lse', 'can_use_pallas',
            'autotune_blocks']
 
-# tuned on v5e at T=4096 D=128: (256, 512) beats XLA's fused einsum
-# attention by ~21% (an earlier round; no ledger line holds it)
+# What every shape without a table entry runs.  The one sweep a ledger
+# line stands behind (PR 32, a v5e, [64, 2048, 128] bfloat16 causal,
+# forward plus backward) read 5.55 ms a call here; fewer, larger tiles
+# are faster there (the table below), no other shape has been swept.
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -34,9 +41,13 @@ NEG_INF = -1e30
 # -- per-shape block tuning --------------------------------------------------
 # key "tq,tk,d,causal" -> (bq, bk).  The table is this literal: what a
 # checkout runs is what git holds.  tools/tune_flash.py measures
-# candidates on the chip and prints the winners; a builder who wants
-# one pastes it here.  Explicit block_q/block_k args always win.
-_tune_table = {}
+# candidates on the chip and prints every one; a builder who wants an
+# entry pastes it here.  Explicit block_q/block_k args always win.
+_tune_table = {
+    # the benchmark's train_seq2048: 4.09 ms a call against 5.55 at
+    # (256, 512), twelve candidates (PERF.md section 6, PR 32)
+    '2048,2048,128,1': (512, 1024),
+}
 
 
 def _tuned_blocks(tq, tk, d, causal):
@@ -45,19 +56,22 @@ def _tuned_blocks(tq, tk, d, causal):
 
 
 def autotune_blocks(tq, tk, d, causal=True, dtype=jnp.bfloat16,
-                    bh=8, candidates=None, iters=8):
-    """Time the kernel per (bq, bk) candidate ON THE LIVE DEVICE and
-    record the winner in this process's tuning table (the cuDNN-style
-    heuristic table the reference gets from NVIDIA, built empirically
-    here).  Returns ((bq, bk), ms)."""
+                    bh=8, candidates=None, iters=8, report=None):
+    """Time forward plus backward (flash_fwd, flash_bwd_dq,
+    flash_bwd_dkv, on operands of `dtype`) per (bq, bk) candidate ON
+    THE LIVE DEVICE and record the winner in this process's tuning
+    table (the cuDNN-style heuristic table the reference gets from
+    NVIDIA, built empirically here).  `report((bq, bk), ms)` is called
+    for every candidate that compiled.  Returns ((bq, bk), ms)."""
     import time
     import numpy as np
 
     cands = candidates or [(bq, bk)
                            for bq in (128, 256, 512)
                            for bk in (128, 256, 512, 1024)]
+    cands = sorted({(min(bq, tq), min(bk, tk)) for bq, bk in cands})
     cands = [(bq, bk) for bq, bk in cands
-             if tq % min(bq, tq) == 0 and tk % min(bk, tk) == 0
+             if tq % bq == 0 and tk % bk == 0
              and can_use_pallas(tq, tk, d, bq, bk)]
     if not cands:
         return (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K), float('nan')
@@ -68,27 +82,34 @@ def autotune_blocks(tq, tk, d, causal=True, dtype=jnp.bfloat16,
     scale = 1.0 / math.sqrt(d)
     best, best_ms = None, float('inf')
     for bq, bk in cands:
-        bq_, bk_ = min(bq, tq), min(bk, tk)
-
-        # amortize dispatch: chain the kernel in-graph
+        # amortize dispatch: chain the kernels in-graph
         @jax.jit
-        def run(q, k, v, bq_=bq_, bk_=bk_):
-            # chain on Q (output shape == Q shape) so the scan carries
-            # a real data dependency between kernel invocations
+        def run(q, k, v):
+            def loss(q, k, v):
+                out = _flash(q, k, v, causal, scale, bq, bk)
+                return jnp.sum(out.astype(jnp.float32))
+
+            # chain on the gradients (shapes == operands' shapes) so
+            # the scan carries a real data dependency from each
+            # iteration's three kernels into the next's
             def body(c, _):
-                return _flash(c, k, v, causal, scale, bq_, bk_), None
-            out, _ = jax.lax.scan(body, q, None, length=iters)
+                g = jax.grad(loss, argnums=(0, 1, 2))(*c)
+                return tuple((x + 1e-3 * gx).astype(x.dtype)
+                             for x, gx in zip(c, g)), None
+            out, _ = jax.lax.scan(body, (q, k, v), None, length=iters)
             return out
 
         try:
-            float(np.asarray(run(q, k, v)).ravel()[0])   # compile+warm
+            jax.block_until_ready(run(q, k, v))          # compile+warm
             t0 = time.perf_counter()
-            float(np.asarray(run(q, k, v)).ravel()[0])
+            jax.block_until_ready(run(q, k, v))
             ms = (time.perf_counter() - t0) * 1000 / iters
         except Exception:
             continue
+        if report is not None:
+            report((bq, bk), ms)
         if ms < best_ms:
-            best, best_ms = (bq_, bk_), ms
+            best, best_ms = (bq, bk), ms
     if best is None:
         return (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K), float('nan')
     _tune_table[f'{tq},{tk},{d},{int(bool(causal))}'] = best
@@ -119,6 +140,82 @@ def _reference(q, k, v, causal, scale):
     return o.astype(q.dtype)
 
 
+# -- what the three kernels share -------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))      # a . b^T
+_NN = (((1,), (0,)), ((), ()))      # a . b
+_TN = (((0,), (0,)), ((), ()))      # a^T . b
+
+
+def _dot(a, b, dims):
+    """The MXU gets the operands in the dtype they are stored in and
+    accumulates in float32.  Float32 operands take the caller's matmul
+    precision, as they always did; a bfloat16 product is exact in
+    float32, so one pass is all there is, and Mosaic refuses a higher
+    precision on such operands ("Bad lhs type") if the caller's
+    jax_default_matmul_precision reaches it."""
+    precision = (None if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _last_k_block(qi, block_q, block_k):
+    """The last block column a causal row of query blocks computes."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _first_q_block(ki, block_q, block_k, num_q_blocks):
+    """The first block row a causal column of key blocks computes; a
+    column past the last query row (tk > tq) computes none and gets
+    the last."""
+    return jnp.minimum((ki * block_k) // block_q, num_q_blocks - 1)
+
+
+def _kv_index_map(causal, block_q, block_k):
+    """K/V block of grid step (b, qi, ki) of flash_fwd and
+    flash_bwd_dq: a step above the diagonal names the block the step
+    before it held, so the pipeline fetches nothing for it."""
+    if not causal:
+        return lambda b, qi, ki: (b, ki, 0)
+    return lambda b, qi, ki: (
+        b, jnp.minimum(ki, _last_k_block(qi, block_q, block_k)), 0)
+
+
+def _q_index_map(causal, block_q, block_k, num_q_blocks):
+    """q/do/lse/delta block of flash_bwd_dkv's grid step (b, ki, qi):
+    the steps above the diagonal come first in a column and name the
+    first block that column computes."""
+    if not causal:
+        return lambda b, ki, qi: (b, qi, 0)
+    return lambda b, ki, qi: (
+        b, jnp.maximum(qi, _first_q_block(ki, block_q, block_k,
+                                          num_q_blocks)), 0)
+
+
+def _for_tile(causal, qi, ki, block_q, block_k, compute):
+    """Run compute() for tile (qi, ki) unless it lies wholly above the
+    causal diagonal."""
+    if causal:
+        pl.when(ki * block_k <= qi * block_q + block_q - 1)(compute)
+    else:
+        compute()
+
+
+def _scores(q_ref, k_ref, scale, causal, qi, ki):
+    """The [bq, bk] float32 score tile, masked cells at NEG_INF."""
+    s = _dot(q_ref[0], k_ref[0], _NT) * scale
+    if causal:
+        block_q, block_k = s.shape
+        rows = jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0) + qi * block_q
+        cols = jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) + ki * block_k
+        s = jnp.where(rows > cols if causal == 'strict'
+                      else rows >= cols, s, NEG_INF)
+    return s
+
+
 # -- forward kernel ----------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -134,18 +231,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_sc[:] = jnp.zeros_like(l_sc)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)                 # [bq, d]
-        kb = k_ref[0].astype(jnp.float32)                # [bk, d]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + qi * block_q
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1) + ki * block_k
-            s = jnp.where(rows > cols if causal == 'strict'
-                          else rows >= cols, s, NEG_INF)
+        s = _scores(q_ref, k_ref, scale, causal, qi, ki)
         m_prev = m_sc[:, :1]                              # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -156,19 +242,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             p = jnp.where(s <= NEG_INF / 2, 0.0, p)
         alpha = jnp.exp(m_prev - m_new)                   # [bq, 1]
         l_new = alpha * l_sc[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        vb = v_ref[0]
+        acc_sc[:] = acc_sc[:] * alpha + _dot(p.astype(vb.dtype), vb, _NN)
         m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
         l_sc[:] = jnp.broadcast_to(l_new, l_sc.shape)
 
-    if causal:
-        # skip blocks strictly above the diagonal
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    _for_tile(causal, qi, ki, block_q, block_k, compute)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -187,6 +266,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, num_k_blocks=tk // block_k)
+    kv_map = _kv_index_map(causal, block_q, block_k)
     out, lse = pl.pallas_call(
         kernel,
         name='flash_fwd',
@@ -194,8 +274,8 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
@@ -216,6 +296,19 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k):
 
 # -- backward kernels --------------------------------------------------------
 
+def _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, scale, causal,
+          qi, ki):
+    """The tile's probabilities p and ds / scale = p * (dp - delta),
+    float32 [bq, bk]; ds's scale multiplies the accumulated dq and dk
+    once, at _finalize."""
+    s = _scores(q_ref, k_ref, scale, causal, qi, ki)
+    p = jnp.exp(jnp.minimum(s - lse_ref[0][:, :1], 0.0))
+    if causal == 'strict':
+        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+    dp = _dot(do_ref[0], v_ref[0], _NT)
+    return p, p * (dp - delta_ref[0][:, :1])
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_sc, *, scale, causal, block_q, block_k,
                    num_k_blocks):
@@ -227,43 +320,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]                           # [bq, 1]
-        delta = delta_ref[0][:, :1]                       # [bq, 1]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + qi * block_q
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1) + ki * block_k
-            s = jnp.where(rows > cols if causal == 'strict'
-                          else rows >= cols, s, NEG_INF)
-        p = jnp.exp(jnp.minimum(s - lse, 0.0))            # [bq, bk]
-        if causal == 'strict':
-            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dq_sc[:] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _, ds = _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      scale, causal, qi, ki)
+        kb = k_ref[0]
+        dq_sc[:] += _dot(ds.astype(kb.dtype), kb, _NN)
 
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-    else:
-        compute()
+    _for_tile(causal, qi, ki, block_q, block_k, compute)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
-        dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_sc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -278,46 +344,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
     def compute():
-        q = q_ref[0].astype(jnp.float32)
-        kb = k_ref[0].astype(jnp.float32)
-        vb = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + qi * block_q
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1) + ki * block_k
-            s = jnp.where(rows > cols if causal == 'strict'
-                          else rows >= cols, s, NEG_INF)
-        p = jnp.exp(jnp.minimum(s - lse, 0.0))            # [bq, bk]
-        if causal == 'strict':
-            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        dv_sc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bk, d]
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dk_sc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bk, d]
+        p, ds = _p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      scale, causal, qi, ki)
+        q, do = q_ref[0], do_ref[0]
+        dv_sc[:] += _dot(p.astype(do.dtype), do, _TN)     # [bk, d]
+        dk_sc[:] += _dot(ds.astype(q.dtype), q, _TN)      # [bk, d]
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            compute()
-    else:
-        compute()
+    _for_tile(causal, qi, ki, block_q, block_k, compute)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
-        dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_sc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
@@ -341,6 +378,7 @@ def _bwd_pallas(res, g, scale, causal, block_q, block_k, g_lse=None):
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, num_k_blocks=tk // block_k)
+    kv_map = _kv_index_map(causal, block_q, block_k)
     dq = pl.pallas_call(
         dq_kernel,
         name='flash_bwd_dq',
@@ -348,8 +386,8 @@ def _bwd_pallas(res, g, scale, causal, block_q, block_k, g_lse=None):
         grid=(bh, tq // block_q, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_q, 8), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_q, 8), lambda b, qi, ki: (b, qi, 0)),
@@ -363,18 +401,19 @@ def _bwd_pallas(res, g, scale, causal, block_q, block_k, g_lse=None):
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, num_q_blocks=tq // block_q)
+    q_map = _q_index_map(causal, block_q, block_k, tq // block_q)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         name='flash_bwd_dkv',
         interpret=_gating.INTERPRET,
         grid=(bh, tk // block_k, tq // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda b, ki, qi: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_q, 8), q_map),
+            pl.BlockSpec((1, block_q, 8), q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
@@ -397,7 +436,17 @@ def _bwd_pallas(res, g, scale, causal, block_q, block_k, g_lse=None):
 def shapes_tile(tq, tk, d, block_q, block_k):
     """The single shape predicate every Pallas-attention gate shares.
     d=64 compiles fine (Mosaic pads the lane dim); smaller head dims
-    waste too much of the tile."""
+    waste too much of the tile.
+
+    What a causal grid does with the tiles this admits: the grid is
+    the full [tq / bq, tk / bk] rectangle; a tile wholly above the
+    diagonal runs no body (_for_tile) and, since its index maps name
+    the block its neighbour on the diagonal's side holds
+    (_kv_index_map, _q_index_map), fetches nothing; every other tile
+    runs ONE body that builds the mask.  A second, mask-free body for
+    tiles wholly below the diagonal was measured and dropped: the
+    tile-wide elementwise work hides under the matmuls and the
+    per-row work (PERF.md section 6, PR 32)."""
     bq, bk = min(block_q, tq), min(block_k, tk)
     return (tq % bq == 0 and tk % bk == 0 and d % 64 == 0
             and bq >= 128 and bk >= 128)

@@ -8,7 +8,8 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.flash_attention import (
-    _flash, _reference as att_ref, flash_attention)
+    _flash, _kv_index_map, _q_index_map, _reference as att_ref,
+    flash_attention)
 from paddle_tpu.ops.fused_norm import (
     _ln, _reference as ln_ref, fused_layer_norm)
 from paddle_tpu.ops.fused_softmax import (
@@ -57,6 +58,143 @@ class TestFlashAttention:
         ref = att_ref(q, k, v, True, 1.0 / np.sqrt(32))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-6)
+
+
+def _fwd_bwd(attn, q, k, v, w):
+    """(out, dq, dk, dv) of sum(attn(q, k, v) * w), as float32 arrays."""
+    def loss(q, k, v):
+        o = attn(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+    (_, o), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return [np.asarray(x, np.float32) for x in (o,) + grads]
+
+
+def _kernel_eqns(jaxpr, found):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (a pallas_call's kernel, a pl.when's branches)."""
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    _kernel_eqns(sub, found)
+    return found
+
+
+class TestFlashOperandDtype:
+    """The kernels hand the MXU the dtype their operands are stored
+    in.  A kernel has no counter, so the jaxpr is the proof."""
+
+    # Largest error over the largest reference value.  Read from the
+    # kernel as it was before PR 32 (every operand cast to float32) on
+    # these very inputs: 1.6e-3 to 3.3e-3 over the eighteen
+    # (causal, blocks, tensor) cases, which is the output's one bf16
+    # rounding (2^-9).  With p and ds rounded to bf16 before their
+    # matmuls the kernel reads 1.7e-3 to 3.9e-3 (CPU, interpret mode;
+    # on the chip the two kernels agree bit for bit, PERF.md section
+    # 6, PR 32); 2^-7 leaves a second bf16 rounding room.
+    BF16_TOL = 2.0 ** -7
+
+    @pytest.mark.parametrize('blocks', [(128, 128), (128, 256)])
+    @pytest.mark.parametrize('causal', [False, True, 'strict'])
+    def test_bf16_operands_match_f32_reference(self, interp, causal,
+                                               blocks):
+        # T=512: a [4 x 4] and a [4 x 2] grid, so block_q != block_k,
+        # tiles on, below and above the diagonal all occur
+        rs = np.random.RandomState(0)
+        q, k, v = (jnp.asarray(rs.randn(2, 512, 64), jnp.bfloat16)
+                   for _ in range(3))
+        w = jnp.asarray(rs.randn(2, 512, 64), jnp.float32)
+        got = _fwd_bwd(lambda q, k, v: _flash(q, k, v, causal, 0.125,
+                                              *blocks), q, k, v, w)
+        want = _fwd_bwd(lambda q, k, v: att_ref(q, k, v, causal, 0.125),
+                        *(x.astype(jnp.float32) for x in (q, k, v)), w)
+        for name, g, r in zip(('out', 'dq', 'dk', 'dv'), got, want):
+            err = np.abs(g - r).max() / np.abs(r).max()
+            assert err <= self.BF16_TOL, (name, err)
+
+    @pytest.mark.parametrize('kernel', ['flash_fwd', 'flash_bwd_dq',
+                                        'flash_bwd_dkv'])
+    @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+    def test_matmuls_take_the_stored_dtype(self, kernel, dtype):
+        x = jnp.zeros((1, 256, 64), dtype)
+        traced = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(_flash(q, k, v, True, 0.125, 128,
+                                           128).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(x, x, x)
+        calls = [e for e in _kernel_eqns(traced.jaxpr, [])
+                 if e.primitive.name == 'pallas_call'
+                 and e.params['name'] == kernel]
+        assert len(calls) == 1
+        body = _kernel_eqns(calls[0].params['jaxpr'], [])
+        dots = [e for e in body if e.primitive.name == 'dot_general']
+        assert len(dots) == {'flash_fwd': 2, 'flash_bwd_dq': 3,
+                             'flash_bwd_dkv': 4}[kernel]
+        # float32 operands take the caller's matmul precision (this
+        # suite's conftest sets 'highest'), as at the parent; Mosaic
+        # refuses it on bfloat16 ones, whose product is exact anyway
+        want = jax.lax.Precision.HIGHEST if dtype == 'float32' \
+            else jax.lax.Precision.DEFAULT
+        for e in dots:
+            assert [str(a.aval.dtype) for a in e.invars] == [dtype] * 2
+            assert str(e.outvars[0].aval.dtype) == 'float32'
+            assert e.params['precision'] == (want, want)
+        if dtype == 'float32':
+            # a float32 caller gets the matmuls the parent gave it:
+            # nothing in the kernel is bfloat16
+            seen = {str(a.aval.dtype) for e in body
+                    for a in list(e.invars) + list(e.outvars)
+                    if hasattr(a.aval, 'dtype')}
+            assert 'bfloat16' not in seen
+
+
+class TestFlashIndexMaps:
+    """A causal grid step above the diagonal names the block its
+    neighbour holds, so the pipeline fetches nothing for it."""
+    BQ, BK, NQ, NK = 128, 256, 8, 4          # T=1024: an [8 x 4] grid
+
+    def _computes(self, qi, ki):
+        return ki * self.BK <= qi * self.BQ + self.BQ - 1
+
+    @pytest.mark.parametrize('causal', [True, 'strict'])
+    def test_kv_map_stays_on_the_rows_last_block(self, causal):
+        kv_map = _kv_index_map(causal, self.BQ, self.BK)
+        for qi in range(self.NQ):
+            last = max(ki for ki in range(self.NK)
+                       if self._computes(qi, ki))
+            for ki in range(self.NK):
+                b, blk, z = kv_map(3, qi, ki)
+                want = ki if self._computes(qi, ki) else last
+                assert (b, int(blk), z) == (3, want, 0), (qi, ki)
+
+    @pytest.mark.parametrize('causal', [True, 'strict'])
+    def test_q_map_stays_on_the_columns_first_block(self, causal):
+        q_map = _q_index_map(causal, self.BQ, self.BK, self.NQ)
+        for ki in range(self.NK):
+            first = min(qi for qi in range(self.NQ)
+                        if self._computes(qi, ki))
+            for qi in range(self.NQ):
+                b, blk, z = q_map(3, ki, qi)
+                want = qi if self._computes(qi, ki) else first
+                assert (b, int(blk), z) == (3, want, 0), (qi, ki)
+
+    def test_non_causal_maps_name_every_block(self):
+        kv_map = _kv_index_map(False, self.BQ, self.BK)
+        q_map = _q_index_map(False, self.BQ, self.BK, self.NQ)
+        for qi in range(self.NQ):
+            for ki in range(self.NK):
+                assert kv_map(0, qi, ki) == (0, ki, 0)
+                assert q_map(0, ki, qi) == (0, qi, 0)
+
+    def test_more_keys_than_queries_stays_in_range(self):
+        # tk > tq (a ring step's partial): key columns past the last
+        # query row compute nothing and must still name a real block
+        q_map = _q_index_map(True, 128, 128, 2)
+        assert [int(q_map(0, ki, 0)[1]) for ki in range(4)] == \
+            [0, 1, 1, 1]
 
 
 class TestFusedLayerNorm:
@@ -164,6 +302,15 @@ class TestFlashAutotuneTable:
         # other shapes still default
         assert fa._tuned_blocks(4096, 4096, 128, True) == \
             (fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
+
+    def test_the_table_holds_the_one_swept_shape(self):
+        """PR 32's sweep at the benchmark's shape is the only entry:
+        no other shape has a cell that could have measured one."""
+        import importlib
+        fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+        assert fa._tune_table == {'2048,2048,128,1': (512, 1024)}
+        assert fa._tuned_blocks(2048, 2048, 128, True) == (512, 1024)
+        assert fa.shapes_tile(2048, 2048, 128, 512, 1024)
 
     def test_autotune_on_cpu_is_safe(self):
         """Without a TPU the pallas gate rejects every candidate and

@@ -260,6 +260,34 @@ def test_mosaic_compiles_the_kernel_at_serving_widths(one_chip, dtype,
     assert 'tpu_custom_call' in text and 'paged_decode' in text
 
 
+@pytest.mark.parametrize('bh,t,d,dtype', [(64, 2048, 128, 'bfloat16'),
+                                          (96, 1024, 64, 'bfloat16'),
+                                          (64, 2048, 128, 'float32')])
+def test_mosaic_compiles_the_flash_kernels_at_training_widths(one_chip, bh,
+                                                             t, d, dtype):
+    """train_seq2048's attention (4 x 16 heads of 128 at T=2048, the
+    blocks its table entry resolves) and chip_smoke.py's GPT-2 small,
+    causal, forward and backward; float32 as a model without AMP hands
+    it over.  Compiled, not run, under this suite's
+    jax_default_matmul_precision 'highest', which Mosaic takes for
+    float32 operands and refuses for bfloat16 ones.  (Here with the
+    other described-chip compiles: one process of a suite may hold the
+    TPU's compiler.)"""
+    import importlib
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    bq, bk = fa._tuned_blocks(t, t, d, True)
+    x = jax.ShapeDtypeStruct((bh, t, d), jnp.dtype(dtype),
+                             sharding=one_chip)
+    step = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(fa._flash(
+            q, k, v, True, d ** -0.5, bq, bk).astype(jnp.float32)),
+        argnums=(0, 1, 2)))
+    text = _uncached(lambda: step.lower(x, x, x).compile()).as_text()
+    for name in ('flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv'):
+        assert any('tpu_custom_call' in line and name in line
+                   for line in text.splitlines()), name
+
+
 # -- the GPT serving modules for the same described chip ---------------------
 def _results_of(text, shape, ops):
     """The instructions of an HLO text whose operation is one of `ops`

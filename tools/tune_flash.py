@@ -1,17 +1,19 @@
 #!/usr/bin/env python
-"""Flash-attention block autotune sweep (PERF.md round-3 lead 4).
+"""Flash-attention block sweep: forward plus backward per (block_q,
+block_k), in the operands' dtype, printed for every candidate.
 
-Run ON THE REAL CHIP; writes winners into
-paddle_tpu/ops/flash_attention_tuning.json, which flash_attention()
-consults per shape at call time.
+Run ON THE REAL CHIP.  The table flash_attention() consults is the
+literal `_tune_table` in paddle_tpu/ops/flash_attention.py: a builder
+who wants an entry pastes it there with the sweep's readings in PERF.md.
+The default shape is the one the benchmark measures (train_seq2048:
+[4 x 16 heads, 2048, 128] bfloat16, causal).
 
-    python tools/tune_flash.py                  # standard shape sweep
-    python tools/tune_flash.py --tq 4096 --d 128
+    python tools/tune_flash.py
+    python tools/tune_flash.py --tq 1024 --d 64 --bh 96
 """
 import argparse
-import sys
-
 import os
+import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -19,32 +21,28 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument('--tq', type=int, default=None)
+    ap.add_argument('--tq', type=int, default=2048)
     ap.add_argument('--tk', type=int, default=None)
-    ap.add_argument('--d', type=int, default=None)
-    ap.add_argument('--bh', type=int, default=8)
+    ap.add_argument('--d', type=int, default=128)
+    ap.add_argument('--bh', type=int, default=64)
     ap.add_argument('--no-causal', action='store_true')
     args = ap.parse_args()
 
     from paddle_tpu.core.compile_cache import setup_xla_cache
     setup_xla_cache()
-    from paddle_tpu.ops.flash_attention import autotune_blocks
+    from paddle_tpu.ops.flash_attention import (
+        _tuned_blocks, autotune_blocks)
 
-    if args.tq:
-        shapes = [(args.tq, args.tk or args.tq, args.d or 128)]
-    else:
-        # the bench/model shapes: GPT-2 small T=1024 d=64, BERT s128
-        # (too small for pallas — skipped by the gate), longctx bench
-        # = GPT-2 small at T=4096 so d stays 64, long-ctx 4096/8192
-        # at d=128 for the larger-model face
-        shapes = [(1024, 1024, 64), (2048, 2048, 64), (4096, 4096, 64),
-                  (2048, 2048, 128), (4096, 4096, 128),
-                  (8192, 8192, 128)]
+    tq, tk, d = args.tq, args.tk or args.tq, args.d
     causal = not args.no_causal
-    for tq, tk, d in shapes:
-        best, ms = autotune_blocks(tq, tk, d, causal=causal, bh=args.bh)
-        print(f'T={tq}x{tk} d={d} causal={causal}: best blocks={best} '
-              f'({ms:.2f} ms/call)', flush=True)
+    now = _tuned_blocks(tq, tk, d, causal)
+    print(f'T={tq}x{tk} d={d} bh={args.bh} causal={causal}: forward + '
+          f'backward, ms a call; the module resolves {now}', flush=True)
+    best, ms = autotune_blocks(
+        tq, tk, d, causal=causal, bh=args.bh,
+        report=lambda blocks, ms: print(f'  blocks={blocks}: {ms:.3f} ms',
+                                        flush=True))
+    print(f'best blocks={best} ({ms:.3f} ms)', flush=True)
 
 
 if __name__ == '__main__':
