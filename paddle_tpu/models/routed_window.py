@@ -37,12 +37,19 @@ matmul precision (2560 x 64: no traffic beside the experts).
 
 Two programs for the expert product, one mathematics.  A prefill's
 rows (`grouped=True`) are sorted by expert and each expert's rows
-multiply its matrices once (`jax.lax.ragged_dot`, which XLA lowers to a
-grouped matmul on the TPU: some 1,150 rows an expert at 12k tokens,
-compute-bound).  A decode step's few rows (32 rows hit 95% of 64
-experts) take a dense product over all experts, weighted by a
-[rows, experts] matrix that is zero off the chosen ones: bound by the
-same bytes, the experts' weights, and no sort in the token step.
+multiply its matrices once: on the chip through the Pallas grouped
+matmul of `ops/grouped_matmul.py` (row tiles of 128 visited by
+what the router sent, gate and up in one pass over a row tile with
+`_gated` as its epilogue, the down projection a second call; some 1,150
+rows an expert at 12k tokens, compute-bound), and through
+`jax.lax.ragged_dot` wherever that kernel's gate refuses (the CPU, a
+mesh, other dtypes and widths).  The bucket's pad positions (`active`
+false) sort behind the last expert, are multiplied by nothing and come
+back as zeros: prompts are padded on the right and attention is causal,
+so no true position ever read one.  A decode step's few rows (32 rows
+hit 95% of 64 experts) take a dense product over all experts, weighted
+by a [rows, experts] matrix that is zero off the chosen ones: bound by
+the same bytes, the experts' weights, and no sort in the token step.
 
 The cache: `serving_state = 'paged'` with `cache_spec()`, from which
 the engine builds a `LayerGroupKVCache` (`serving/kv_cache.py`): full
@@ -56,6 +63,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as init
+from ..ops import grouped_matmul as gm
 from ..ops.flash_attention import flash_attention
 from ..ops.paged_attention import paged_attention, write_kv
 from .decoder_parts import Dense, RMSNorm, matmul, rms_norm, rotary, sub
@@ -125,7 +133,8 @@ def routed_experts(p, h2, logits, k, *, grouped, active=None):
     `h2 [T, hidden]` routed by `logits [T, experts]` (the router's, of
     the layer's normed INPUT), and its counts: assignments made,
     distinct experts hit and the largest expert's load, over the rows
-    `active` marks (all of them where it is None).
+    `active` marks (all of them where it is None).  A grouped product
+    computes the active rows only; the others' output is zero.
 
     `p` holds `gate_proj`, `up_proj` [experts, hidden, width] and
     `down_proj` [experts, width, hidden].  `grouped` picks the program
@@ -143,7 +152,7 @@ def routed_experts(p, h2, logits, k, *, grouped, active=None):
         stats = jnp.stack([load.sum(), (load > 0).sum(), load.max()])
     x = h2.astype(wg.dtype)
     if grouped:
-        return _grouped(x, top_i, w, wg, wu, wd, k), stats
+        return _grouped(x, top_i, w, wg, wu, wd, k, active), stats
     with jax.named_scope('moe.dispatch'):
         mix = jnp.zeros((T, E), F32).at[
             jnp.arange(T)[:, None], top_i].set(w)
@@ -160,27 +169,48 @@ def routed_experts(p, h2, logits, k, *, grouped, active=None):
     return out, stats
 
 
-def _grouped(x, top_i, w, wg, wu, wd, k):
+def grouped_path(rows, wg, wd):
+    """'kernel' where the grouped product of `rows` sorted rows takes
+    `ops/grouped_matmul.py`, 'ragged_dot' where its gate refuses."""
+    return 'kernel' if gm.can_use_pallas(rows, wg, 2) \
+        and gm.can_use_pallas(rows, wd) else 'ragged_dot'
+
+
+def _grouped(x, top_i, w, wg, wu, wd, k, active):
     """The expert product of rows x [T, hidden] (in the weights'
     dtype) routed to `top_i` [T, k] with weights `w` [T, k]: rows
-    sorted by expert, each expert's rows against its matrices once."""
+    sorted by expert, each expert's rows against its matrices once;
+    rows that are not `active` behind the last expert, in no group."""
     T, E = x.shape[0], wg.shape[0]
     with jax.named_scope('moe.dispatch'):
+        if active is not None:
+            top_i = jnp.where(active[:, None], top_i, E)
         flat = top_i.reshape(-1)
         order = jnp.argsort(flat, stable=True)
         rows = x[order // k]                         # [T k, hidden]
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     with jax.named_scope('moe.experts'):
-        g = jax.lax.ragged_dot(rows, wg, sizes, preferred_element_type=F32)
-        u = jax.lax.ragged_dot(rows, wu, sizes, preferred_element_type=F32)
-        y = jax.lax.ragged_dot(_gated(g, u, wd.dtype), wd, sizes,
-                               preferred_element_type=F32)
+        if grouped_path(T * k, wg, wd) == 'kernel':
+            y = gm.grouped_matmul(
+                gm.grouped_gate_up(rows, wg, wu, sizes, wd.dtype), wd,
+                sizes)
+        else:
+            g = jax.lax.ragged_dot(rows, wg, sizes,
+                                   preferred_element_type=F32)
+            u = jax.lax.ragged_dot(rows, wu, sizes,
+                                   preferred_element_type=F32)
+            y = jax.lax.ragged_dot(_gated(g, u, wd.dtype), wd, sizes,
+                                   preferred_element_type=F32)
     with jax.named_scope('moe.dispatch'):
         # back to the tokens' own order, then each token's k in the
         # order its router chose them: a row's sum does not depend on
         # the rows around it
         y = y[jnp.argsort(order)].reshape(T, k, -1)
-        return (y * w[:, :, None]).sum(1)
+        out = (y * w[:, :, None]).sum(1)
+        if active is not None:
+            # whatever a product left behind its last group
+            out = jnp.where(active[:, None], out, 0.0)
+        return out
 
 
 def plain_heads(p, x, positions, *, num_heads, num_kv_heads, head_dim,
@@ -279,6 +309,15 @@ class RoutedWindowForCausalLM(nn.Layer):
         self.model = RoutedWindowDecoder(config)
         self.lm_head = _Table(config)
 
+    def prefill_path(self, rows, bucket):
+        """Which program the routed layers of a prefill of `rows`
+        prompts padded to `bucket` take (`grouped_path`): what the
+        engine counts as `moe_kernel_prefills`."""
+        experts = self.model.layers[0].experts
+        return grouped_path(
+            rows * bucket * self.config.experts_per_token,
+            experts.gate_proj.value, experts.down_proj.value)
+
     def cache_spec(self):
         """What the engine's `LayerGroupKVCache` is built from: each
         layer's window (None: a full layer) and the key/value heads."""
@@ -332,6 +371,11 @@ class RoutedWindowForCausalLM(nn.Layer):
                      num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
                      theta=cfg.rope_theta)
         decoding = views is not None and getattr(views[0], 'paged', False)
+        # a prefill's true positions: the bucket's pad rows on the right
+        # are routed nowhere
+        true_rows = None if lengths is None else (
+            jnp.arange(T, dtype=jnp.int32)[None, :]
+            < lengths[:, None].astype(jnp.int32)).reshape(B * T)
         with jax.named_scope('dec.embed'):
             x = params['model.embed.weight'][ids].astype(F32)
         new_views = []
@@ -359,7 +403,7 @@ class RoutedWindowForCausalLM(nn.Layer):
                 y, stats = routed_experts(
                     sub(p, 'experts.'), h.reshape(B * T, -1), logits,
                     cfg.experts_per_token, grouped=not decoding,
-                    active=view.active if decoding else None)
+                    active=view.active if decoding else true_rows)
                 x = x + y.reshape(B, T, -1)
             if decoding:
                 # the counts, and what this layer computed a row (T is

@@ -294,6 +294,11 @@ class ServingEngine:
         # ones, and the bucket's of every row of the chunk
         self.prefill_tokens = 0
         self.prefill_padded_tokens = 0
+        # prefill dispatches whose module took a routed model's Pallas
+        # grouped matmul (`model.prefill_path`, asked when the module
+        # is built), and the modules that did
+        self.moe_kernel_prefills = 0
+        self._kernel_prefills = set()
         # small integer counts a model's decode steps hand back through
         # their cache views (`model.step_stat_names`; a routed model's
         # assignments, experts hit and largest load), summed here
@@ -531,6 +536,9 @@ class ServingEngine:
         sig = ('prefill', P, B)
         if sig in self._modules:
             return self._modules[sig]
+        path = getattr(self.model, 'prefill_path', None)
+        if path is not None and path(B, P) == 'kernel':
+            self._kernel_prefills.add(sig)
         return self._get_module(sig, *self._prefill_spec(P, B))
 
     def _decode_build(self, S, K):
@@ -683,6 +691,7 @@ class ServingEngine:
         # padding rows write where nothing is kept (the trash block)
         where = self.cache.prefill_where([r.rid for r in reqs], B, P)
         self._prefills += 1
+        self.moe_kernel_prefills += ('prefill', P, B) in self._kernel_prefills
         self.prefill_tokens += sum(r.prompt.size for r in reqs)
         self.prefill_padded_tokens += B * P
         tok, *arrays = mod(self._params, self._buffers,
@@ -1002,11 +1011,13 @@ class ServingEngine:
     def counts(self):
         """Counters over the engine's life that a caller differences
         itself: prompt positions prefilled (true, and the buckets'),
-        what the model's decode steps handed back
+        the prefill dispatches that took a routed model's grouped
+        kernel, what the model's decode steps handed back
         (`model.step_stat_names`), and a cache's own (`counters`: a
         `LayerGroupKVCache`'s blocks held and read by group)."""
         return {'prefill_tokens': self.prefill_tokens,
                 'prefill_padded_tokens': self.prefill_padded_tokens,
+                'moe_kernel_prefills': self.moe_kernel_prefills,
                 **{k: int(v) for k, v in zip(self.step_stat_names,
                                              self.step_stats)},
                 **getattr(self.cache, 'counters', {})}

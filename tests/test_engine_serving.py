@@ -530,6 +530,9 @@ class TestServingEngine:
         done = telemetry.events('serve_request')
         assert done and done[-1]['tokens'] == 4
         assert done[-1]['ttft_s'] is not None
+        # a model with no routed layers: the counter is there, at 0
+        assert eng._prefills == 1
+        assert eng.counts()['moe_kernel_prefills'] == 0
 
     @pytest.mark.parametrize('exec_tier', [False, True])
     def test_warmup_builds_every_declared_module_up_front(

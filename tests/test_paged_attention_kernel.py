@@ -326,6 +326,34 @@ def test_mosaic_compiles_the_window_flash_kernel_at_the_routed_widths(
                for line in text.splitlines())
 
 
+@pytest.mark.parametrize('rows', [6144, 24576, 73728])
+def test_mosaic_compiles_the_grouped_matmul_at_the_routed_widths(one_chip,
+                                                                 rows):
+    """smallthinker_21b_serve's prefill: six experts a position of the
+    shortest, a middle and the longest bucket, sorted, against 64
+    experts of 2560 x 768 (gate and up in one pass) and 768 x 2560
+    (down), bfloat16, at the entry points' tile height; a whole expert
+    in one block needs more than Mosaic's default 16 MiB of VMEM."""
+    import importlib
+    gm = importlib.import_module('paddle_tpu.ops.grouped_matmul')
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    tm = gm.TILE_ROWS
+    wide, sizes = sd((64, 2560, 768), 'bfloat16'), sd((64,), 'int32')
+    step = jax.jit(lambda x, wg, wu, wd, sizes: gm._grouped(
+        gm._grouped(x, (wg, wu), sizes, tm=tm, dtype=jnp.bfloat16),
+        (wd,), sizes, tm=tm, dtype=jnp.float32))
+    text = _uncached(lambda: step.lower(
+        sd((rows, 2560), 'bfloat16'), wide, wide,
+        sd((64, 768, 2560), 'bfloat16'), sizes).compile()).as_text()
+    for name in ('grouped_gate_up', 'grouped_matmul'):
+        assert any('tpu_custom_call' in line and name in line
+                   for line in text.splitlines()), name
+
+
 # -- the GPT serving modules for the same described chip ---------------------
 def _results_of(text, shape, ops):
     """The instructions of an HLO text whose operation is one of `ops`

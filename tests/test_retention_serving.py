@@ -134,6 +134,7 @@ def test_prefill_then_decode_is_the_references_forward():
     assert report['state_rows_updated'] \
         == sum(new_tokens) - len(new_tokens)
     assert report['state_kernel'] is False              # a CPU
+    assert report['moe_kernel_prefills'] == 0           # no routed layer
     assert report['state_bytes'] == eng.cache.state_bytes
     assert report['state_bytes_per_row'] * 4 == report['state_bytes']
     assert 0 < report['state_rows_updated'] \

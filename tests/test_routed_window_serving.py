@@ -1,8 +1,10 @@
 """The routed-expert decoder with window and full layers (PR 33) at a
 small size on the CPU: `forward` and prefill-then-decode through
 `ServingEngine` against the plain float32 reference by logits, the
-routed layer against a per-token loop, the two-group allocator under
-churn, and which MoE the engine serves."""
+routed layer against a per-token loop (its grouped product through
+`ragged_dot` and, in interpret mode at widths its gate takes, through
+the Pallas kernel of PR 34), a prefill's pad positions, the two-group
+allocator under churn, and which MoE the engine serves."""
 import functools
 
 import numpy as np
@@ -12,6 +14,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.models import routed_window as rw
+from paddle_tpu.ops import _gating
 from paddle_tpu.serving import ServeConfig, ServingEngine
 from paddle_tpu.serving.kv_cache import LayerGroupKVCache
 from paddle_tpu.serving.scheduler import (ContinuousBatchingScheduler,
@@ -115,49 +118,162 @@ def _loop(p, h, logits, k):
     return out
 
 
-@pytest.fixture(scope='module')
-def layer():
+def _layer(hidden, width, rows, dtype):
     rs = np.random.RandomState(0)
-    p = {'gate_proj': rs.randn(8, 64, 32) * .1,
-         'up_proj': rs.randn(8, 64, 32) * .1,
-         'down_proj': rs.randn(8, 32, 64) * .1}
-    return ({n: jnp.asarray(v, jnp.float32) for n, v in p.items()},
-            rs.randn(24, 64).astype('f4'), rs.randn(24, 8).astype('f4'))
+    p = {'gate_proj': rs.randn(8, hidden, width) * .1,
+         'up_proj': rs.randn(8, hidden, width) * .1,
+         'down_proj': rs.randn(8, width, hidden) * .1}
+    return ({n: jnp.asarray(v, dtype) for n, v in p.items()},
+            rs.randn(rows, hidden).astype('f4'),
+            rs.randn(rows, 8).astype('f4'))
 
 
-@pytest.mark.parametrize('grouped', [True, False])
-def test_routed_layer_matches_a_per_token_loop(layer, grouped):
-    p, h, logits = layer
+# how the grouped product is run: through `ragged_dot` (the tests'
+# widths, float32), through the Pallas kernel in interpret mode (a layer
+# whose widths its gate takes: 128 rows x 3 = three tiles of 128,
+# bfloat16), or the decode step's dense product
+PROGRAMS = {True: (64, 32, 24, jnp.float32, 1e-5),
+            'kernel': (128, 128, 128, jnp.bfloat16, 2e-2),
+            False: (64, 32, 24, jnp.float32, 1e-5)}
+
+
+@pytest.fixture(params=list(PROGRAMS), ids=lambda g: f'grouped={g}')
+def layer(request, monkeypatch):
+    """(parameters, rows, router logits), `grouped`, the lowered text's
+    word for the program, and the tolerance against float64."""
+    grouped = request.param
+    hidden, width, rows, dtype, tol = PROGRAMS[grouped]
+    monkeypatch.setattr(_gating, 'INTERPRET', grouped == 'kernel')
+    return (_layer(hidden, width, rows, dtype), bool(grouped),
+            {True: 'ragged_dot', 'kernel': 'grouped_gate_up',
+             False: 'dot_general'}[grouped], tol)
+
+
+def test_routed_layer_matches_a_per_token_loop(layer):
+    (p, h, logits), grouped, word, tol = layer
+    T = h.shape[0]
     with jax.default_matmul_precision('highest'):
-        out, stats = rw.routed_experts(p, jnp.asarray(h),
-                                       jnp.asarray(logits), 3,
-                                       grouped=grouped)
-    want = _loop(p, h, logits, 3)
-    assert np.abs(np.asarray(out) - want).max() <= 1e-5 * np.abs(want).max()
+        fn = jax.jit(functools.partial(rw.routed_experts, k=3,
+                                       grouped=grouped))
+        out, stats = fn(p, jnp.asarray(h), jnp.asarray(logits))
+    # the gate sent the shapes where the fixture says (the traced
+    # program's own words: the CPU lowers a ragged dot to plain ones)
+    text = str(jax.make_jaxpr(fn)(p, jnp.asarray(h), jnp.asarray(logits)))
+    assert word in text
+    assert ('ragged_dot' in text) == (word == 'ragged_dot')
+    assert ('pallas_call' in text) == (word == 'grouped_gate_up')
+    rounded = np.asarray(jnp.asarray(h).astype(p['gate_proj'].dtype),
+                         np.float64)
+    want = _loop(p, rounded, logits, 3)
+    assert np.abs(np.asarray(out) - want).max() <= tol * np.abs(want).max()
     load = np.bincount(np.argsort(-logits, 1, kind='stable')[:, :3].ravel(),
                        minlength=8)
-    assert list(np.asarray(stats)) == [72, (load > 0).sum(), load.max()]
+    assert list(np.asarray(stats)) == [3 * T, (load > 0).sum(), load.max()]
 
 
-@pytest.mark.parametrize('grouped', [True, False])
-def test_a_pad_row_moves_no_other_row_bitwise(layer, grouped):
-    p, h, logits = layer
+def test_a_pad_row_moves_no_other_row_bitwise(layer):
+    (p, h, logits), grouped, _, _ = layer
+    T = h.shape[0]
     fn = jax.jit(functools.partial(rw.routed_experts, k=3, grouped=grouped))
     base, _ = fn(p, jnp.asarray(h), jnp.asarray(logits))
     h2, l2 = h.copy(), logits.copy()
     h2[5], l2[5] = 100.0, -logits[5]          # another row, other experts
     moved, _ = fn(p, jnp.asarray(h2), jnp.asarray(l2))
-    keep = np.arange(24) != 5
+    keep = np.arange(T) != 5
     assert np.array_equal(np.asarray(base)[keep], np.asarray(moved)[keep])
     assert not np.array_equal(np.asarray(base)[5], np.asarray(moved)[5])
 
 
-def test_counts_follow_the_active_rows(layer):
-    p, h, logits = layer
+@pytest.mark.parametrize('layer', [True, 'kernel'], indirect=True,
+                         ids=lambda g: f'grouped={g}')
+def test_rows_that_are_not_active_are_zero_and_move_no_other_row(layer):
+    """A prefill's pad positions: routed nowhere, multiplied by
+    nothing, zero in the result; the true rows' results are the ones
+    they had with the pad rows computed, bit for bit."""
+    (p, h, logits), _, _, _ = layer
+    T = h.shape[0]
+    fn = jax.jit(functools.partial(rw.routed_experts, k=3, grouped=True))
+    base, _ = fn(p, jnp.asarray(h), jnp.asarray(logits))
+    active = np.arange(T) % 24 < 17                  # ragged true lengths
+    got, stats = fn(p, jnp.asarray(h), jnp.asarray(logits),
+                    active=jnp.asarray(active))
+    assert np.array_equal(np.asarray(got)[active], np.asarray(base)[active])
+    assert not np.asarray(got)[~active].any()
+    assert int(stats[0]) == 3 * active.sum()
+
+
+def test_counts_follow_the_active_rows():
+    p, h, logits = _layer(64, 32, 24, jnp.float32)
     active = jnp.asarray(np.arange(24) < 4)
     _, stats = rw.routed_experts(p, jnp.asarray(h), jnp.asarray(logits), 3,
                                  grouped=False, active=active)
     assert int(stats[0]) == 12 and int(stats[1]) <= 8
+
+
+# -- a prefill's pad positions, and which program its modules took ----------------------
+def _wide(monkeypatch):
+    """A model whose routed layers pass the kernel's gate in interpret
+    mode: widths of 128, bfloat16, a bucket of 128 x 3 experts = three
+    tiles of rows."""
+    monkeypatch.setattr(_gating, 'INTERPRET', True)
+    paddle.seed(1)
+    return rw.routed_window_tiny(hidden_size=128, intermediate_size=128,
+                                 num_layers=2, window_layout=(0, 1),
+                                 rope_layout=(0, 1), max_seq_len=256,
+                                 dtype='bfloat16')
+
+
+@pytest.mark.parametrize('program', ['ragged_dot', 'kernel'])
+def test_a_prefill_short_of_its_bucket_returns_what_it_did_with_the_pad_rows(
+        tiny, monkeypatch, program):
+    """Logits at the last true position and every true position's keys
+    and values are the numbers the prefill returned when the routed
+    layers computed the pad positions too."""
+    from paddle_tpu.serving.kv_cache import PrefillKV
+    model = tiny[0] if program == 'ragged_dot' else _wide(monkeypatch)
+    assert model.prefill_path(2, 128) == program
+    params, _ = model.functional_state()
+    ids = jnp.asarray(np.random.default_rng(2).integers(0, 128, (2, 128)))
+    lengths = jnp.asarray([77, 128], jnp.int32)
+
+    def run():
+        caches = [PrefillKV(lengths=lengths)
+                  for _ in range(model.config.num_layers)]
+        logits, views = jax.jit(model.prefill)(params, None, ids, 0, caches)
+        return (np.asarray(logits),
+                [(np.asarray(v.k), np.asarray(v.v)) for v in views])
+
+    logits, kv = run()
+    every_row = rw.routed_experts
+    monkeypatch.setattr(
+        rw, 'routed_experts', lambda *a, active=None, **kw: every_row(
+            *a, active=None, **kw))
+    want_logits, want_kv = run()
+    assert np.array_equal(logits, want_logits)
+    true = np.arange(128)[None, :] < np.asarray(lengths)[:, None]
+    moved = False
+    for (k, v), (wk, wv) in zip(kv, want_kv):
+        assert np.array_equal(k[true], wk[true])
+        assert np.array_equal(v[true], wv[true])
+        moved |= not np.array_equal(k[~true], wk[~true])
+    assert moved          # the pad positions' own rows did change
+
+
+@pytest.mark.parametrize('program', ['ragged_dot', 'kernel'])
+def test_the_engine_counts_the_prefills_that_took_the_kernel(
+        tiny, monkeypatch, program):
+    model = tiny[0] if program == 'ragged_dot' else _wide(monkeypatch)
+    eng = ServingEngine(model, ServeConfig(**{
+        **SERVE, 'prompt_buckets': (128,), 'max_model_len': 160,
+        'num_blocks': 90}))
+    assert eng.counts()['moe_kernel_prefills'] == 0
+    rng = np.random.default_rng(0)
+    reqs = [Request(f'r{i}', rng.integers(0, 128, n), 4, arrival_t=0.0)
+            for i, n in enumerate([100, 128, 9])]
+    report = eng.run(reqs)
+    assert report['audit'] == [] and eng._prefills == 3
+    assert report['moe_kernel_prefills'] == eng.counts()[
+        'moe_kernel_prefills'] == (3 if program == 'kernel' else 0)
 
 
 # -- the two-group allocator ----------------------------------------------------------
