@@ -75,16 +75,19 @@ def gated_mlp(p, x):
 def project_heads(p, x, positions, *, num_heads, num_kv_heads, head_dim,
                   eps, theta):
     """x [B, T, h] -> q [B,T,Hq,d] and k [B,T,Hkv,d] (normed over the
-    head dimension with their own weights, then rotated) and
-    v [B,T,Hkv,d], all float32."""
+    head dimension with their own weights, then rotated where
+    `positions` is given: a layer that carries no positions hands
+    None) and v [B,T,Hkv,d], all float32."""
     B, T, _ = x.shape
     q = matmul(x, p['q_proj.weight']).reshape(B, T, num_heads, head_dim)
     k = matmul(x, p['k_proj.weight']).reshape(B, T, num_kv_heads,
                                               head_dim)
     v = matmul(x, p['v_proj.weight']).reshape(B, T, num_kv_heads,
                                               head_dim)
-    q = rotary(rms_norm(q, p['q_norm.weight'], eps), positions, theta)
-    k = rotary(rms_norm(k, p['k_norm.weight'], eps), positions, theta)
+    q = rms_norm(q, p['q_norm.weight'], eps)
+    k = rms_norm(k, p['k_norm.weight'], eps)
+    if positions is not None:
+        q, k = rotary(q, positions, theta), rotary(k, positions, theta)
     return q, k, v
 
 

@@ -51,6 +51,14 @@ hit 95% of 64 experts) take a dense product over all experts, weighted
 by a [rows, experts] matrix that is zero off the chosen ones: bound by
 the same bytes, the experts' weights, and no sort in the token step.
 
+What another routed decoder shares (`models/afmoe.py` does): the
+expert product takes the CHOICE from its caller, `chosen_experts(p,
+h2, top_i, w, activation=...)`, so scoring (softmax of the largest
+logits here; sigmoid scores, a bias and a scale there) and the gate's
+activation are the model's own; `routed_experts` is this model's
+composition of the two.  The three ways of attention (`attend`,
+`attend_whole`) are functions of this module for the same reason.
+
 The cache: `serving_state = 'paged'` with `cache_spec()`, from which
 the engine builds a `LayerGroupKVCache` (`serving/kv_cache.py`): full
 and window layers hold their own pools and tables, keys stored as
@@ -69,7 +77,8 @@ from ..ops.paged_attention import paged_attention, write_kv
 from .decoder_parts import Dense, RMSNorm, matmul, rms_norm, rotary, sub
 
 __all__ = ['RoutedWindowConfig', 'RoutedWindowForCausalLM',
-           'routed_window_tiny', 'routed_experts', 'router_logits']
+           'routed_window_tiny', 'routed_experts', 'chosen_experts',
+           'top_k_softmax', 'router_logits', 'attend', 'attend_whole']
 
 F32 = jnp.float32
 STEP_STATS = ('moe_assignments', 'moe_experts_hit', 'moe_max_load')
@@ -128,22 +137,51 @@ def _gated(g, u, dtype):
     return (jax.nn.relu(g) * u).astype(dtype)
 
 
+def _gated_silu(g, u, dtype):
+    """silu(gate) * up, rounded for the down projection."""
+    return (jax.nn.silu(g) * u).astype(dtype)
+
+
+def _epilogue(activation):
+    """The gated pair's epilogue by the name the Pallas kernel takes
+    (`gm.ACTIVATIONS`), found through the module at each call."""
+    return {'relu': _gated, 'silu': _gated_silu}[activation]
+
+
+def top_k_softmax(logits, k):
+    """The k largest logits a row, and softmax over those k in
+    float32: `(top_i [T, k], w [T, k])`."""
+    top_v, top_i = jax.lax.top_k(logits, k)
+    return top_i, jax.nn.softmax(top_v.astype(F32), axis=-1)
+
+
 def routed_experts(p, h2, logits, k, *, grouped, active=None):
-    """The routed layer's output [T, hidden] float32 for rows
-    `h2 [T, hidden]` routed by `logits [T, experts]` (the router's, of
-    the layer's normed INPUT), and its counts: assignments made,
+    """This model's routed layer: the k largest of `logits [T,
+    experts]` (the router's, of the layer's normed INPUT), softmax
+    over them, ReLU-gated experts (`chosen_experts`)."""
+    with jax.named_scope('moe.dispatch'):
+        top_i, w = top_k_softmax(logits, k)
+    return chosen_experts(p, h2, top_i, w, activation='relu',
+                          grouped=grouped, active=active)
+
+
+def chosen_experts(p, h2, top_i, w, *, activation, grouped, active=None):
+    """sum_j w[:, j] expert top_i[:, j] of rows `h2 [T, hidden]`,
+    [T, hidden] float32, and the layer's counts: assignments made,
     distinct experts hit and the largest expert's load, over the rows
     `active` marks (all of them where it is None).  A grouped product
     computes the active rows only; the others' output is zero.
 
     `p` holds `gate_proj`, `up_proj` [experts, hidden, width] and
-    `down_proj` [experts, width, hidden].  `grouped` picks the program
-    (this file's header), never the mathematics."""
-    T, E = logits.shape
+    `down_proj` [experts, width, hidden]; an expert is
+    down(act(gate x) * (up x)) with `activation` naming act ('relu',
+    'silu').  `grouped` picks the program (this file's header), never
+    the mathematics."""
+    T, k = top_i.shape
     wg, wu, wd = p['gate_proj'], p['up_proj'], p['down_proj']
+    E = wg.shape[0]
+    gated = _epilogue(activation)
     with jax.named_scope('moe.dispatch'):
-        top_v, top_i = jax.lax.top_k(logits, k)
-        w = jax.nn.softmax(top_v.astype(F32), axis=-1)        # [T, k]
         hit = jnp.zeros((T, E), jnp.int32).at[
             jnp.arange(T)[:, None], top_i].set(1)
         if active is not None:
@@ -152,7 +190,7 @@ def routed_experts(p, h2, logits, k, *, grouped, active=None):
         stats = jnp.stack([load.sum(), (load > 0).sum(), load.max()])
     x = h2.astype(wg.dtype)
     if grouped:
-        return _grouped(x, top_i, w, wg, wu, wd, k, active), stats
+        return _grouped(x, top_i, w, wg, wu, wd, active, activation), stats
     with jax.named_scope('moe.dispatch'):
         mix = jnp.zeros((T, E), F32).at[
             jnp.arange(T)[:, None], top_i].set(w)
@@ -161,7 +199,7 @@ def routed_experts(p, h2, logits, k, *, grouped, active=None):
         # the two products into a bfloat16 dot its runtime lacks)
         g = jnp.matmul(x[None], wg, preferred_element_type=F32)
         u = jnp.matmul(x[None], wu, preferred_element_type=F32)
-        y = jnp.einsum('etf,efh->eth', _gated(g, u, wd.dtype), wd,
+        y = jnp.einsum('etf,efh->eth', gated(g, u, wd.dtype), wd,
                        preferred_element_type=F32)
     with jax.named_scope('moe.dispatch'):
         out = jnp.einsum('te,eth->th', mix, y,
@@ -176,12 +214,12 @@ def grouped_path(rows, wg, wd):
         and gm.can_use_pallas(rows, wd) else 'ragged_dot'
 
 
-def _grouped(x, top_i, w, wg, wu, wd, k, active):
+def _grouped(x, top_i, w, wg, wu, wd, active, activation):
     """The expert product of rows x [T, hidden] (in the weights'
     dtype) routed to `top_i` [T, k] with weights `w` [T, k]: rows
     sorted by expert, each expert's rows against its matrices once;
     rows that are not `active` behind the last expert, in no group."""
-    T, E = x.shape[0], wg.shape[0]
+    (T, k), E = top_i.shape, wg.shape[0]
     with jax.named_scope('moe.dispatch'):
         if active is not None:
             top_i = jnp.where(active[:, None], top_i, E)
@@ -192,15 +230,15 @@ def _grouped(x, top_i, w, wg, wu, wd, k, active):
     with jax.named_scope('moe.experts'):
         if grouped_path(T * k, wg, wd) == 'kernel':
             y = gm.grouped_matmul(
-                gm.grouped_gate_up(rows, wg, wu, sizes, wd.dtype), wd,
-                sizes)
+                gm.grouped_gate_up(rows, wg, wu, sizes, wd.dtype,
+                                   activation), wd, sizes)
         else:
             g = jax.lax.ragged_dot(rows, wg, sizes,
                                    preferred_element_type=F32)
             u = jax.lax.ragged_dot(rows, wu, sizes,
                                    preferred_element_type=F32)
-            y = jax.lax.ragged_dot(_gated(g, u, wd.dtype), wd, sizes,
-                                   preferred_element_type=F32)
+            y = jax.lax.ragged_dot(_epilogue(activation)(g, u, wd.dtype),
+                                   wd, sizes, preferred_element_type=F32)
     with jax.named_scope('moe.dispatch'):
         # back to the tokens' own order, then each token's k in the
         # order its router chose them: a row's sum does not depend on
@@ -226,6 +264,48 @@ def plain_heads(p, x, positions, *, num_heads, num_kv_heads, head_dim,
     if positions is not None:
         q, k = rotary(q, positions, theta), rotary(k, positions, theta)
     return q, k, v
+
+
+# -- attention, three ways ----------------------------------------------------------
+def attend_whole(q, k, v, window, dtype):
+    """Whole sequences [B, T, heads, d] through the flash kernel (its
+    reference off the chip): causal, banded in a window layer.  q's
+    rows go batch, key/value head, head of the group, which is the
+    order the kernel's index map groups them by.  The operands are
+    rounded to `dtype`, the weights', on the way in, as every matmul's
+    are (`decoder_parts.matmul`): the MXU takes one bfloat16 pass over
+    float32 operands anyway, and half the bytes move.  Tiles of (512,
+    1024) where the length allows: the largest that PR 32's sweep
+    found fastest at 2,048."""
+    B, T, H, d = q.shape
+    dtype = jnp.dtype(dtype)
+
+    def rows(x):
+        return jnp.swapaxes(x, 1, 2).reshape(-1, T, d).astype(dtype)
+
+    blocks = dict(block_q=512, block_k=1024) if T % 1024 == 0 else {}
+    y = flash_attention(rows(q), rows(k), rows(v), causal=True,
+                        window=window, **blocks)
+    return jnp.swapaxes(y.reshape(B, H, T, d), 1, 2).astype(F32)
+
+
+def attend(q, k, v, view, window, dtype):
+    """(attention output [B, T, heads, d] float32, the layer's view
+    with what it wrote) for `view` None (`forward`), a `PrefillKV` (a
+    prefill: whole sequences, the keys and values handed back) or a
+    paged view (a decode step: one token a row through the pools)."""
+    B, T = q.shape[:2]
+    if view is None:                                  # forward()
+        return attend_whole(q, k, v, window, dtype), None
+    if not getattr(view, 'paged', False):             # prefill
+        return attend_whole(q, k, v, window, dtype), view.updated(
+            k.reshape(B, T, -1), v.reshape(B, T, -1))
+    # one token a row, through the paged pools
+    kp, vp = write_kv(view.k_pool, view.v_pool, k.reshape(B, -1),
+                      v.reshape(B, -1), view.block_table, view.slots)
+    y = paged_attention(q[:, 0], kp, vp, view.block_table, view.lens,
+                        view.first)
+    return y[:, None], view.updated(kp, vp)
 
 
 # -- the Layers that own the parameters -------------------------------------------
@@ -327,49 +407,56 @@ class RoutedWindowForCausalLM(nn.Layer):
                 'num_kv_heads': cfg.num_kv_heads,
                 'head_dim': cfg.head_dim}
 
-    # -- attention, three ways ------------------------------------------------
-    def _attend_whole(self, q, k, v, window):
-        """Whole sequences [B, T, heads, d] through the flash kernel
-        (its reference off the chip): causal, banded in a window
-        layer.  q's rows go batch, key/value head, head of the group,
-        which is the order the kernel's index map groups them by.  The
-        operands are rounded to the weights' dtype on the way in, as
-        every matmul's are (`decoder_parts.matmul`): the MXU takes one
-        bfloat16 pass over float32 operands anyway, and half the bytes
-        move.  Tiles of (512, 1024) where the length allows: the
-        largest that PR 32's sweep found fastest at 2,048."""
-        B, T, H, d = q.shape
-        dtype = jnp.dtype(self.config.dtype)
-
-        def rows(x):
-            return jnp.swapaxes(x, 1, 2).reshape(-1, T, d).astype(dtype)
-
-        blocks = dict(block_q=512, block_k=1024) if T % 1024 == 0 else {}
-        y = flash_attention(rows(q), rows(k), rows(v), causal=True,
-                            window=window, **blocks)
-        return jnp.swapaxes(y.reshape(B, H, T, d), 1, 2).astype(F32)
-
-    def _attend(self, q, k, v, view, window):
-        B, T = q.shape[:2]
-        if view is None:                                  # forward()
-            return self._attend_whole(q, k, v, window), None
-        if not getattr(view, 'paged', False):             # prefill
-            return self._attend_whole(q, k, v, window), view.updated(
-                k.reshape(B, T, -1), v.reshape(B, T, -1))
-        # one token a row, through the paged pools
-        kp, vp = write_kv(view.k_pool, view.v_pool, k.reshape(B, -1),
-                          v.reshape(B, -1), view.block_table, view.slots)
-        y = paged_attention(q[:, 0], kp, vp, view.block_table, view.lens,
-                            view.first)
-        return y[:, None], view.updated(kp, vp)
-
     # -- the one forward ------------------------------------------------------
+    def _embed(self, params, ids):
+        """Token ids -> the residual stream before layer 0, float32."""
+        return params['model.embed.weight'][ids].astype(F32)
+
+    def _block(self, p, i, x, positions, view, decoding, true_rows):
+        """Layer `i` with parameters `p` over x [B, T, hidden]:
+        (x, the layer's view with what it wrote).  `true_rows` [B T]
+        marks a prefill's true positions (None: all)."""
+        cfg = self.config
+        B, T, _ = x.shape
+        with jax.named_scope('dec.norm'):
+            h = rms_norm(x, p['input_norm.weight'], cfg.rms_norm_eps)
+        with jax.named_scope('moe.router'):
+            logits = router_logits(h.reshape(B * T, -1),
+                                   p['router.weight'])
+        with jax.named_scope('dec.attn'):
+            a = sub(p, 'attn.')
+            q, k, v = plain_heads(
+                a, h, positions if cfg.rope_layout[i] else None,
+                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.head_dim, theta=cfg.rope_theta)
+            y, view = attend(
+                q, k, v, view,
+                cfg.window if cfg.window_layout[i] else None, cfg.dtype)
+            attended = y.reshape(B, T, -1)
+            x = x + matmul(attended, a['o_proj.weight'])
+        with jax.named_scope('dec.norm'):
+            h = rms_norm(x, p['post_norm.weight'], cfg.rms_norm_eps)
+        with jax.named_scope('dec.moe'):
+            y, stats = routed_experts(
+                sub(p, 'experts.'), h.reshape(B * T, -1), logits,
+                cfg.experts_per_token, grouped=not decoding,
+                active=view.active if decoding else true_rows)
+            x = x + y.reshape(B, T, -1)
+        if decoding:
+            # the counts, and what this layer computed a row (T is
+            # 1): the cache hands its tapped layers' out of the
+            # decode module, the rest is never materialised
+            view = view.updated(
+                view.k_pool, view.v_pool, stats,
+                {'router': logits, 'attn': attended[:, 0], 'moe': y})
+        return x, view
+
     def _run(self, params, ids, positions, views, lengths, last_only):
+        """Embedding, every layer's `_block`, the final norm and the
+        head; a decoder of another block overrides `_embed` and
+        `_block`."""
         cfg = self.config
         B, T = ids.shape
-        heads = dict(num_heads=cfg.num_heads,
-                     num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
-                     theta=cfg.rope_theta)
         decoding = views is not None and getattr(views[0], 'paged', False)
         # a prefill's true positions: the bucket's pad rows on the right
         # are routed nowhere
@@ -377,41 +464,12 @@ class RoutedWindowForCausalLM(nn.Layer):
             jnp.arange(T, dtype=jnp.int32)[None, :]
             < lengths[:, None].astype(jnp.int32)).reshape(B * T)
         with jax.named_scope('dec.embed'):
-            x = params['model.embed.weight'][ids].astype(F32)
+            x = self._embed(params, ids)
         new_views = []
         for i in range(cfg.num_layers):
-            p = sub(params, f'model.layers.{i}.')
-            view = None if views is None else views[i]
-            with jax.named_scope('dec.norm'):
-                h = rms_norm(x, p['input_norm.weight'], cfg.rms_norm_eps)
-            with jax.named_scope('moe.router'):
-                logits = router_logits(h.reshape(B * T, -1),
-                                       p['router.weight'])
-            with jax.named_scope('dec.attn'):
-                a = sub(p, 'attn.')
-                q, k, v = plain_heads(
-                    a, h, positions if cfg.rope_layout[i] else None,
-                    **heads)
-                y, view = self._attend(
-                    q, k, v, view,
-                    cfg.window if cfg.window_layout[i] else None)
-                attended = y.reshape(B, T, -1)
-                x = x + matmul(attended, a['o_proj.weight'])
-            with jax.named_scope('dec.norm'):
-                h = rms_norm(x, p['post_norm.weight'], cfg.rms_norm_eps)
-            with jax.named_scope('dec.moe'):
-                y, stats = routed_experts(
-                    sub(p, 'experts.'), h.reshape(B * T, -1), logits,
-                    cfg.experts_per_token, grouped=not decoding,
-                    active=view.active if decoding else true_rows)
-                x = x + y.reshape(B, T, -1)
-            if decoding:
-                # the counts, and what this layer computed a row (T is
-                # 1): the cache hands its tapped layers' out of the
-                # decode module, the rest is never materialised
-                view = view.updated(
-                    view.k_pool, view.v_pool, stats,
-                    {'router': logits, 'attn': attended[:, 0], 'moe': y})
+            x, view = self._block(
+                sub(params, f'model.layers.{i}.'), i, x, positions,
+                None if views is None else views[i], decoding, true_rows)
             new_views.append(view)
         if last_only:
             x = jnp.take_along_axis(

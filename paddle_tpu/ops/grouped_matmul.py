@@ -7,9 +7,10 @@ own matrix (a routed layer's prefill: groups are experts).
 `rows [M, K]` and `w [E, K, N]` in bfloat16, `sizes [E]` int32 with
 `sizes.sum() <= M`, float32 accumulation.  Two entry points, one kernel
 body: `grouped_matmul` returns `[M, N]` float32; `grouped_gate_up` reads
-a row tile once against TWO matrices and returns `relu(rows @ wg[g]) *
+a row tile once against TWO matrices and returns `act(rows @ wg[g]) *
 (rows @ wu[g])` rounded once to `dtype`, so the two float32 products
-never reach HBM.
+never reach HBM; `act` is the caller's (`ACTIVATIONS`: a ReGLU or a
+SwiGLU expert), a static argument of the one kernel.
 
 **Visits.**  The grid is one list of visits, a (group, row tile) pair
 each, in the rows' order: a group of `n` rows starting at `s` visits the
@@ -49,7 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import _gating
 
-__all__ = ['can_use_pallas', 'TILE_ROWS', 'group_metadata',
+__all__ = ['can_use_pallas', 'TILE_ROWS', 'ACTIVATIONS', 'group_metadata',
            'grouped_matmul', 'grouped_gate_up']
 
 F32 = jnp.float32
@@ -57,6 +58,10 @@ F32 = jnp.float32
 # compiler's default scope is 16): the gate refuses a larger matrix
 VMEM_BUDGET = 48 << 20
 TILE_ROWS = 128
+# what a gated pair's epilogue may apply to the gate's product, as
+# the kernel's body writes it
+ACTIVATIONS = {'relu': lambda x: jnp.maximum(x, 0.0),
+               'silu': lambda x: x * jax.nn.sigmoid(x)}
 
 
 def _vmem_bytes(tm, k, n, weights, out_bytes):
@@ -113,7 +118,7 @@ def group_metadata(sizes, m, tm):
             real[None])
 
 
-def _kernel(offsets, group, tile, src, real, x_ref, *refs, tm, gated):
+def _kernel(offsets, group, tile, src, real, x_ref, *refs, tm, activation):
     """One grid step: a visit (its group's rows of the tile stored),
     a tile no group reaches (zeros), or nothing."""
     del src                                  # the index maps read it
@@ -130,7 +135,8 @@ def _kernel(offsets, group, tile, src, real, x_ref, *refs, tm, gated):
         # the caller's jax_default_matmul_precision asks of others
         acc = [jnp.dot(x, w[...], precision=jax.lax.Precision.DEFAULT,
                        preferred_element_type=F32) for w in w_refs]
-        y = jnp.maximum(acc[0], 0.0) * acc[1] if gated else acc[0]
+        y = ACTIVATIONS[activation](acc[0]) * acc[1] if activation \
+            else acc[0]
         g = group[i]
         row = t * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
         mine = jnp.logical_and(row >= offsets[g], row < offsets[g + 1])
@@ -144,8 +150,12 @@ def _kernel(offsets, group, tile, src, real, x_ref, *refs, tm, gated):
         o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=('tm', 'dtype', 'interpret'))
-def _grouped(rows, ws, sizes, *, tm, dtype, interpret=False):
+@functools.partial(jax.jit, static_argnames=('tm', 'dtype', 'activation',
+                                             'interpret'))
+def _grouped(rows, ws, sizes, *, tm, dtype, activation='relu',
+             interpret=False):
+    """One matrix a group: the product.  Two: `activation` of the
+    first's product times the second's."""
     m, k = rows.shape
     E, _, n = ws[0].shape
     meta = group_metadata(sizes, m, tm)
@@ -161,7 +171,8 @@ def _grouped(rows, ws, sizes, *, tm, dtype, interpret=False):
         return (tile[i], 0)
 
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, gated=len(ws) == 2),
+        functools.partial(_kernel, tm=tm,
+                          activation=activation if len(ws) == 2 else None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(meta),
             grid=(m // tm + E - 1,),
@@ -175,7 +186,9 @@ def _grouped(rows, ws, sizes, *, tm, dtype, interpret=False):
             vmem_limit_bytes=_vmem_bytes(tm, k, n, len(ws), out_bytes)
             + (16 << 20)),
         cost_estimate=pl.CostEstimate(
-            flops=2 * m * k * n * len(ws), transcendentals=0,
+            flops=2 * m * k * n * len(ws),
+            transcendentals=m * n if len(ws) == 2
+            and activation == 'silu' else 0,
             bytes_accessed=m * k * 2 + len(ws) * E * k * n * 2
             + m * n * out_bytes),
         interpret=interpret,
@@ -190,8 +203,10 @@ def grouped_matmul(rows, w, sizes):
                     interpret=_gating.INTERPRET)
 
 
-def grouped_gate_up(rows, wg, wu, sizes, dtype):
-    """`relu(rows @ wg[g]) * (rows @ wu[g]) -> [M, N]` in `dtype`, by
-    group, the two float32 products multiplied and rounded once."""
+def grouped_gate_up(rows, wg, wu, sizes, dtype, activation='relu'):
+    """`act(rows @ wg[g]) * (rows @ wu[g]) -> [M, N]` in `dtype`, by
+    group, the two float32 products multiplied and rounded once;
+    `activation` names `act` (`ACTIVATIONS`)."""
     return _grouped(rows, (wg, wu), sizes, tm=TILE_ROWS,
-                    dtype=jnp.dtype(dtype), interpret=_gating.INTERPRET)
+                    dtype=jnp.dtype(dtype), activation=activation,
+                    interpret=_gating.INTERPRET)

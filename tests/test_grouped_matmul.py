@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.models.routed_window import _gated
+from paddle_tpu.models.routed_window import _gated, _gated_silu
 from paddle_tpu.ops import _gating
 from paddle_tpu.ops import grouped_matmul as gm
 
@@ -75,6 +75,42 @@ def test_gate_and_up_in_one_pass_is_gated_of_two_calls_bitwise(
     assert fused.dtype == jnp.bfloat16
     assert np.array_equal(np.asarray(fused, np.float32),
                           np.asarray(two, np.float32))
+
+
+def test_silu_at_128_groups_of_fewer_rows_than_a_tile():
+    """The routed decoder with 128 SwiGLU experts (PR 35): a prompt of
+    1,024 at 8 experts a token gives an expert some 64 rows, half a
+    tile, so nearly every tile is shared by two or three groups.  The
+    one pass with `activation='silu'` against `jax.lax.ragged_dot` and
+    XLA's epilogue, and bit for bit against two plain calls."""
+    rs = np.random.RandomState(1)
+    m, k, n, e = 2048, 128, 128, 128
+    sizes = rs.randint(0, 32, e)
+    sizes[5], sizes[77] = 0, 127
+    assert sizes.sum() <= m and (sizes < gm.TILE_ROWS).all()
+    rows = jnp.asarray(rs.randn(m, k), jnp.bfloat16)
+    wg, wu = (jnp.asarray(rs.randn(e, k, n) * .1, jnp.bfloat16)
+              for _ in range(2))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    fused = gm._grouped(rows, (wg, wu), sizes, tm=gm.TILE_ROWS,
+                        dtype=jnp.bfloat16, activation='silu',
+                        interpret=True)
+    two = _gated_silu(_plain(rows, wg, sizes, gm.TILE_ROWS),
+                      _plain(rows, wu, sizes, gm.TILE_ROWS), jnp.bfloat16)
+    assert np.array_equal(np.asarray(fused, np.float32),
+                          np.asarray(two, np.float32))
+    relu = gm._grouped(rows, (wg, wu), sizes, tm=gm.TILE_ROWS,
+                       dtype=jnp.bfloat16, interpret=True)
+    assert not np.array_equal(np.asarray(fused, np.float32),
+                              np.asarray(relu, np.float32))
+    held = int(sizes.sum())
+    want = _gated_silu(*(jax.lax.ragged_dot(
+        rows, w, sizes, preferred_element_type=jnp.float32)
+        for w in (wg, wu)), jnp.bfloat16)
+    np.testing.assert_allclose(
+        np.asarray(fused, np.float32)[:held],
+        np.asarray(want, np.float32)[:held], rtol=2e-2, atol=1e-3)
+    assert not np.asarray(fused, np.float32)[held:].any()
 
 
 @pytest.mark.parametrize('tm', [128, 256])

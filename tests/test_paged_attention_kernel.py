@@ -354,6 +354,56 @@ def test_mosaic_compiles_the_grouped_matmul_at_the_routed_widths(one_chip,
                    for line in text.splitlines()), name
 
 
+def test_mosaic_compiles_the_kernels_at_the_shared_expert_decoders_widths(
+        one_chip):
+    """trinity_mini_serve (PR 35), compiled, not run.  Its decode: 32
+    query heads on 4 key/value heads of 128, 64 rows, tables of 384,
+    the window group's pool of 8,321 blocks.  Its prefill: the longest
+    bucket, 2,048 positions, through the window flash kernel at a
+    window of 2,048 and tiles of (512, 1024), and the grouped expert
+    product of 8 experts a position against 128 experts of 2048 x
+    1024 with SiLU in the epilogue and 1024 x 2048 back (20 and 13 MB
+    of the gate's 48 MB of VMEM); the shortest bucket, 256 positions,
+    gives an expert 16 rows."""
+    import importlib
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    gm = importlib.import_module('paddle_tpu.ops.grouped_matmul')
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    pool, tables = sd((8321, 16, 512), 'float32'), sd((64, 384), 'int32')
+    chunk = pa._blocks_a_round_grouped(pool, tables)
+    text = _uncached(lambda: pa._paged_decode_grouped.lower(
+        sd((64, 32, 128), 'float32'), pool, pool, tables,
+        sd((64,), 'int32'), sd((64,), 'int32'),
+        chunk=chunk).compile()).as_text()
+    assert 'tpu_custom_call' in text and 'paged_decode_grouped' in text
+
+    q, kv = sd((32, 2048, 128), 'bfloat16'), sd((4, 2048, 128), 'bfloat16')
+    step = jax.jit(lambda q, k, v: fa._flash(
+        q, k, v, True, 128 ** -0.5, 512, 1024, 2048))
+    text = _uncached(lambda: step.lower(q, kv, kv).compile()).as_text()
+    assert any('tpu_custom_call' in line and 'flash_fwd_window' in line
+               for line in text.splitlines())
+
+    tm = gm.TILE_ROWS
+    wide, sizes = sd((128, 2048, 1024), 'bfloat16'), sd((128,), 'int32')
+    assert gm._vmem_bytes(tm, 2048, 1024, 2, 2) < gm.VMEM_BUDGET / 2
+    step = jax.jit(lambda x, wg, wu, wd, sizes: gm._grouped(
+        gm._grouped(x, (wg, wu), sizes, tm=tm, dtype=jnp.bfloat16,
+                    activation='silu'),
+        (wd,), sizes, tm=tm, dtype=jnp.float32))
+    for rows in (256 * 8, 2048 * 8):
+        text = _uncached(lambda: step.lower(
+            sd((rows, 2048), 'bfloat16'), wide, wide,
+            sd((128, 1024, 2048), 'bfloat16'), sizes).compile()).as_text()
+        for name in ('grouped_gate_up', 'grouped_matmul'):
+            assert any('tpu_custom_call' in line and name in line
+                       for line in text.splitlines()), (rows, name)
+
+
 # -- the GPT serving modules for the same described chip ---------------------
 def _results_of(text, shape, ops):
     """The instructions of an HLO text whose operation is one of `ops`
