@@ -22,6 +22,36 @@ Ties the whole PR-7..11 runway into live decode throughput:
   the dispatches had to read (``kv_read_share``);
 - **continuous batching** (``scheduler.py``): admit/evict at every
   intervention, prefill into freed blocks, immediate backfill;
+- **a decode dispatch in flight**: the span of intervention N+1 is
+  planned by counts and sent while N is still running on the device,
+  and the host reads, absorbs and books N behind it.  An intervention
+  is ``deadlines → admit → prefill_dispatch → reserve → plan →
+  decode_dispatch`` (N+1) ``→ decode_sync`` (N) ``→ absorb`` (N) ``→
+  first_token_sync`` (of the prefills just sent) ``→ bookkeeping``.
+  A row's last token stays on the device (``_last``, one entry a
+  request's ``slot``): the prefill modules write their first tokens
+  into it, the decode modules read a row's input token from it and
+  write the span's last one back, and the host reads tokens only to
+  deliver them, never to schedule.  The scheduler plans from ``ctx +
+  dispatched`` (``scheduler.py``'s docstring): a row that ends by
+  ``max_new_tokens`` is known to end in N before N has run, so its
+  slot and blocks go to the next admission when N+1 is planned, and
+  occupancy does not fall; the device runs what it is sent in order,
+  so a block freed on the host while N is in flight is never
+  rewritten before N has read it.  A row that ends on ``eos_id``
+  cannot be foreseen: the token it left on the device silences it in
+  N+1, the host learns of it when it absorbs N, and its slot and
+  blocks come back ONE SPAN LATE.  A caller who steps the engine by
+  hand reads a span's tokens one ``step()`` after it was sent;
+  ``drain()`` reads what is in flight without sending more (``run()``
+  at its timeout and at its end, ``cancel()`` and ``warmup()`` call
+  it).  Where requests may still arrive, ``run()`` and
+  ``ServingFrontend`` take the next intervention only when the span
+  in flight is about to end (``wait_s``: the engine's own measured
+  lengths, no option), so an arrival waits no longer than when each
+  span was planned after the one before it had been read;
+  ``counts()['decode_dispatches_ahead']`` and the ``ahead`` field of
+  ``serve_step`` say how often a dispatch was sent ahead;
 - **fused multi-step decode**: ``decode_span=K`` scans K decode steps
   inside one compiled module between scheduler interventions — the
   ROADMAP item-4 remainder lifted to the decode loop;
@@ -53,8 +83,10 @@ The decode math runs through the SAME ``GPTForCausalLM.prefill`` /
 the reference path greedy engine output is bit-exact with sequential
 batch-1 generate — pinned by tests/test_engine_serving.py.
 """
+import collections
 import json
 import math
+import statistics
 import time
 import zlib
 
@@ -273,6 +305,26 @@ class ServingEngine:
         self.compile_count = 0
         self.interventions = 0
         self.decoded_tokens = 0
+        # each live row's last token, on the device, at the request's
+        # `slot` (the entry past the last is the padding rows'): the
+        # prefill modules write it, the decode modules read and write
+        # it, and the host reads tokens only to deliver them
+        import jax.numpy as jnp
+        self._last = jnp.zeros((self.config.max_slots + 1,), jnp.int64)
+        # the decode dispatch that was sent and not yet read (None, or
+        # a dict: plan, the device arrays, when it was sent, whether it
+        # was sent ahead of an unread one), and how many were
+        self._in_flight = None
+        self.decode_dispatches_ahead = 0
+        # the engine's own measurements, on the machine's clock: when
+        # the device last finished something the host waited for, the
+        # last lengths of a decode dispatch by its shape and of the
+        # host's planning (the start of an intervention to its decode
+        # dispatch).  `wait_s` reckons from them what is left of the
+        # span in flight
+        self._device_free_t = 0.0
+        self._span_s = {}
+        self._plan_s = collections.deque(maxlen=3)
         self._rid = 0
         self._prefills = 0
         # first-token / rollback counts carried to the NEXT serve_step
@@ -291,7 +343,8 @@ class ServingEngine:
         # slot of every layer's state)
         self.state_rows_updated = 0
         # prompt positions the prefill dispatches computed: the true
-        # ones, and the bucket's of every row of the chunk
+        # ones, and the bucket's of every row of the chunk (counted,
+        # like `_prefills`, when a chunk's first tokens are read)
         self.prefill_tokens = 0
         self.prefill_padded_tokens = 0
         # prefill dispatches whose module took a routed model's Pallas
@@ -481,8 +534,8 @@ class ServingEngine:
         sample = self._sample_fn()
 
         @jax.named_scope('serve.prefill')
-        def prefill_fn(params, buffers, ids, t0, first, second, where,
-                       seeds):
+        def prefill_fn(params, buffers, ids, t0, first, second, last,
+                       where, slot, seeds):
             # the cache's arrays are a pair of per-layer tuples (k and
             # v pools; S and z states), two arguments as the paged
             # modules always had them
@@ -505,7 +558,11 @@ class ServingEngine:
                          (t0 - 1).astype(jnp.int64))  # [B]
             first, second = cache.store_prefill((first, second), caches,
                                                 where)
-            return tok, first, second
+            # the rows' first tokens stay on the device, where the
+            # next decode span reads them (a padding row's slot is the
+            # spare entry)
+            last = last.at[slot].set(tok.astype(last.dtype))
+            return tok, first, second, last
 
         return prefill_fn
 
@@ -522,15 +579,20 @@ class ServingEngine:
         # — without the marker a pre-discipline AOT artifact would
         # deserialize against the new call signature; layout= marks
         # the order of the cache's arrays for the same reason
+        # tokens= marks the device-resident last-token vector, one
+        # more donated argument, for the same reason
         fp = self._fingerprint('serve-prefill', bucket=P, nblk=nblk,
                                chunk=B, keys='per-request-pos',
-                               layout=self.cache.layout_key)
+                               layout=self.cache.layout_key,
+                               tokens='device-slots')
         example = (self._params, self._buffers,
                    jnp.zeros((B, P), jnp.int64),
                    jnp.full((B,), P, jnp.int32), *self.cache.arrays(),
+                   self._last,
                    jnp.asarray(self.cache.prefill_where((), B, P)),
+                   jnp.full((B,), self.config.max_slots, jnp.int32),
                    jnp.zeros((B,), jnp.int64))
-        return fn, fp, example, f'serve.prefill[{P}x{B}]', (4, 5)
+        return fn, fp, example, f'serve.prefill[{P}x{B}]', (4, 5, 6)
 
     def _prefill_module(self, P, B):
         sig = ('prefill', P, B)
@@ -554,9 +616,18 @@ class ServingEngine:
         with_stats = bool(getattr(model, 'step_stat_names', ()))
 
         @jax.named_scope('serve.decode')
-        def decode_fn(params, buffers, first, second, where, ctx, tok,
-                      active, limit, seeds):
+        def decode_fn(params, buffers, first, second, last, where, ctx,
+                      slot, active, limit, seeds):
             arrays = cache.constrain((first, second))
+            # a row's input token is what the prefill or the span
+            # before this one left at its slot: the host plans by
+            # counts and never sends a token
+            tok = last[slot]
+            if eos is not None:
+                # the host cannot foresee a row that ended on EOS in
+                # the span before this one (or at its prefill): the
+                # token it left says so
+                active = active & (tok != eos)
 
             def body(carry, _):
                 tok, ctx, active, arrays = carry
@@ -583,7 +654,7 @@ class ServingEngine:
             (tok, ctx, active, arrays), (toks, valid, *stats) = \
                 jax.lax.scan(body, (tok, ctx, active, arrays),
                              None, length=K)
-            return (toks, valid, *arrays, *stats)
+            return (toks, valid, *arrays, last.at[slot].set(tok), *stats)
 
         return decode_fn
 
@@ -600,14 +671,15 @@ class ServingEngine:
         where = jnp.asarray(self.cache.idle_where(S, W))
         fp = self._fingerprint('serve-decode', batch=S, span=K,
                                keys='per-request-pos',
-                               layout=self.cache.layout_key, **extra)
-        example = (self._params, self._buffers, *arrays, where,
-                   jnp.zeros((S,), jnp.int64),
-                   jnp.zeros((S,), jnp.int64),
+                               layout=self.cache.layout_key,
+                               tokens='device-slots', **extra)
+        example = (self._params, self._buffers, *arrays, self._last,
+                   where, jnp.zeros((S,), jnp.int64),
+                   jnp.full((S,), self.config.max_slots, jnp.int32),
                    jnp.zeros((S,), bool),
                    jnp.zeros((S,), jnp.int64),
                    jnp.zeros((S,), jnp.int64))
-        return fn, fp, example, f'serve.decode[{S}x{K}]', (2, 3)
+        return fn, fp, example, f'serve.decode[{S}x{K}]', (2, 3, 4)
 
     def _decode_module(self, S, K):
         sig = ('decode', S, K)
@@ -651,8 +723,11 @@ class ServingEngine:
         preemption path's discipline — a token nobody received must
         not count as delivered throughput), and emits the usual
         finished-request telemetry with the typed cause.  Returns True
-        if the rid was live (queued or running), False otherwise."""
+        if the rid was live (queued or running), False otherwise.  A
+        span in flight is read first (`drain`), so what is rolled back
+        is everything the request was ever handed."""
         sched = self.scheduler
+        self.drain()
         for req in list(sched.queue):
             if req.rid == rid:
                 sched.queue.remove(req)
@@ -672,11 +747,12 @@ class ServingEngine:
     def _chunk_bucket(self, n):
         return _cc.bucket_pow2(n, cap=self.config.prefill_batch)
 
-    def _prefill_dispatch(self, reqs):
+    def _prefill_dispatch(self, reqs, ordinal):
         """Dispatch ONE batched prefill over a chunk of same-bucket
-        admissions (async); the pools chain through donation so
-        back-to-back chunks pipeline on the device.  Returns the
-        un-synced first-token device array [chunk bucket]."""
+        admissions (async), the engine's `ordinal`-th; the pools chain
+        through donation so back-to-back chunks pipeline on the
+        device.  Returns the un-synced first-token device array
+        [chunk bucket]."""
         import jax.numpy as jnp
         P = reqs[0].prompt_bucket
         B = self._chunk_bucket(len(reqs))
@@ -690,26 +766,42 @@ class ServingEngine:
             seeds[i] = req.seed or 0
         # padding rows write where nothing is kept (the trash block)
         where = self.cache.prefill_where([r.rid for r in reqs], B, P)
-        self._prefills += 1
-        self.moe_kernel_prefills += ('prefill', P, B) in self._kernel_prefills
-        self.prefill_tokens += sum(r.prompt.size for r in reqs)
-        self.prefill_padded_tokens += B * P
-        tok, *arrays = mod(self._params, self._buffers,
-                           jnp.asarray(ids), jnp.asarray(t0s),
-                           *self.cache.arrays(), jnp.asarray(where),
-                           jnp.asarray(seeds))
-        self.cache.set_arrays(arrays)
+        slots = np.full((B,), self.config.max_slots, np.int32)
+        slots[:len(reqs)] = [r.slot for r in reqs]
+        tok, first, second, self._last = mod(
+            self._params, self._buffers, jnp.asarray(ids),
+            jnp.asarray(t0s), *self.cache.arrays(), self._last,
+            jnp.asarray(where), jnp.asarray(slots), jnp.asarray(seeds))
+        self.cache.set_arrays((first, second))
         now = self._clock()
         for i, req in enumerate(reqs):
             # a recurrent cache: the device row that holds the state
             req.trace_note('prefill', now, bucket=P, chunk=B,
-                           dispatch=self._prefills,
+                           dispatch=ordinal,
                            slot=int(where[i]) if self.recurrent else None)
         return tok
 
+    def _prefill_read(self, reqs, toks):
+        """A prefill chunk's first tokens, on the host, each handed to
+        its request; and the chunk counted, now that the device has
+        run it: a counter read beside a device trace (a traced
+        benchmark run's) then counts the work the trace holds, not
+        what is still queued behind the span in flight."""
+        P, B = reqs[0].prompt_bucket, self._chunk_bucket(len(reqs))
+        self._prefills += 1
+        self.moe_kernel_prefills += ('prefill', P, B) in self._kernel_prefills
+        self.prefill_tokens += sum(r.prompt.size for r in reqs)
+        self.prefill_padded_tokens += B * P
+        for req, tok in zip(reqs, toks):
+            # (one that reservation preempted just now has no token)
+            if req.state == Request.RUNNING:
+                self._prefill_finish(req, tok)
+                self._pending_prefilled += 1
+
     def _prefill_finish(self, req, tok):
-        """Record one synced first token (TTFT anchor) and finish the
-        request if it is already complete."""
+        """Record one synced first token (TTFT anchor: the moment the
+        host holds it) and finish the request if it is already
+        complete."""
         req.tokens.append(int(tok))
         req.first_token_t = self._clock()
         req.trace_note('first_token', req.first_token_t)
@@ -724,11 +816,11 @@ class ServingEngine:
     def _decode(self, plan):
         import jax.numpy as jnp
         mod = self._decode_module(plan.batch, plan.span)
-        toks, valid, first, second, *stats = mod(
+        toks, valid, first, second, self._last, *stats = mod(
             self._params, self._buffers, *self.cache.arrays(),
-            jnp.asarray(self.cache.decode_where(plan)),
+            self._last, jnp.asarray(self.cache.decode_where(plan)),
             jnp.asarray(plan.ctx),
-            jnp.asarray(plan.tok), jnp.asarray(plan.active),
+            jnp.asarray(plan.slot), jnp.asarray(plan.active),
             jnp.asarray(plan.limit), jnp.asarray(plan.seed))
         self.cache.set_arrays((first, second))
         return toks, valid, stats
@@ -793,15 +885,34 @@ class ServingEngine:
                     budget_s=req.deadline_s, age_s=rec['age_s'])
 
     # -- the intervention loop -----------------------------------------------
-    def step(self, now=None):
-        """ONE scheduler intervention: release/admit/prefill, decode
-        the live set for one span, absorb, evict, backfill.  Returns
-        the intervention's progress count (admissions + evictions +
-        decoded tokens); 0 means nothing could move at all.
+    # the next intervention starts when what is left of the span in
+    # flight is this many of the host's own planning times (`wait_s`)
+    LEAD = 2.0
 
-        The spans (``serve.step`` and its children, in this order) are
-        the contract PERF.md section 3 lists beside the metrics that
-        read them."""
+    def step(self, now=None):
+        """ONE scheduler intervention.  A decode dispatch is kept in
+        flight: this call plans and SENDS the span of intervention
+        N+1 while N is still running on the device, and only then
+        reads, absorbs and books N behind it.  In order (the spans
+        ``serve.step`` and its children, the contract PERF.md section
+        3 lists beside the metrics that read them): ``deadlines →
+        admit → prefill_dispatch → reserve → plan → decode_dispatch``
+        (N+1) ``→ decode_sync`` (N) ``→ absorb`` (N) ``→
+        first_token_sync`` (of the prefills just sent) ``→
+        bookkeeping``.
+
+        So a caller who steps the engine by hand reads a span's tokens
+        one call after it was sent: the first call that decodes
+        delivers first tokens only, ``interventions`` counts spans
+        READ, and ``step_taps`` are those of the span read last.
+        ``drain()`` reads what is in flight without sending more;
+        ``run()``, ``cancel()`` and ``warmup()`` call it.  A request's
+        ``first_token_t`` is still the moment the host holds its first
+        token.
+
+        Returns the intervention's progress count (admissions +
+        evictions + token steps sent + tokens read); 0 means nothing
+        could move at all."""
         from ..telemetry import span
         with span('serve.step'):
             return self._step(now)
@@ -812,12 +923,15 @@ class ServingEngine:
         sched = self.scheduler
         now = self._clock() if now is None else now
         t_start = self._clock()
+        t_plan = time.monotonic()
         with span('serve.deadlines'):
             breached = sched.check_deadlines(now)
             self._note_finished(breached, now)
         # two-phase admission: chunk same-bucket admissions into
         # batched prefill dispatches (device work pipelines through
-        # the donated pool chain), then sync first tokens in order
+        # the donated pool chain); their first tokens stay on the
+        # device for the decode span sent below, and the host reads
+        # them last
         chunks = []
         with span('serve.admit'):
             while True:
@@ -829,81 +943,172 @@ class ServingEngine:
                         or len(chunks[-1]) >= self.config.prefill_batch):
                     chunks.append([])
                 chunks[-1].append(req)
-        admitted = sum(len(c) for c in chunks)
-        dispatched = []
+        fresh = [r for c in chunks for r in c]
+        admitted = len(fresh)
+        firsts = []
         for chunk in chunks:
             with span('serve.prefill_dispatch'):
-                dispatched.append(self._prefill_dispatch(chunk))
-        for reqs, toks_dev in zip(chunks, dispatched):
-            with span('serve.first_token_sync'):
-                toks = np.asarray(toks_dev)
-            for i, req in enumerate(reqs):
-                self._prefill_finish(req, toks[i])
-            self._pending_prefilled += len(reqs)
-        prefill_done = [r for reqs in chunks for r in reqs if r.done]
-        progress = admitted + len(breached)
-        if not sched.running:
-            # everything finished at prefill (or evicted): flush the
-            # carried first-token counts NOW — no later serve_step
-            # will fire to carry them, and the live plane / run_report
-            # token accounting must still match decoded_tokens
-            with span('serve.bookkeeping'):
-                self._note_finished(prefill_done, now)
-                self._flush_pending_tokens(admitted, t_start)
-            return progress
-        self._note_finished(prefill_done, now)
+                firsts.append(self._prefill_dispatch(
+                    chunk, self._prefills + len(firsts) + 1))
+        # a request of one token is whole once its prefill is sent
+        sched.release_sent(fresh)
         with span('serve.reserve'):
             preempted = sched.reserve_span(sched.decode_span)
         # a preempted request's emitted tokens are discarded and will
         # be recomputed — un-count them so tokens_per_s only ever
-        # counts DELIVERED tokens once
-        discarded = sum(getattr(r, 'discarded_tokens', 0)
-                        for r in preempted)
+        # counts DELIVERED tokens once (what is in flight for it was
+        # never counted, and is skipped when its span is absorbed)
+        discarded = sum(r.discarded_tokens for r in preempted)
         self.decoded_tokens -= discarded
         self._pending_discarded += discarded
         with span('serve.plan'):
             plan = sched.plan()
-        if plan is None:
-            with span('serve.bookkeeping'):
+        read, self._in_flight = self._in_flight, None
+        sent = 0
+        if plan is not None:
+            with span('serve.decode_dispatch'):
+                toks_dev, valid_dev, stats_dev = self._decode(plan)
+                # what the dispatch reads of what its rows hold, before
+                # the rows that end in it give their blocks back
+                kv_read, kv_table = self.cache.kv_blocks(plan)
+                sched.sent(plan)
+                sent = sum(plan.sent)
+                self._in_flight = {
+                    'plan': plan, 'toks': toks_dev, 'valid': valid_dev,
+                    'stats': stats_dev, 'kv': (kv_read, kv_table),
+                    'ahead': int(read is not None),
+                    'sent_t': time.monotonic()}
+            self._plan_s.append(time.monotonic() - t_plan)
+            if self._prof is not None:
+                # dispatches sent before this one: those read, and the
+                # one still in flight
+                self._prof.observe(
+                    (self.interventions + (read is not None)) * plan.span,
+                    sync=toks_dev, span=plan.span)
+        n, finished = 0, []
+        if read is not None:
+            n, finished = self._collect(read)
+        for reqs, toks_dev in zip(chunks, firsts):
+            with span('serve.first_token_sync'):
+                toks = self._await(toks_dev)
+            self._prefill_read(reqs, toks)
+        with span('serve.bookkeeping'):
+            self._note_finished(finished + [r for r in fresh if r.done],
+                                self._clock())
+            if read is not None:
+                self._book(read, n, len(finished), admitted,
+                           len(preempted), t_start)
+            elif self._in_flight is None:
+                # everything finished at prefill (or was evicted):
+                # flush the carried first-token counts NOW — no later
+                # serve_step will fire to carry them, and the live
+                # plane / run_report token accounting must still match
+                # decoded_tokens
                 self._flush_pending_tokens(admitted, t_start)
-            return progress
-        with span('serve.decode_dispatch'):
-            toks_dev, valid_dev, stats_dev = self._decode(plan)
-        if self._prof is not None:
-            self._prof.observe(self.interventions * plan.span,
-                               sync=toks_dev, span=plan.span)
+        return admitted + len(breached) + sent + n
+
+    def _await(self, arr, sent_t=None, shape=None):
+        """The host's copy of a device array, and the engine's own
+        clock of the device: when it finished this, and, where the
+        host had to wait for it, how long a decode dispatch of `shape`
+        ran (from the later of its being sent and the device's
+        finishing what was before it)."""
+        waited = not arr.is_ready()
+        out = np.asarray(arr)
+        t = time.monotonic()
+        if shape is not None and waited:
+            self._span_s.setdefault(
+                shape, collections.deque(maxlen=3)).append(
+                    t - max(sent_t, self._device_free_t))
+        self._device_free_t = t
+        return out
+
+    def _collect(self, flight):
+        """Read a decode dispatch's tokens and fold them into its
+        requests; returns (tokens delivered, requests finished)."""
+        from ..telemetry import span
+        plan = flight['plan']
         with span('serve.decode_sync'):
-            toks = np.asarray(toks_dev)
-            valid = np.asarray(valid_dev)
-            for stats in stats_dev:         # counts [span, names]
+            toks = self._await(flight['toks'], flight['sent_t'],
+                               (plan.batch, plan.span))
+            valid = np.asarray(flight['valid'])
+            for stats in flight['stats']:   # counts [span, names]
                 self.step_stats += np.asarray(stats['counts'],
                                               np.int64).sum(0)
                 self.step_taps = stats['taps']
         with span('serve.absorb'):
-            finished = sched.absorb(plan, toks, valid)
-        with span('serve.bookkeeping'):
-            self._note_finished(finished, self._clock())
-            n = int(valid.sum())
-            self.decoded_tokens += n
-            self.interventions += 1
-            read, table = self.cache.kv_blocks(plan)
-            self.kv_blocks_read += read * plan.span
-            self.kv_blocks_table += table * plan.span
-            if self.recurrent:
-                # every valid token is one live row's state rewritten
-                self.state_rows_updated += n
-            self._emit_serve_step(
-                admitted, t_start, live=len(plan.requests),
-                batch=plan.batch, span=plan.span, decoded=n,
-                finished=len(finished), preempted=len(preempted),
-                kv_blocks_read=read, kv_blocks_table=table)
-            telemetry.add('serve.decoded_tokens', n)
-        return progress + n
+            finished, n = self.scheduler.absorb(plan, toks, valid)
+        # every valid token is one live row's state rewritten
+        flight['rows'] = int(valid.sum())
+        return n, finished
+
+    def _book(self, flight, n, finished, admitted, preempted, t_start):
+        """Count a dispatch that was read, and emit its serve_step."""
+        from .. import telemetry
+        plan = flight['plan']
+        kv_read, kv_table = flight['kv']
+        self.decoded_tokens += n
+        self.interventions += 1
+        self.decode_dispatches_ahead += flight['ahead']
+        self.kv_blocks_read += kv_read * plan.span
+        self.kv_blocks_table += kv_table * plan.span
+        if self.recurrent:
+            self.state_rows_updated += flight['rows']
+        self._emit_serve_step(
+            admitted, t_start, live=len(plan.requests),
+            batch=plan.batch, span=plan.span, decoded=n,
+            finished=finished, preempted=preempted,
+            kv_blocks_read=kv_read, kv_blocks_table=kv_table,
+            ahead=flight['ahead'])
+        telemetry.add('serve.decoded_tokens', n)
+
+    def drain(self):
+        """Read, absorb and book the decode dispatch in flight, if
+        there is one, without sending another: after it the host holds
+        every token the device was asked for.  Returns the tokens it
+        delivered."""
+        flight, self._in_flight = self._in_flight, None
+        if flight is None:
+            return 0
+        from ..telemetry import span
+        t_start = self._clock()
+        with span('serve.step'):
+            n, finished = self._collect(flight)
+            with span('serve.bookkeeping'):
+                self._note_finished(finished, self._clock())
+                self._book(flight, n, len(finished), 0, 0, t_start)
+        return n
+
+    def wait_s(self):
+        """Seconds the caller may still hand arrivals over before the
+        next intervention has to start (0: start it now).  An
+        intervention fixes the next span while the one in flight still
+        runs, and a request that arrives after that waits a whole span
+        longer; so where arrivals may still come (`run()` with
+        requests not yet due, `ServingFrontend`'s loop) the next one is
+        taken only when what is left of the span in flight, reckoned
+        from the measured length of the last dispatches of its shape,
+        is `LEAD` times the host's own measured planning time.  With
+        nothing in flight, or nothing measured yet, that is now."""
+        flight = self._in_flight
+        if flight is None:
+            return 0.0
+        plan = flight['plan']
+        lengths = self._span_s.get((plan.batch, plan.span))
+        if not lengths or not self._plan_s:
+            return 0.0
+        ends = max(flight['sent_t'], self._device_free_t) \
+            + statistics.median_high(lengths)
+        lead = self.LEAD * statistics.median_high(self._plan_s)
+        return max(0.0, ends - lead - time.monotonic())
 
     def run(self, requests=(), timeout_s=None):
         """Drive to drain: submit `requests` honoring their
         ``arrival_t`` offsets (the Poisson load path), loop
-        interventions until every request completes or evicts.
+        interventions until every request completes or evicts, and
+        read what is still in flight.  While requests are still to
+        come it keeps handing them over and takes the next
+        intervention when `wait_s` says so; a backlog plans at once.
         Returns the report dict."""
         from ..telemetry import span
         pending = sorted(requests, key=lambda r: r.arrival_t)
@@ -922,6 +1127,8 @@ class ServingEngine:
             while pending or sched.queue or sched.running:
                 now = self._clock()
                 if timeout_s is not None and now - start > timeout_s:
+                    # what the device was asked for is delivered first
+                    self.drain()
                     timed_out = []
                     for req in list(sched.running) + list(sched.queue):
                         if req in sched.queue:
@@ -943,6 +1150,13 @@ class ServingEngine:
                             time.sleep(min(0.05, max(
                                 0.0, pending[0].arrival_t - now)))
                     continue
+                if pending:
+                    wait = min(self.wait_s(), 0.05,
+                               pending[0].arrival_t - now)
+                    if wait > 0:
+                        with span('serve.wait_span'):
+                            time.sleep(wait)
+                        continue
                 if self.step(now=now) == 0 and not sched.running \
                         and sched.queue:
                     # nothing live and the head of the queue cannot be
@@ -951,6 +1165,8 @@ class ServingEngine:
                     req = sched.queue.popleft()
                     sched.finish(req, 'oom')
                     self._note_finished([req], self._clock())
+            # a span whose every row was evicted meanwhile
+            self.drain()
         finally:
             if self._prof is not None:
                 self._prof.close()
@@ -1012,12 +1228,15 @@ class ServingEngine:
         """Counters over the engine's life that a caller differences
         itself: prompt positions prefilled (true, and the buckets'),
         the prefill dispatches that took a routed model's grouped
-        kernel, what the model's decode steps handed back
+        kernel, the decode dispatches that were sent while the one
+        before them had not been read (of `interventions`, counted as
+        each is read), what the model's decode steps handed back
         (`model.step_stat_names`), and a cache's own (`counters`: a
         `LayerGroupKVCache`'s blocks held and read by group)."""
         return {'prefill_tokens': self.prefill_tokens,
                 'prefill_padded_tokens': self.prefill_padded_tokens,
                 'moe_kernel_prefills': self.moe_kernel_prefills,
+                'decode_dispatches_ahead': self.decode_dispatches_ahead,
                 **{k: int(v) for k, v in zip(self.step_stat_names,
                                              self.step_stats)},
                 **getattr(self.cache, 'counters', {})}
@@ -1080,23 +1299,29 @@ class ServingEngine:
         import jax.numpy as jnp
         params, buffers = self._params, self._buffers
         cache = self.cache
+        self.drain()
+        # every row writes the spare entry of the last-token vector
+        spare = self.config.max_slots
         for P in self.config.prompt_buckets:
             for B in _pow2_chain(1, self.config.prefill_batch):
                 mod = self._prefill_module(P, B)
-                tok, *arrays = mod(
+                tok, first, second, self._last = mod(
                     params, buffers, jnp.zeros((B, P), jnp.int64),
                     jnp.full((B,), P, jnp.int32), *cache.arrays(),
+                    self._last,
                     jnp.asarray(cache.prefill_where((), B, P)),
+                    jnp.full((B,), spare, jnp.int32),
                     jnp.zeros((B,), jnp.int64))
-                cache.set_arrays(arrays)
+                cache.set_arrays((first, second))
                 np.asarray(tok)
         W = self.scheduler.table_width
         for S in self.config.batch_buckets:
             mod = self._decode_module(S, self.config.decode_span)
-            toks, _valid, first, second, *_stats = mod(
-                params, buffers, *cache.arrays(),
+            toks, _valid, first, second, self._last, *_stats = mod(
+                params, buffers, *cache.arrays(), self._last,
                 jnp.asarray(cache.idle_where(S, W)),
-                jnp.zeros((S,), jnp.int64), jnp.zeros((S,), jnp.int64),
+                jnp.zeros((S,), jnp.int64),
+                jnp.full((S,), spare, jnp.int32),
                 jnp.zeros((S,), bool), jnp.zeros((S,), jnp.int64),
                 jnp.zeros((S,), jnp.int64))
             cache.set_arrays((first, second))

@@ -207,8 +207,16 @@ class ServingFrontend:
             except Exception as e:      # pragma: no cover - relay
                 op.finish(error=e)
         if not sched.queue and not sched.running:
+            eng.drain()     # a span whose every row was evicted
             if not ran_op:
                 time.sleep(self.poll_s)
+            return
+        # the door is open, so a request may always still come: keep
+        # taking them until the engine says the span in flight is
+        # about to end (`ServingEngine.wait_s`, `run()`'s own question)
+        wait = eng.wait_s()
+        if wait > 0:
+            time.sleep(min(wait, self.poll_s))
             return
         if eng.step() == 0 and not sched.running and sched.queue:
             # the head of the queue can never be admitted even

@@ -19,6 +19,23 @@ youngest running request is *preempted* back to the queue — its
 blocks free immediately and it re-prefills later (recompute-style
 preemption, the simple/robust vLLM policy).
 
+**Planning is by counts.**  The engine sends the decode span of
+intervention N+1 while N is still running on the device, so the
+scheduler never reads a token to schedule: a request carries how many
+token steps have been sent for it and not yet absorbed
+(``Request.dispatched``), and ``reserve_span`` and ``plan`` reckon its
+context as ``ctx + dispatched``, capped at its ``limit``.  The input
+token of a row stays on the device, in the engine's vector of last
+tokens, at the request's ``slot`` (held from admission to the end).  A
+row that ends by ``max_new_tokens`` is known to end in N as soon as N
+is sent: ``sent(plan)`` gives its slot and blocks back at once
+(``Request.released``; it stays in ``running`` until its tokens are
+absorbed), so the next admission has them when N+1 is planned.  The
+device runs what it is sent in order, so a block freed on the host
+while N is in flight is never rewritten before N has read it.  A row
+that ends on ``eos_id`` cannot be foreseen: the host learns it when it
+absorbs N and frees the slot one span late.
+
 All host-side bookkeeping: the scheduler never touches a device
 array.  The engine asks for a :class:`DecodePlan` (padded numpy
 tables/lengths bucketed to the declared pow2 batch set) and reports
@@ -94,7 +111,12 @@ class Request:
         self.state = Request.QUEUED
         self.reason = None          # eos | max_tokens | deadline | ...
         self.tokens = []            # decoded token ids (ints)
-        self.ctx = 0                # cache positions written so far
+        self.ctx = 0                # cache positions written AND absorbed
+        # token steps sent to the device and not yet absorbed, and the
+        # row of the engine's last-token vector held while there are
+        # steps still to send (see the module's docstring)
+        self.dispatched = 0
+        self.slot = None
         self.prompt_bucket = None   # padded prefill length (pow2)
         self.first_token_t = None   # wall time of the first token
         self.finish_t = None
@@ -117,6 +139,12 @@ class Request:
     @property
     def done(self):
         return self.state in (Request.DONE, Request.EVICTED)
+
+    @property
+    def released(self):
+        """Running with slot and blocks given back already: everything
+        it will ever emit has been sent and is in flight."""
+        return self.state == Request.RUNNING and self.slot is None
 
     # emitted-token accounting: after prefill ctx == t0 and ONE token
     # exists; each decode step advances ctx and emits one more.  The
@@ -166,10 +194,18 @@ class DecodePlan:
             else (self.batch, int(groups), table_width)
         self.tables = np.full(shape, TRASH_BLOCK, np.int32)
         self.ctx = np.zeros((self.batch,), np.int64)
-        self.tok = np.zeros((self.batch,), np.int64)
+        # the rows of the device's last-token vector the steps read
+        # their input from and write their last token to; a padding
+        # row names the spare entry past the last slot
+        self.slot = np.zeros((self.batch,), np.int32)
         self.active = np.zeros((self.batch,), bool)
         self.limit = np.zeros((self.batch,), np.int64)
         self.seed = np.zeros((self.batch,), np.int64)
+        # token steps this plan sends for each request, and the
+        # request's count of preemptions when it was planned (a span
+        # in flight across a preemption is discarded)
+        self.sent = [0] * len(self.requests)
+        self.epoch = [r.preemptions for r in self.requests]
 
     def kv_blocks(self, block_size):
         """(read, table): the KV blocks one token step of this plan
@@ -215,6 +251,9 @@ class ContinuousBatchingScheduler:
         self.running = []            # admission order (oldest first)
         self.finished = []
         self.counters = collections.Counter()
+        # rows of the engine's last-token vector nobody holds (LIFO);
+        # entry `max_slots` is the spare one of a plan's padding rows
+        self._free_slots = list(range(self.max_slots - 1, -1, -1))
 
     # -- submission ---------------------------------------------------------
     def submit(self, req):
@@ -252,7 +291,7 @@ class ContinuousBatchingScheduler:
     def admit_next(self):
         """Admit the head of the queue if a slot and blocks exist;
         returns the Request (caller prefills it) or None."""
-        if not self.queue or len(self.running) >= self.max_slots:
+        if not self.queue or not self._free_slots:
             return None
         req = self.queue[0]
         bucket = int(self.bucket_fn(req.prompt.size))
@@ -270,6 +309,8 @@ class ContinuousBatchingScheduler:
         req.state = Request.RUNNING
         req.prompt_bucket = bucket
         req.ctx = req.prompt.size
+        req.dispatched = 0
+        req.slot = self._free_slots.pop()
         self.running.append(req)
         self.counters['admitted'] += 1
         req.trace_note('admitted', self.now_fn(), bucket=bucket,
@@ -277,12 +318,22 @@ class ContinuousBatchingScheduler:
         return req
 
     # -- eviction / completion ----------------------------------------------
+    def _give_back(self, req):
+        """`req`'s blocks and slot go back to their free lists (once:
+        a released request holds neither)."""
+        self.cache.free_seq(req.rid)
+        if req.slot is not None:
+            self._free_slots.append(req.slot)
+            req.slot = None
+
     def finish(self, req, reason):
+        if not req.released:
+            self._give_back(req)
+        req.dispatched = 0
         req.state = Request.DONE if reason in ('eos', 'max_tokens') \
             else Request.EVICTED
         req.reason = reason
         req.finish_t = self.now_fn()
-        self.cache.free_seq(req.rid)
         if req in self.running:
             self.running.remove(req)
         self.finished.append(req)
@@ -293,13 +344,17 @@ class ContinuousBatchingScheduler:
                        tokens=len(req.tokens))
 
     def preempt_youngest(self):
-        """Pool pressure: push the newest running request back to the
-        queue head (recompute-style — its blocks free now, it
-        re-prefills from scratch later)."""
-        if not self.running:
+        """Pool pressure: push the newest running request that still
+        holds blocks back to the queue head (recompute-style — its
+        blocks free now, it re-prefills from scratch later; what is in
+        flight for it is discarded when its span is absorbed)."""
+        held = [r for r in self.running if not r.released]
+        if not held:
             return None
-        req = self.running.pop()
-        self.cache.free_seq(req.rid)
+        req = held[-1]
+        self.running.remove(req)
+        self._give_back(req)
+        req.dispatched = 0
         req.state = Request.QUEUED
         # the discarded work is recomputed after re-admission — the
         # engine rolls its decoded-token accounting back by this much
@@ -331,16 +386,23 @@ class ContinuousBatchingScheduler:
     # -- decode planning -----------------------------------------------------
     def reserve_span(self, span):
         """Reserve blocks so every live sequence can write `span` more
-        positions (capped at its own limit).  Preempts the youngest
-        request(s) on pool pressure; returns the preempted list."""
+        positions past what has been sent for it (capped at its own
+        limit).  Preempts the youngest request(s) on pool pressure;
+        returns the preempted list."""
         preempted = []
         i = 0
         while i < len(self.running):
             req = self.running[i]
-            need = min(req.ctx + span, req.limit)
+            if req.released:
+                i += 1
+                continue
+            at = req.ctx + req.dispatched
+            need = min(at + span, req.limit)
             # `written`: a window group releases what no query from
-            # position ctx on can see before it grows
-            if self.cache.ensure(req.rid, need, written=req.ctx):
+            # position `at` on can see before it grows (a span in
+            # flight read its table when it was planned, and runs
+            # before anything that is given a released block)
+            if self.cache.ensure(req.rid, need, written=at):
                 i += 1
                 continue
             victim = self.preempt_youngest()
@@ -351,35 +413,65 @@ class ContinuousBatchingScheduler:
         return preempted
 
     def plan(self, span=None):
-        """Build the DecodePlan for the current live set (None when
-        nothing is running).  Batch is padded to the smallest declared
-        pow2 bucket >= live count; padding rows point at the trash
-        block and stay inactive."""
-        if not self.running:
+        """Build the DecodePlan for the rows that have token steps
+        still to be sent (None when there is none), by counts alone: a
+        row stands at ``ctx + dispatched``.  Batch is padded to the
+        smallest declared pow2 bucket >= live count; padding rows
+        point at the trash block and stay inactive.  Changes nothing:
+        ``sent(plan)`` books a plan that was dispatched."""
+        rows = [r for r in self.running if not r.released]
+        if not rows:
             return None
         span = self.decode_span if span is None else int(span)
-        live = len(self.running)
-        batch = next(b for b in self.batch_buckets if b >= live)
-        plan = DecodePlan(self.running, batch, self.table_width, span,
+        batch = next(b for b in self.batch_buckets if b >= len(rows))
+        plan = DecodePlan(rows, batch, self.table_width, span,
                           groups=getattr(self.cache, 'table_groups',
                                          None))
-        for i, req in enumerate(self.running):
+        plan.slot[:] = self.max_slots
+        for i, req in enumerate(rows):
+            at = req.ctx + req.dispatched
             plan.tables[i] = self.cache.table_row(req.rid,
                                                   self.table_width)
-            plan.ctx[i] = req.ctx
-            plan.tok[i] = req.tokens[-1]
-            plan.active[i] = len(req.tokens) < req.max_new_tokens
+            plan.ctx[i] = at
+            plan.slot[i] = req.slot
+            plan.active[i] = at < req.limit
             plan.limit[i] = req.limit
             plan.seed[i] = req.seed or 0
+            plan.sent[i] = max(0, min(span, req.limit - at))
         return plan
+
+    def release_sent(self, reqs):
+        """Of `reqs`, those for which every token step they will ever
+        run has been sent give their slot and blocks back now.  They
+        stay in ``running`` until what is in flight for them has been
+        absorbed."""
+        for req in reqs:
+            if req.state == Request.RUNNING and not req.released \
+                    and req.ctx + req.dispatched >= req.limit:
+                self._give_back(req)
+
+    def sent(self, plan):
+        """`plan` was dispatched: count its token steps as in flight
+        and release the rows that end in it by ``max_new_tokens``."""
+        for req, n in zip(plan.requests, plan.sent):
+            req.dispatched += n
+        self.release_sent(plan.requests)
 
     def absorb(self, plan, toks, valid):
         """Fold one decode span's outputs back into the requests:
         append valid tokens, finish on EOS / max tokens.  ``toks`` and
-        ``valid`` are ``[span, batch]`` host arrays."""
+        ``valid`` are ``[span, batch]`` host arrays.  A request that
+        was preempted, cancelled or timed out while the span was in
+        flight is skipped: its tokens are discarded.  Returns (the
+        requests that finished, the tokens delivered)."""
         finished = []
+        delivered = 0
         now = self.now_fn()
         for i, req in enumerate(plan.requests):
+            if req.state != Request.RUNNING \
+                    or req.preemptions != plan.epoch[i]:
+                continue
+            req.dispatched -= plan.sent[i]
             emitted = 0
             finish_reason = None
             for k in range(plan.span):
@@ -395,6 +487,7 @@ class ContinuousBatchingScheduler:
                     finish_reason = 'max_tokens'
                     break
             req.ctx = min(req.ctx + emitted, req.limit)
+            delivered += emitted
             if emitted:
                 # ONE trace row per intervention per live request,
                 # noted BEFORE any finish row so the trail stays in
@@ -407,7 +500,7 @@ class ContinuousBatchingScheduler:
             if req.done:
                 finished.append(req)
         self.counters['decode_steps'] += plan.span
-        return finished
+        return finished, delivered
 
     # -- invariants ----------------------------------------------------------
     def audit(self):
@@ -416,18 +509,26 @@ class ContinuousBatchingScheduler:
         states = collections.Counter(r.state for r in self.running)
         if set(states) - {Request.RUNNING}:
             problems.append(f'non-running request in live set: {states}')
-        for req in self.running:
+        held = [req for req in self.running if not req.released]
+        for req in held:
             covered = len(self.cache.owned(req.rid)) \
                 * self.cache.block_size
-            if covered < req.ctx:
+            if covered < req.ctx + req.dispatched:
                 problems.append(
-                    f'request {req.rid}: ctx {req.ctx} exceeds its '
+                    f'request {req.rid}: ctx {req.ctx} and '
+                    f'{req.dispatched} steps in flight exceed its '
                     f'{covered} covered cache positions')
         for req in self.finished:
             if self.cache.owned(req.rid):
                 problems.append(
                     f'finished request {req.rid} still owns blocks')
-        live = {req.rid for req in self.running}
+        slots = [req.slot for req in held]
+        if sorted(slots + self._free_slots) != list(range(self.max_slots)):
+            problems.append(
+                f'token slots held {sorted(slots)} and free '
+                f'{sorted(self._free_slots)} are not 0..'
+                f'{self.max_slots - 1} once each')
+        live = {req.rid for req in held}
         for sid in self.cache.owners():
             if sid not in live:
                 problems.append(

@@ -297,6 +297,35 @@ class TestFrontendDoor:
         req = door._requests['st-0']
         assert [e['token'] for e in events] == list(req.tokens)
 
+    def test_the_loop_paces_by_the_engine_and_leaves_nothing_in_flight(
+            self, door):
+        """PR 36: the engine thread asks `wait_s` before each
+        intervention (the door is open, so an arrival may always
+        come) and reads the last span when the schedule empties: the
+        tokens are the engine's own, nothing stays in flight, the
+        pool comes back whole."""
+        import concurrent.futures
+        eng = door.engine
+        asked = []
+        wait_s = eng.wait_s
+        eng.wait_s = lambda: asked.append(wait_s()) or asked[-1]
+        docs = [{'prompt': [2 + i, 7, 1, 8], 'max_new_tokens': 5 + i,
+                 'rid': f'pace-{i}', 'stream': False} for i in range(5)]
+        with concurrent.futures.ThreadPoolExecutor(5) as pool:
+            replies = list(pool.map(
+                lambda d: _post(door.port, '/v1/generate', d), docs))
+        for doc, (st, _h, body) in zip(docs, replies):
+            assert st == 200 and body['state'] == 'done'
+            assert len(body['tokens']) == doc['max_new_tokens']
+        deadline = time.monotonic() + 10
+        while eng._in_flight is not None and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert asked and eng._in_flight is None
+        assert eng.scheduler.audit() == []
+        assert eng.cache.free_blocks == eng.cache.num_blocks - 1
+        assert eng.decoded_tokens == sum(d['max_new_tokens'] for d in docs)
+        assert eng.counts()['decode_dispatches_ahead'] >= 1
+
     def test_typed_sheds_with_retry_after(self, door):
         # 413 exceeds_pool straight through the door
         st, hdrs, body = _post(door.port, '/v1/generate', {

@@ -300,7 +300,9 @@ class TestSchedulerHost:
         plan = s.plan()
         assert plan.batch == 1 and plan.requests == [req]
         assert plan.tables.shape == (1, 8)     # 32 / 4
-        assert plan.ctx[0] == 3 and plan.tok[0] == 9
+        # the input token stays on the device: the plan names its slot
+        assert plan.ctx[0] == 3 and plan.slot[0] == req.slot == 0
+        assert plan.sent == [2]                # a whole span of 2
         assert plan.active[0]
         assert plan.limit[0] == 3 + 4 - 1
 
@@ -582,6 +584,290 @@ class TestServingEngine:
         assert win['step_lo'] >= 1
 
 
+class TestDispatchInFlight:
+    """PR 36: the span of intervention N+1 is planned by counts and
+    sent while N is still on the device; the host reads N behind it.
+    The tokens are what the synchronous order gave, and whatever
+    happens to a request while its span is in flight, the books
+    balance."""
+
+    def _load(self, eng, n=9, seed=11, lo=2, hi=9):
+        rs = np.random.RandomState(seed)
+        return [eng.submit(rs.randint(0, 128, (int(rs.randint(2, 9)),))
+                           .astype('int64'), int(rs.randint(lo, hi)))
+                for _ in range(n)]
+
+    def _balanced(self, eng, reqs):
+        assert eng._in_flight is None
+        assert eng.scheduler.audit() == []
+        assert eng.cache.free_blocks == eng.cache.num_blocks - 1
+        assert eng.decoded_tokens == sum(len(r.tokens) for r in reqs)
+        assert sorted(eng.scheduler._free_slots) \
+            == list(range(eng.config.max_slots))
+
+    def test_stepped_by_hand_a_spans_tokens_come_one_call_later(self):
+        m = _tiny_model()
+        eng = ServingEngine(m, _tiny_config())
+        req = eng.submit(np.arange(1, 6).astype('int64'), 7)
+        eng.step()              # prefill, span 1 sent, first token read
+        assert len(req.tokens) == 1 and req.dispatched == 2
+        assert eng.interventions == 0 and eng._in_flight is not None
+        eng.step()              # span 2 sent, span 1 read
+        assert len(req.tokens) == 3 and req.dispatched == 2
+        assert eng.interventions == 1
+        assert eng.decode_dispatches_ahead == 0    # span 1 was not
+        eng.step()              # span 3 sent, span 2 read
+        assert len(req.tokens) == 5 and req.ctx == 5 + 4
+        assert eng.decode_dispatches_ahead == 1
+        # span 3 ends the request by count: slot and blocks are back
+        # before its tokens are read
+        assert req.released and req.slot is None
+        assert req.state == Request.RUNNING
+        assert eng.cache.owned(req.rid) == []
+        assert eng.scheduler.audit() == []
+        assert eng.drain() == 2 and req.state == Request.DONE
+        assert req.tokens == _ref_tokens(m, req.prompt, 7)
+        assert eng.interventions == 3
+        assert eng.decode_dispatches_ahead == 2
+        self._balanced(eng, [req])
+        assert eng.drain() == 0                    # nothing in flight
+
+    def test_ahead_counts_what_it_says(self):
+        """Every dispatch but the first of an unbroken run was sent
+        while the one before it had not been read; a drain breaks the
+        run.  `serve_step` carries the same, event by event."""
+        m = _tiny_model()
+        telemetry.reset()
+        eng = ServingEngine(m, _tiny_config())
+        reqs = self._load(eng)
+        rep = eng.run()
+        n = eng.interventions
+        assert n > 3 and eng.decode_dispatches_ahead == n - 1
+        assert rep['decode_dispatches_ahead'] == n - 1
+        steps = [e for e in telemetry.events('serve_step') if e['span']]
+        assert [e['ahead'] for e in steps] == [0] + [1] * (n - 1)
+        self._balanced(eng, reqs)
+        for req in reqs:
+            assert req.tokens == _ref_tokens(m, req.prompt,
+                                             req.max_new_tokens)
+        # a second run starts with nothing in flight again
+        more = self._load(eng, n=3, seed=12)
+        eng.run()
+        assert eng.decode_dispatches_ahead == eng.interventions - 2
+        self._balanced(eng, reqs + more)
+
+    def test_a_freed_slot_and_its_blocks_are_backfilled_at_once(self):
+        """Two slots, requests of one span each: the row that ends by
+        count in N is replaced in N+1, so every dispatch after the
+        first is full (occupancy does not fall)."""
+        m = _tiny_model()
+        telemetry.reset()
+        eng = ServingEngine(m, _tiny_config(max_slots=2,
+                                            batch_buckets=(1, 2)))
+        rs = np.random.RandomState(2)
+        reqs = [eng.submit(rs.randint(0, 128, (4,)).astype('int64'), 3)
+                for _ in range(8)]
+        eng.run()
+        steps = [e for e in telemetry.events('serve_step') if e['span']]
+        assert [e['live'] for e in steps] == [2] * 4
+        for req in reqs:
+            assert req.tokens == _ref_tokens(m, req.prompt, 3)
+        self._balanced(eng, reqs)
+
+    def test_preemption_with_a_span_in_flight(self):
+        """A pool too small for three rows to grow: the youngest is
+        preempted while its span is in flight, those tokens are never
+        counted, and it is served again from its prompt."""
+        m = _tiny_model()
+        eng = ServingEngine(m, _tiny_config(
+            max_slots=4, batch_buckets=(1, 2, 4), num_blocks=10,
+            prompt_buckets=(8,)))
+        rs = np.random.RandomState(4)
+        reqs = [eng.submit(rs.randint(0, 128, (7,)).astype('int64'), 12)
+                for _ in range(3)]
+        seen_in_flight = False
+        while eng.scheduler.queue or eng.scheduler.running:
+            before = eng.scheduler.counters['preempted']
+            flight = eng._in_flight
+            eng.step()
+            if eng.scheduler.counters['preempted'] > before \
+                    and flight is not None:
+                victim = eng.scheduler.queue[0]
+                seen_in_flight |= victim in flight['plan'].requests
+                assert victim.tokens == [] and victim.dispatched == 0
+            assert eng.scheduler.audit() == []
+            assert eng.decoded_tokens == sum(len(r.tokens) for r in reqs)
+        eng.drain()
+        assert seen_in_flight
+        assert eng.scheduler.counters['preempted'] >= 1
+        for req in reqs:
+            assert req.state == Request.DONE
+            assert req.tokens == _ref_tokens(m, req.prompt, 12)
+        self._balanced(eng, reqs)
+
+    def test_cancel_with_a_span_in_flight(self):
+        m = _tiny_model()
+        eng = ServingEngine(m, _tiny_config())
+        reqs = self._load(eng, n=4, lo=8, hi=12)
+        eng.step()
+        eng.step()
+        assert eng._in_flight is not None
+        victim = reqs[1]
+        assert victim in eng._in_flight['plan'].requests
+        assert eng.cancel(victim.rid)
+        # the span in flight was read first, then everything the
+        # request had been handed was rolled back
+        assert eng._in_flight is None and victim.state == Request.EVICTED
+        assert eng.decoded_tokens == sum(
+            len(r.tokens) for r in reqs if r is not victim)
+        assert eng.scheduler.audit() == []
+        eng.run()
+        for req in reqs:
+            if req is not victim:
+                assert req.tokens == _ref_tokens(m, req.prompt,
+                                                 req.max_new_tokens)
+        assert eng._in_flight is None
+        assert eng.cache.free_blocks == eng.cache.num_blocks - 1
+
+    def test_a_deadline_with_a_span_in_flight(self):
+        """The request is evicted between two calls while its span is
+        on the device: the span's tokens for it are discarded, the
+        others' are delivered."""
+        clock = {'t': 0.0}
+        m = _tiny_model()
+        eng = ServingEngine(m, _tiny_config(), now_fn=lambda: clock['t'])
+        late = eng.submit(np.arange(1, 5).astype('int64'), 12,
+                          deadline_s=5.0)
+        good = eng.submit(np.arange(2, 7).astype('int64'), 12)
+        eng.step()
+        eng.step()
+        had = len(late.tokens)
+        assert late in eng._in_flight['plan'].requests
+        clock['t'] = 10.0
+        eng.step()              # deadline first, then the span is read
+        assert late.state == Request.EVICTED and late.reason == 'deadline'
+        assert len(late.tokens) == had and late.dispatched == 0
+        assert len(good.tokens) == had + 2
+        assert eng.scheduler.audit() == []
+        eng.run()
+        assert good.tokens == _ref_tokens(m, good.prompt, 12)
+        self._balanced(eng, [late, good])
+
+    def test_runs_timeout_with_a_span_in_flight(self):
+        """run() cut by its timeout reads what the device was asked
+        for before it evicts: every token computed is delivered once."""
+        clock = {'t': 0.0}
+
+        def now():
+            clock['t'] += 0.01
+            return clock['t']
+
+        m = _tiny_model()
+        eng = ServingEngine(m, _tiny_config(), now_fn=now)
+        reqs = self._load(eng, n=6, lo=10, hi=14)
+        rep = eng.run(timeout_s=0.4)
+        cut = [r for r in reqs if r.reason == 'engine_timeout']
+        assert cut and any(r.tokens for r in cut)
+        assert rep['decoded_tokens'] == sum(len(r.tokens) for r in reqs)
+        assert rep['audit'] == []
+        for req in reqs:
+            want = _ref_tokens(m, req.prompt, req.max_new_tokens)
+            assert req.tokens == want[:len(req.tokens)]
+        self._balanced(eng, reqs)
+
+    def test_eos_is_learnt_a_span_late(self):
+        """A row that ends on eos_id cannot be foreseen: the span sent
+        before the host knew runs it inactive (nothing after the EOS
+        is emitted), and its slot comes back one intervention later
+        than the slot of a row that ends at the same token by count."""
+        m = _tiny_model()
+        prompt = np.arange(3, 8).astype('int64')
+        ref = _ref_tokens(m, prompt, 12)
+        eos = ref[2]            # the last token of the first span
+        assert eos not in ref[:2]
+        by_count = ServingEngine(m, _tiny_config())
+        counted = by_count.submit(prompt, 3)
+        by_count.step()         # first token read; span 1 sent
+        assert counted.released and counted.slot is None
+        by_count.run()
+        assert counted.tokens == ref[:3]
+
+        eng = ServingEngine(m, _tiny_config(eos_id=eos))
+        req = eng.submit(prompt, 12)
+        eng.step()              # first token read; span 1 sent
+        assert not req.released and req.slot == 0
+        eng.step()              # span 2 sent BLIND; span 1 read: EOS
+        assert req.state == Request.DONE and req.reason == 'eos'
+        assert req.tokens == ref[:3] and req.slot is None
+        blind = eng._in_flight
+        assert req in blind['plan'].requests and blind['plan'].active[0]
+        assert eng.scheduler.audit() == []
+        assert eng.cache.owned(req.rid) == []
+        # the slot is free now: the next admission takes it while the
+        # blind span is still to be read
+        other = eng.submit(np.arange(9, 13).astype('int64'), 2)
+        eng.step()
+        assert other.slot == 0 or other.released
+        assert not np.asarray(blind['valid']).any()    # the device knew
+        assert req.tokens == ref[:3]
+        eng.run()
+        want = _ref_tokens(m, other.prompt, 2)
+        assert other.tokens == (want[:want.index(eos) + 1]
+                                if eos in want else want)
+        self._balanced(eng, [req, other])
+
+    def test_an_eos_at_the_first_token_is_silenced_on_the_device(self):
+        m = _tiny_model()
+        prompt = np.arange(3, 8).astype('int64')
+        eos = _ref_tokens(m, prompt, 1)[0]
+        eng = ServingEngine(m, _tiny_config(eos_id=eos))
+        req = eng.submit(prompt, 9)
+        eng.step()      # the span was sent before the first token was read
+        assert req.tokens == [eos] and req.reason == 'eos'
+        valid = np.asarray(eng._in_flight['valid'])
+        assert not valid.any()                  # the device knew
+        assert eng.drain() == 0
+        self._balanced(eng, [req])
+
+    def test_wait_s_asks_for_time_only_with_a_measured_span_in_flight(
+            self):
+        m = _tiny_model()
+        eng = ServingEngine(m, _tiny_config())
+        assert eng.wait_s() == 0.0              # nothing in flight
+        req = eng.submit(np.arange(1, 6).astype('int64'), 12)
+        eng.step()
+        assert eng.wait_s() == 0.0              # nothing measured yet
+        # a span of this shape that took a second, planned in a
+        # millisecond: the next intervention can wait
+        import collections
+        import time
+        eng._span_s[(1, 2)] = collections.deque([1.0])
+        eng._plan_s.clear()
+        eng._plan_s.append(0.001)
+        eng._device_free_t = time.monotonic()
+        assert 0.9 < eng.wait_s() <= 1.0
+        eng.run()
+        assert req.tokens == _ref_tokens(m, req.prompt, 12)
+
+    def test_arrivals_over_time_are_served_the_same(self):
+        """run() with requests still to come paces itself by wait_s;
+        the tokens are the same and nothing is left in flight."""
+        m = _tiny_model()
+        eng = ServingEngine(m, _tiny_config())
+        eng.warmup()
+        rs = np.random.RandomState(6)
+        load = [Request(f'a{i}', rs.randint(0, 128, (5,)).astype('int64'),
+                        int(rs.randint(3, 9)), arrival_t=0.01 * i)
+                for i in range(8)]
+        rep = eng.run(load)
+        assert rep['audit'] == []
+        for req in load:
+            assert req.state == Request.DONE
+            assert req.tokens == _ref_tokens(m, req.prompt,
+                                             req.max_new_tokens)
+        self._balanced(eng, load)
+
+
 class TestServeConfigAndLoadgen:
     def test_config_resolves_and_roundtrips(self):
         m = _tiny_model()
@@ -727,6 +1013,7 @@ class TestServingAnalysis:
                 for _ in range(m.config.num_layers))
             report = analysis.lint(
                 fn, eng._params, eng._buffers, pools, pools,
+                jax.ShapeDtypeStruct((cfg.max_slots + 1,), jnp.int32),
                 jax.ShapeDtypeStruct((S, W), jnp.int32),
                 jax.ShapeDtypeStruct((S,), jnp.int32),
                 jax.ShapeDtypeStruct((S,), jnp.int32),

@@ -674,9 +674,9 @@ def test_the_decode_module_holds_the_recurrent_state_once(one_chip,
     assert pr.can_use_pallas(S, sd((slots, 40, 128), 'float32'))
     row = sd((slots,), 'int32')
     fn = eng._decode_build(slots, span)
-    compiled = _uncached(lambda: jax.jit(fn, donate_argnums=(2, 3)).lower(
-        params, {}, (S, S), (z, z), row, row, row, sd((slots,), 'bool'),
-        row, row).compile())
+    compiled = _uncached(lambda: jax.jit(fn, donate_argnums=(2, 3, 4)).lower(
+        params, {}, (S, S), (z, z), sd((slots + 1,), 'int32'), row, row,
+        row, sd((slots,), 'bool'), row, row).compile())
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 1
     assert 'retention_decode' in text

@@ -267,3 +267,49 @@ def test_the_engine_decodes_through_the_kernel_what_it_decodes_plain(
         for i, prompt in enumerate(prompts):
             assert _gaps(eng, prompt, tokens[path][f'r{i}']).max() <= GAP
     assert tokens['kernel'] == tokens['plain']
+
+
+# -- a decode dispatch in flight (PR 36) ------------------------------------------------
+def test_a_span_in_flight_changes_no_token_and_the_books_balance():
+    """Two slots for six requests: the row that ends by count in span N
+    hands its slot to the next prefill while N is still to be read, and
+    the state that prefill writes there is the new request's alone:
+    every request's tokens are what a one-slot engine gives."""
+    rs = np.random.RandomState(9)
+    prompts = [rs.randint(0, 128, size=n) for n in (9, 25, 14, 30, 5, 18)]
+    new = [14, 9, 20, 6, 17, 11]
+    alone = _engine(max_slots=1, batch_buckets=(1,))
+    want, _ = _serve(alone, prompts, new)
+    eng = _engine(max_slots=2, batch_buckets=(2,))
+    got, report = _serve(eng, prompts, new)
+    assert got == want
+    assert report['audit'] == [] and eng._in_flight is None
+    assert eng.cache.free_blocks == eng.cache.slots
+    assert eng.decoded_tokens == sum(new)
+    assert eng.counts()['decode_dispatches_ahead'] == eng.interventions - 1
+    assert report['state_rows_updated'] == sum(new) - len(new)
+
+
+def test_a_cancel_and_a_deadline_with_a_span_in_flight():
+    clock = {'t': 0.0}
+    eng = ServingEngine(_model(), ServeConfig(
+        max_slots=4, decode_span=4, prompt_buckets=(16, 32),
+        batch_buckets=(2, 4), prefill_batch=1, max_model_len=64,
+        temperature=0.0), now_fn=lambda: clock['t'])
+    rs = np.random.RandomState(10)
+    reqs = [eng.submit(rs.randint(0, 128, size=10), 30, rid=f'r{i}',
+                       deadline_s=5.0 if i == 2 else None)
+            for i in range(3)]
+    eng.step()
+    eng.step()
+    assert all(r in eng._in_flight['plan'].requests for r in reqs)
+    assert eng.cancel('r0') and eng._in_flight is None
+    clock['t'] = 10.0
+    eng.step()
+    eng.step()
+    assert reqs[2].reason == 'deadline' and reqs[2].dispatched == 0
+    assert eng.scheduler.audit() == []
+    eng.run()
+    assert reqs[1].state == Request.DONE and len(reqs[1].tokens) == 30
+    assert eng.cache.free_blocks == eng.cache.slots
+    assert eng.decoded_tokens == len(reqs[1].tokens) + len(reqs[2].tokens)

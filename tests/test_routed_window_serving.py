@@ -385,3 +385,39 @@ def test_engine_serves_a_dropless_model_and_refuses_switch_moe(tiny):
     ServingEngine(model, ServeConfig(**SERVE))
     with pytest.raises(ValueError, match='capacity-dropping MoE'):
         ServingEngine(gpt_moe_tiny(), ServeConfig(max_slots=2))
+
+
+# -- a decode dispatch in flight (PR 36) ------------------------------------------------
+def test_a_span_in_flight_changes_no_token_and_the_books_balance(tiny):
+    """The span of N+1 is sent while N is on the device, over a pool so
+    small that rows are preempted with their span in flight and window
+    blocks are released under a span that still reads them: every
+    request's tokens are what a one-slot engine gives (one row, each
+    span planned with nothing else live), nothing is counted twice and
+    both groups come back whole."""
+    model, _params, _cfg = tiny
+    shapes = [(5, 20), (13, 9), (30, 25), (27, 30), (8, 12), (16, 16)]
+
+    def load():
+        rng = np.random.default_rng(0)
+        return [Request(f'r{i}', rng.integers(0, 128, n), new,
+                        arrival_t=0.0) for i, (n, new) in enumerate(shapes)]
+
+    alone = ServingEngine(model, ServeConfig(**dict(
+        SERVE, max_slots=1, batch_buckets=(1,))))
+    want = load()
+    alone.run(want)
+    eng = ServingEngine(model, ServeConfig(**dict(SERVE, num_blocks=34)))
+    reqs = load()
+    report = eng.run(reqs)
+    assert report['counters']['preempted'] >= 1
+    assert eng.counts()['window_blocks_released'] > 0
+    for got, ref_req in zip(reqs, want):
+        assert got.state == Request.DONE
+        assert got.tokens == ref_req.tokens, got.rid
+    assert report['audit'] == [] and eng._in_flight is None
+    assert eng.cache.free_blocks == eng.cache.num_blocks - 1
+    assert eng.decoded_tokens == sum(len(r.tokens) for r in reqs)
+    assert report['decoded_tokens'] == sum(new for _, new in shapes)
+    # one unbroken run: every dispatch but the first was sent ahead
+    assert eng.counts()['decode_dispatches_ahead'] == eng.interventions - 1
