@@ -52,6 +52,36 @@ Ties the whole PR-7..11 runway into live decode throughput:
   span was planned after the one before it had been read;
   ``counts()['decode_dispatches_ahead']`` and the ``ahead`` field of
   ``serve_step`` say how often a dispatch was sent ahead;
+- **spans that carry the work they send** (``telemetry.span``'s
+  arguments, on the host line of a profiler session's own file, so a
+  reader of a device trace counts the work of exactly the dispatches
+  its window holds, with no host counter read beside it; computed
+  only while a profiler session is open; PERF.md section 3 names the
+  metric that reads each):
+  ``serve.decode_dispatch``: ``dispatch`` (a running number),
+  ``batch`` (the bucket's rows), ``rows`` (the live ones), ``steps``
+  (the span), ``ahead`` (1 where a dispatch was still in flight as
+  this one was sent), and what its token steps read, counted step by
+  step as the module runs them (a row's length grows by one a step
+  for the steps sent to it; a row that ends on ``eos_id`` is counted
+  as if it ran on): ``kv_blocks``, a layer's KV blocks summed over the
+  steps (for a ``LayerGroupKVCache`` instead ``kv_blocks_read_full``
+  and ``kv_blocks_read_window``, a layer of each group), or for a
+  recurrent cache ``state_rows`` (live rows x steps);
+  ``serve.prefill_dispatch``: ``dispatch``, ``rows`` (requests in the
+  chunk), ``tokens`` (their true prompt positions), ``padded`` (the
+  bucket's positions of the chunk); ``serve.first_token_sync``: the
+  chunk's ``dispatch``; ``serve.absorb``: ``dispatch`` (the span whose
+  tokens it folds in), ``tokens`` (delivered), and a model's
+  ``step_stat_names`` counts of that span.  A dispatch's ``dispatch``
+  and the end of its absorb or sync (the device has run it by then)
+  tie it to its module execution on the device's own line: the
+  dispatches of one kind run in the order of their numbers.  The
+  host counters (``counts()``,
+  ``kv_blocks_read``, ``report()``) are as they were;
+  ``serve_step`` carries ``sync_ms`` (the host's wait in
+  ``serve.decode_sync`` and ``serve.first_token_sync``) and ``host_ms``
+  (the rest of the intervention);
 - **fused multi-step decode**: ``decode_span=K`` scans K decode steps
   inside one compiled module between scheduler interventions — the
   ROADMAP item-4 remainder lifted to the decode loop;
@@ -84,6 +114,7 @@ the reference path greedy engine output is bit-exact with sequential
 batch-1 generate — pinned by tests/test_engine_serving.py.
 """
 import collections
+import contextlib
 import json
 import math
 import statistics
@@ -112,6 +143,13 @@ def request_seed(rid, engine_seed):
     ops/sampling per-position key discipline does the rest)."""
     return (zlib.crc32(str(rid).encode()) ^ int(engine_seed)) \
         & 0x7FFFFFFF
+
+
+def _traced():
+    """Whether a profiler session is open: the spans' arguments are
+    computed only then (nobody reads them otherwise)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation.is_enabled()
 
 
 def _pow2_chain(lo, hi):
@@ -316,6 +354,11 @@ class ServingEngine:
         # was sent ahead of an unread one), and how many were
         self._in_flight = None
         self.decode_dispatches_ahead = 0
+        self._decode_sent = 0
+        # where the intervention began and how long the host waited in
+        # it for the device, on the machine's clock (`serve_step`)
+        self._t_step = time.monotonic()
+        self._sync_s = 0.0
         # the engine's own measurements, on the machine's clock: when
         # the device last finished something the host waited for, the
         # last lengths of a decode dispatch by its shape and of the
@@ -749,36 +792,42 @@ class ServingEngine:
 
     def _prefill_dispatch(self, reqs, ordinal):
         """Dispatch ONE batched prefill over a chunk of same-bucket
-        admissions (async), the engine's `ordinal`-th; the pools chain
-        through donation so back-to-back chunks pipeline on the
-        device.  Returns the un-synced first-token device array
-        [chunk bucket]."""
+        admissions (async), the engine's `ordinal`-th, inside its span
+        `serve.prefill_dispatch`; the pools chain through donation so
+        back-to-back chunks pipeline on the device.  Returns the
+        un-synced first-token device array [chunk bucket]."""
         import jax.numpy as jnp
+        from ..telemetry import span
         P = reqs[0].prompt_bucket
         B = self._chunk_bucket(len(reqs))
-        mod = self._prefill_module(P, B)
-        ids = np.zeros((B, P), np.int64)
-        t0s = np.ones((B,), np.int32)      # padding rows sample row 0
-        seeds = np.zeros((B,), np.int64)
-        for i, req in enumerate(reqs):
-            ids[i, :req.prompt.size] = req.prompt
-            t0s[i] = req.prompt.size
-            seeds[i] = req.seed or 0
-        # padding rows write where nothing is kept (the trash block)
-        where = self.cache.prefill_where([r.rid for r in reqs], B, P)
-        slots = np.full((B,), self.config.max_slots, np.int32)
-        slots[:len(reqs)] = [r.slot for r in reqs]
-        tok, first, second, self._last = mod(
-            self._params, self._buffers, jnp.asarray(ids),
-            jnp.asarray(t0s), *self.cache.arrays(), self._last,
-            jnp.asarray(where), jnp.asarray(slots), jnp.asarray(seeds))
-        self.cache.set_arrays((first, second))
-        now = self._clock()
-        for i, req in enumerate(reqs):
-            # a recurrent cache: the device row that holds the state
-            req.trace_note('prefill', now, bucket=P, chunk=B,
-                           dispatch=ordinal,
-                           slot=int(where[i]) if self.recurrent else None)
+        attrs = dict(dispatch=ordinal, rows=len(reqs),
+                     tokens=sum(r.prompt.size for r in reqs),
+                     padded=B * P) if _traced() else {}
+        with span('serve.prefill_dispatch', **attrs):
+            mod = self._prefill_module(P, B)
+            ids = np.zeros((B, P), np.int64)
+            t0s = np.ones((B,), np.int32)      # padding rows sample row 0
+            seeds = np.zeros((B,), np.int64)
+            for i, req in enumerate(reqs):
+                ids[i, :req.prompt.size] = req.prompt
+                t0s[i] = req.prompt.size
+                seeds[i] = req.seed or 0
+            # padding rows write where nothing is kept (the trash block)
+            where = self.cache.prefill_where([r.rid for r in reqs], B, P)
+            slots = np.full((B,), self.config.max_slots, np.int32)
+            slots[:len(reqs)] = [r.slot for r in reqs]
+            tok, first, second, self._last = mod(
+                self._params, self._buffers, jnp.asarray(ids),
+                jnp.asarray(t0s), *self.cache.arrays(), self._last,
+                jnp.asarray(where), jnp.asarray(slots), jnp.asarray(seeds))
+            self.cache.set_arrays((first, second))
+            now = self._clock()
+            for i, req in enumerate(reqs):
+                # a recurrent cache: the device row that holds the state
+                req.trace_note('prefill', now, bucket=P, chunk=B,
+                               dispatch=ordinal,
+                               slot=int(where[i]) if self.recurrent
+                               else None)
         return tok
 
     def _prefill_read(self, reqs, toks):
@@ -846,6 +895,9 @@ class ServingEngine:
                         prefilled=self._pending_prefilled,
                         discarded=self._pending_discarded,
                         dur_s=round(self._clock() - t_start, 6),
+                        sync_ms=round(self._sync_s * 1e3, 3),
+                        host_ms=round((time.monotonic() - self._t_step
+                                       - self._sync_s) * 1e3, 3),
                         **fields)
         self._pending_prefilled = 0
         self._pending_discarded = 0
@@ -923,7 +975,8 @@ class ServingEngine:
         sched = self.scheduler
         now = self._clock() if now is None else now
         t_start = self._clock()
-        t_plan = time.monotonic()
+        t_plan = self._t_step = time.monotonic()
+        self._sync_s = 0.0
         with span('serve.deadlines'):
             breached = sched.check_deadlines(now)
             self._note_finished(breached, now)
@@ -947,9 +1000,8 @@ class ServingEngine:
         admitted = len(fresh)
         firsts = []
         for chunk in chunks:
-            with span('serve.prefill_dispatch'):
-                firsts.append(self._prefill_dispatch(
-                    chunk, self._prefills + len(firsts) + 1))
+            ordinal = self._prefills + len(firsts) + 1
+            firsts.append((ordinal, self._prefill_dispatch(chunk, ordinal)))
         # a request of one token is whole once its prefill is sent
         sched.release_sent(fresh)
         with span('serve.reserve'):
@@ -966,7 +1018,13 @@ class ServingEngine:
         read, self._in_flight = self._in_flight, None
         sent = 0
         if plan is not None:
-            with span('serve.decode_dispatch'):
+            self._decode_sent += 1
+            ahead = int(read is not None)
+            attrs = dict(dispatch=self._decode_sent, batch=plan.batch,
+                         rows=len(plan.requests), steps=plan.span,
+                         ahead=ahead, **self.cache.span_reads(plan)) \
+                if _traced() else {}
+            with span('serve.decode_dispatch', **attrs):
                 toks_dev, valid_dev, stats_dev = self._decode(plan)
                 # what the dispatch reads of what its rows hold, before
                 # the rows that end in it give their blocks back
@@ -976,7 +1034,7 @@ class ServingEngine:
                 self._in_flight = {
                     'plan': plan, 'toks': toks_dev, 'valid': valid_dev,
                     'stats': stats_dev, 'kv': (kv_read, kv_table),
-                    'ahead': int(read is not None),
+                    'ahead': ahead, 'dispatch': self._decode_sent,
                     'sent_t': time.monotonic()}
             self._plan_s.append(time.monotonic() - t_plan)
             if self._prof is not None:
@@ -988,8 +1046,11 @@ class ServingEngine:
         n, finished = 0, []
         if read is not None:
             n, finished = self._collect(read)
-        for reqs, toks_dev in zip(chunks, firsts):
-            with span('serve.first_token_sync'):
+        # the sync ends once the device has run the chunk: a reader
+        # of the trace matches the chunk to its module execution by it
+        # (`benchmark/readers/span_args.py`, read_by)
+        for reqs, (ordinal, toks_dev) in zip(chunks, firsts):
+            with self._sync('serve.first_token_sync', dispatch=ordinal):
                 toks = self._await(toks_dev)
             self._prefill_read(reqs, toks)
         with span('serve.bookkeeping'):
@@ -1028,7 +1089,8 @@ class ServingEngine:
         requests; returns (tokens delivered, requests finished)."""
         from ..telemetry import span
         plan = flight['plan']
-        with span('serve.decode_sync'):
+        before = self.step_stats.copy() if _traced() else None
+        with self._sync('serve.decode_sync'):
             toks = self._await(flight['toks'], flight['sent_t'],
                                (plan.batch, plan.span))
             valid = np.asarray(flight['valid'])
@@ -1036,15 +1098,30 @@ class ServingEngine:
                 self.step_stats += np.asarray(stats['counts'],
                                               np.int64).sum(0)
                 self.step_taps = stats['taps']
-        with span('serve.absorb'):
+        attrs = {} if before is None else dict(
+            dispatch=flight['dispatch'],
+            **{k: int(v) for k, v in zip(self.step_stat_names,
+                                         self.step_stats - before)})
+        with span('serve.absorb', **attrs) as sp:
             finished, n = self.scheduler.absorb(plan, toks, valid)
+            if attrs:
+                sp.set(tokens=n)
         # every valid token is one live row's state rewritten
         flight['rows'] = int(valid.sum())
         return n, finished
 
+    @contextlib.contextmanager
+    def _sync(self, name, **attrs):
+        """The span `name`, a wait for the device: its length counts
+        into the intervention's `sync_ms`."""
+        from ..telemetry import span
+        t = time.monotonic()
+        with span(name, **attrs):
+            yield
+        self._sync_s += time.monotonic() - t
+
     def _book(self, flight, n, finished, admitted, preempted, t_start):
         """Count a dispatch that was read, and emit its serve_step."""
-        from .. import telemetry
         plan = flight['plan']
         kv_read, kv_table = flight['kv']
         self.decoded_tokens += n
@@ -1060,7 +1137,6 @@ class ServingEngine:
             finished=finished, preempted=preempted,
             kv_blocks_read=kv_read, kv_blocks_table=kv_table,
             ahead=flight['ahead'])
-        telemetry.add('serve.decoded_tokens', n)
 
     def drain(self):
         """Read, absorb and book the decode dispatch in flight, if
@@ -1072,6 +1148,7 @@ class ServingEngine:
             return 0
         from ..telemetry import span
         t_start = self._clock()
+        self._t_step, self._sync_s = time.monotonic(), 0.0
         with span('serve.step'):
             n, finished = self._collect(flight)
             with span('serve.bookkeeping'):

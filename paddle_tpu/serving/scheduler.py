@@ -219,6 +219,19 @@ class DecodePlan:
         read = np.minimum(-(-ends // block_size), width)
         return int(read.sum()), self.batch * width
 
+    def step_lengths(self):
+        """[span, batch]: the length each row attends at each token
+        step as the decode module runs this plan: an active row's grows
+        by one a step for the steps sent to it and then stands, every
+        other row's is its context and the token just written (a row
+        that ends on EOS is taken to run on: no plan can know it).
+        `kv_blocks` counts every step at the span's end instead, up to
+        one block a row and step more."""
+        sent = np.zeros((self.batch,), np.int64)
+        sent[:len(self.sent)] = self.sent
+        steps = np.arange(self.span)[:, None]
+        return self.ctx + np.minimum(steps, sent) + 1
+
 
 class ContinuousBatchingScheduler:
     """Admission/eviction policy over a :class:`PagedKVCache`.

@@ -213,14 +213,16 @@ def enabled():
 class Span:
     """``with span('serve.plan', rid=...):`` — the program's ONE span
     (``telemetry.span`` and ``profiler.RecordEvent`` are this class).
-    Always a ``jax.profiler.TraceAnnotation(name)`` for its extent: a
-    disabled TraceMe until a profiler session opens, then an event on
-    the host line of the device trace's own file; that session is the
-    only switch.  A record (``start``/``end`` in seconds on the
-    recorder's clock, ``id``, ``parent_id``, the inherited ``rid``;
-    closed into ``span_stats`` and one ``span`` event) is kept only
-    when telemetry is enabled or the span was opened on a ``Recorder``:
-    off, no lock, no dict, no event."""
+    Always a ``jax.profiler.TraceAnnotation(name, **attrs)`` for its
+    extent: a disabled TraceMe until a profiler session opens, then an
+    event on the host line of the device trace's own file, its
+    ``attrs`` (plain ints and floats) the event's arguments; that
+    session is the only switch.  ``set(**attrs)`` adds arguments known
+    only inside the span.  A record (``start``/``end`` in seconds on
+    the recorder's clock, ``id``, ``parent_id``, the inherited ``rid``,
+    the attrs; closed into ``span_stats`` and one ``span`` event) is
+    kept only when telemetry is enabled or the span was opened on a
+    ``Recorder``: off, no lock, no event."""
     __slots__ = ('name', 'rid', 'attrs', 'id', 'parent_id', 'start',
                  'end', '_rec', '_ann')
 
@@ -231,7 +233,7 @@ class Span:
 
     def __enter__(self):
         from jax.profiler import TraceAnnotation    # a dict lookup
-        self._ann = TraceAnnotation(self.name)
+        self._ann = TraceAnnotation(self.name, **self.attrs)
         self._ann.__enter__()
         rec = self._rec
         if rec is None and enabled():
@@ -246,6 +248,11 @@ class Span:
             stack.append(self)
             self.start = _MONO() - rec._t0
         return self
+
+    def set(self, **attrs):
+        """Arguments of the open span that its body computed."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
 
     def __exit__(self, *exc):
         if self._rec is not None:
