@@ -27,7 +27,8 @@ and `x0 = embed[ids] * sqrt(hidden)` before layer 0 (`mup_enabled`).
 
 What is shared with `routed_window.py`, as that module's functions and
 Layers: the expert product (`chosen_experts`: a prefill's grouped
-program, a decode step's dense one, the counts), the router's float32
+program, a decode step's dense one, the counts), the sigmoid choice
+(`sigmoid_top_k`, which `models/joyai.py` takes too), the router's float32
 product (`router_logits`: an eighth choice of 128 stands as close to
 the ninth as a sixth of 64 to the seventh), the three ways of
 attention (`attend`), `RoutedExperts`, the serving entry points
@@ -46,19 +47,17 @@ layers only.
 import math
 
 import jax
-import jax.numpy as jnp
 
 from .. import nn
 from ..nn import initializer as init
-from .decoder_parts import (F32, GatedMLP, GroupedProjections, Dense,
+from .decoder_parts import (GatedMLP, GroupedProjections, Dense,
                             RMSNorm, gated_mlp, matmul, project_heads,
                             rms_norm, sub)
 from .routed_window import (RoutedExperts, RoutedWindowForCausalLM, _Table,
                             attend, chosen_experts, grouped_path,
-                            router_logits)
+                            router_logits, sigmoid_top_k)
 
-__all__ = ['AfmoeConfig', 'AfmoeForCausalLM', 'afmoe_tiny',
-           'sigmoid_top_k']
+__all__ = ['AfmoeConfig', 'AfmoeForCausalLM', 'afmoe_tiny']
 
 
 class AfmoeConfig:
@@ -106,17 +105,6 @@ class AfmoeConfig:
         self.rms_norm_eps = float(rms_norm_eps)
         self.initializer_range = initializer_range
         self.dtype = dtype
-
-
-def sigmoid_top_k(logits, bias, k, scale):
-    """Sigmoid scores of every logit in float32; the k largest of
-    score + `bias` are chosen (the bias moves the choice only); the
-    chosen scores, renormalised to sum 1 and scaled:
-    `(top_i [T, k], w [T, k])`."""
-    s = jax.nn.sigmoid(logits.astype(F32))
-    _, top_i = jax.lax.top_k(s + bias.astype(F32), k)
-    top_s = jnp.take_along_axis(s, top_i, axis=-1)
-    return top_i, scale * top_s / (top_s.sum(-1, keepdims=True) + 1e-20)
 
 
 def gate_and_project(p, attended, h):

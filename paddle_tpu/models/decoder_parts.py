@@ -52,15 +52,22 @@ def rms_norm(x, weight, eps):
     return (y * weight.astype(F32)).astype(x.dtype)
 
 
-def rotary(x, positions, theta):
-    """Rotary positions, the rotate-half form, in float32.
-    x [B, T, H, d], positions [B, T] (absolute)."""
+def rotary(x, positions, theta, interleaved=False):
+    """Rotary positions in float32.  x [B, T, H, d], positions [B, T]
+    (absolute).  The rotate-half form pairs lane i with i + d/2;
+    `interleaved` pairs lanes (2i, 2i + 1) and rotates them in place
+    (a config's `rope_interleave`), frequency i either way."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
     ang = positions.astype(F32)[..., None] * inv             # [B,T,d/2]
+    x = x.astype(F32)
+    if interleaved:
+        cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
+        even, odd = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
+                         -1).reshape(x.shape)
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None]
-    x = x.astype(F32)
     x1, x2 = x[..., :d // 2], x[..., d // 2:]
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 

@@ -78,7 +78,8 @@ from .decoder_parts import Dense, RMSNorm, matmul, rms_norm, rotary, sub
 
 __all__ = ['RoutedWindowConfig', 'RoutedWindowForCausalLM',
            'routed_window_tiny', 'routed_experts', 'chosen_experts',
-           'top_k_softmax', 'router_logits', 'attend', 'attend_whole']
+           'top_k_softmax', 'sigmoid_top_k', 'router_logits', 'attend',
+           'attend_whole']
 
 F32 = jnp.float32
 STEP_STATS = ('moe_assignments', 'moe_experts_hit', 'moe_max_load')
@@ -155,6 +156,17 @@ def top_k_softmax(logits, k):
     return top_i, jax.nn.softmax(top_v.astype(F32), axis=-1)
 
 
+def sigmoid_top_k(logits, bias, k, scale):
+    """Sigmoid scores of every logit in float32; the k largest of
+    score + `bias` are chosen (the bias moves the choice only); the
+    chosen scores, renormalised to sum 1 and scaled:
+    `(top_i [T, k], w [T, k])`.  (The Trinity and JoyAI routers.)"""
+    s = jax.nn.sigmoid(logits.astype(F32))
+    _, top_i = jax.lax.top_k(s + bias.astype(F32), k)
+    top_s = jnp.take_along_axis(s, top_i, axis=-1)
+    return top_i, scale * top_s / (top_s.sum(-1, keepdims=True) + 1e-20)
+
+
 def routed_experts(p, h2, logits, k, *, grouped, active=None):
     """This model's routed layer: the k largest of `logits [T,
     experts]` (the router's, of the layer's normed INPUT), softmax
@@ -165,7 +177,8 @@ def routed_experts(p, h2, logits, k, *, grouped, active=None):
                           grouped=grouped, active=active)
 
 
-def chosen_experts(p, h2, top_i, w, *, activation, grouped, active=None):
+def chosen_experts(p, h2, top_i, w, *, activation, grouped, active=None,
+                   held=None):
     """sum_j w[:, j] expert top_i[:, j] of rows `h2 [T, hidden]`,
     [T, hidden] float32, and the layer's counts: assignments made,
     distinct experts hit and the largest expert's load, over the rows
@@ -176,14 +189,31 @@ def chosen_experts(p, h2, top_i, w, *, activation, grouped, active=None):
     `down_proj` [experts, width, hidden]; an expert is
     down(act(gate x) * (up x)) with `activation` naming act ('relu',
     'silu').  `grouped` picks the program (this file's header), never
-    the mathematics."""
+    the mathematics.
+
+    `held` (first, count): the layer holds experts [first, first +
+    count) of the ones the router chose among, as one chip of an
+    expert-parallel group does, and `p` holds those alone.  An
+    assignment to an expert held elsewhere is neither computed nor
+    counted: the result is the part this chip's experts give (a grouped
+    product sorts such an assignment behind the last expert, as it does
+    a pad row's).  None, the default: every expert is here, and the
+    program is what it was without the argument."""
     T, k = top_i.shape
     wg, wu, wd = p['gate_proj'], p['up_proj'], p['down_proj']
     E = wg.shape[0]
     gated = _epilogue(activation)
+    if held is not None:
+        with jax.named_scope('moe.dispatch'):
+            # this chip's index of a held expert; E (no expert here)
+            # for the others, whose weight is zero
+            local = top_i - int(held[0])
+            here = (local >= 0) & (local < E)
+            top_i = jnp.where(here, local, E)
+            w = jnp.where(here, w, 0.0)
     with jax.named_scope('moe.dispatch'):
         hit = jnp.zeros((T, E), jnp.int32).at[
-            jnp.arange(T)[:, None], top_i].set(1)
+            jnp.arange(T)[:, None], top_i].set(1, mode='drop')
         if active is not None:
             hit = hit * active.astype(jnp.int32)[:, None]
         load = hit.sum(0)
@@ -193,7 +223,7 @@ def chosen_experts(p, h2, top_i, w, *, activation, grouped, active=None):
         return _grouped(x, top_i, w, wg, wu, wd, active, activation), stats
     with jax.named_scope('moe.dispatch'):
         mix = jnp.zeros((T, E), F32).at[
-            jnp.arange(T)[:, None], top_i].set(w)
+            jnp.arange(T)[:, None], top_i].set(w, mode='drop')
     with jax.named_scope('moe.experts'):
         # [1, T, h] @ [E, h, f] (as an einsum XLA's CPU backend merges
         # the two products into a bfloat16 dot its runtime lacks)
@@ -218,7 +248,9 @@ def _grouped(x, top_i, w, wg, wu, wd, active, activation):
     """The expert product of rows x [T, hidden] (in the weights'
     dtype) routed to `top_i` [T, k] with weights `w` [T, k]: rows
     sorted by expert, each expert's rows against its matrices once;
-    rows that are not `active` behind the last expert, in no group."""
+    rows that are not `active`, and assignments `top_i` names E for
+    (an expert not held here), behind the last expert, in no group:
+    the product leaves zeros there."""
     (T, k), E = top_i.shape, wg.shape[0]
     with jax.named_scope('moe.dispatch'):
         if active is not None:
@@ -327,12 +359,14 @@ class PlainProjections(nn.Layer):
 
 
 class RoutedExperts(nn.Layer):
-    """`num_experts` ReGLU experts, stacked: gate and up
-    [experts, hidden, width], down [experts, width, hidden]."""
+    """`num_experts` experts, or the `count` a layer holds of them,
+    stacked: gate and up [experts, hidden, width], down [experts,
+    width, hidden]."""
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, count=None):
         super().__init__()
-        E, h, f = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+        E, h, f = (count or cfg.num_experts, cfg.hidden_size,
+                   cfg.intermediate_size)
         normal = init.Normal(0.0, cfg.initializer_range)
         self.gate_proj = self.create_parameter(
             (E, h, f), dtype=cfg.dtype, default_initializer=normal)
