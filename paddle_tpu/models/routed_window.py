@@ -46,7 +46,10 @@ rows an expert at 12k tokens, compute-bound), and through
 mesh, other dtypes and widths).  The bucket's pad positions (`active`
 false) sort behind the last expert, are multiplied by nothing and come
 back as zeros: prompts are padded on the right and attention is causal,
-so no true position ever read one.  A decode step's few rows (32 rows
+so no true position ever read one.  Back in the tokens' order, the
+sort's inverse comes from a scatter and each token's k results are
+gathered with k the major axis and summed over it, so the combine
+relays nothing out (`_grouped`).  A decode step's few rows (32 rows
 hit 95% of 64 experts) take a dense product over all experts, weighted
 by a [rows, experts] matrix that is zero off the chosen ones: bound by
 the same bytes, the experts' weights, and no sort in the token step.
@@ -244,13 +247,24 @@ def grouped_path(rows, wg, wd):
         and gm.can_use_pallas(rows, wd) else 'ragged_dot'
 
 
+def _inverse(order):
+    """The inverse of the permutation `order`, `argsort(order)`, by one
+    scatter."""
+    return jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype), unique_indices=True)
+
+
 def _grouped(x, top_i, w, wg, wu, wd, active, activation):
     """The expert product of rows x [T, hidden] (in the weights'
     dtype) routed to `top_i` [T, k] with weights `w` [T, k]: rows
-    sorted by expert, each expert's rows against its matrices once;
-    rows that are not `active`, and assignments `top_i` names E for
-    (an expert not held here), behind the last expert, in no group:
-    the product leaves zeros there."""
+    sorted by expert (one stable sort), each expert's rows against its
+    matrices once; rows that are not `active`, and assignments `top_i`
+    names E for (an expert not held here), behind the last expert, in
+    no group: the product leaves zeros there.  The combine takes the
+    sort's inverse by a scatter, not a second sort, gathers the
+    product's rows k-major ([k, T, hidden] float32) and sums
+    `w[:, j]` times them over that major axis, j = 0 ... k-1: no
+    [T, k, hidden] relayout, whose k axis the (8, 128) tiling pads."""
     (T, k), E = top_i.shape, wg.shape[0]
     with jax.named_scope('moe.dispatch'):
         if active is not None:
@@ -272,11 +286,10 @@ def _grouped(x, top_i, w, wg, wu, wd, active, activation):
             y = jax.lax.ragged_dot(_epilogue(activation)(g, u, wd.dtype),
                                    wd, sizes, preferred_element_type=F32)
     with jax.named_scope('moe.dispatch'):
-        # back to the tokens' own order, then each token's k in the
-        # order its router chose them: a row's sum does not depend on
-        # the rows around it
-        y = y[jnp.argsort(order)].reshape(T, k, -1)
-        out = (y * w[:, :, None]).sum(1)
+        # k the major axis never lands on the sublanes, so nothing is
+        # relaid out; a row's sum does not depend on the rows around it
+        inv = _inverse(order).reshape(T, k)
+        out = (w.T[:, :, None] * y[inv.T]).sum(0)
         if active is not None:
             # whatever a product left behind its last group
             out = jnp.where(active[:, None], out, 0.0)
