@@ -16,13 +16,17 @@ AOT-compiles the whole surface at deploy time.
 
 A model whose memory is one recurrent state a sequence
 (``models/retention.py``: ``serving_state = 'recurrent'``) is served
-by the same engine over a ``RecurrentStateCache``.
+by the same engine over a ``RecurrentStateCache``, and one whose layers
+keep either a state or paged keys and values
+(``models/granite_hybrid.py``: ``serving_state = 'hybrid'``) over a
+``HybridCache``, a slot of state and paged blocks a sequence.
 
 Additive: ``GPTForCausalLM.generate`` is unchanged (and bit-exact
 with the engine's greedy decode by test).
 """
 from .kv_cache import (                              # noqa: F401
-    PagedKVCache, PagedCacheView, RecurrentStateCache, RecurrentStateView)
+    HybridCache, PagedKVCache, PagedCacheView, RecurrentStateCache,
+    RecurrentStateView)
 from .scheduler import (                             # noqa: F401
     ContinuousBatchingScheduler, DecodePlan, Request, RejectReason,
     RejectedRequest)
@@ -31,7 +35,7 @@ from .engine import (                                # noqa: F401
     DecodeAuditLayer, ServeConfig, ServingEngine, request_seed)
 
 __all__ = ['PagedKVCache', 'PagedCacheView', 'RecurrentStateCache',
-           'RecurrentStateView', 'Request', 'DecodePlan',
+           'RecurrentStateView', 'HybridCache', 'Request', 'DecodePlan',
            'ContinuousBatchingScheduler', 'poisson_requests',
            'ServeConfig', 'ServingEngine', 'DecodeAuditLayer',
            'RejectReason', 'RejectedRequest', 'request_seed']

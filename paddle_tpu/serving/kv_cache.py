@@ -10,10 +10,13 @@ pool below, blocks that grow with a sequence's length;
 `LayerGroupKVCache` after it, the same for a model that mixes full and
 window attention layers on grouped query heads, two groups of layers
 with a pool, a table and a free list each; `LatentKVCache`, one pool a
-layer of a latent-attention model's compressed latents; and
-`RecurrentStateCache` at the end of this file, one state of fixed size
-a sequence. The model says which it needs (`model.serving_state`, and
-for the groups and the latent pool `model.cache_spec()`).
+layer of a latent-attention model's compressed latents;
+`RecurrentStateCache`, one state of fixed size a sequence; and
+`HybridCache` at the end of this file, both at once for a model whose
+layers are of the two kinds (a slot of state and paged blocks a
+sequence).  The model says which it needs (`model.serving_state`, and
+for the groups, the latent pool and the hybrid `model.cache_spec()`);
+`cache_for` builds it.
 
 The serving engine never allocates per-sequence KV buffers.  Instead
 each layer owns ONE device pool ``[num_blocks, block_size, num_heads,
@@ -43,6 +46,7 @@ Sharding: pools carry their heads on the ``tp`` mesh axis
 the attention weights, applied by the engine's compiled steps via
 ``maybe_shard`` when a mesh is installed.
 """
+import math
 import types
 
 import jax
@@ -52,7 +56,7 @@ import numpy as np
 __all__ = ['PagedKVCache', 'PagedCacheView', 'LayerGroupKVCache',
            'GroupedCacheView', 'PrefillKV', 'LatentKVCache',
            'LatentCacheView', 'RecurrentStateCache', 'RecurrentStateView',
-           'TRASH_BLOCK', 'blocks_for']
+           'HybridCache', 'cache_for', 'TRASH_BLOCK', 'blocks_for']
 
 TRASH_BLOCK = 0
 
@@ -128,6 +132,10 @@ class PagedKVCache:
     owned-block lists.  Allocation never partially succeeds: asking
     for more blocks than are free changes nothing and returns False.
     """
+
+    # the kinds of per-sequence memory the cache keeps, 'kv' and
+    # 'state' (what the engine's report asks of it)
+    kinds = ('kv',)
 
     def __init__(self, num_layers, num_heads, head_dim, *,
                  block_size, num_blocks, dtype=None, device_init=True):
@@ -526,6 +534,7 @@ class LayerGroupKVCache:
     by `LatentKVCache` below, not by this class.
     """
 
+    kinds = ('kv',)
     table_groups = 2
     FULL, WINDOW = 0, 1
 
@@ -1002,25 +1011,39 @@ class LatentKVCache(PagedKVCache):
 class RecurrentStateView:
     """One layer's recurrent state as a compiled module sees it.
 
-    In a decode step: the whole arrays `S [slots, ...]` and `z`, the
-    rows' `slots` (distinct) and which rows are `active`.  In a
-    prefill: `S` and `z` are None on the way in (the empty state) and
-    the rows' final states on the way out, `lengths` the rows' true
-    lengths.  `updated()` is the functional write-back.
+    In a decode step: the layer's whole state arrays `states` (`S [slots,
+    ...]` and the rest, in the order the cache's `shapes` state them),
+    the rows' `slots` (distinct) and which rows are `active`.  In a
+    prefill: `states` is None on the way in (the empty state) and the
+    rows' final states on the way out, `lengths` the rows' true lengths.
+    `updated()` is the functional write-back; `taps` (None on the way
+    in) are tensors a row of what the layer computed, which a decode
+    module hands out for the cache's tapped layers (`GroupedCacheView`'s
+    kind).  `S` and `z` name the first two arrays (power retention's
+    pair).
     """
 
-    def __init__(self, S=None, z=None, slots=None, active=None,
-                 lengths=None):
-        self.S, self.z = S, z
+    def __init__(self, states=None, slots=None, active=None, lengths=None,
+                 taps=None):
+        self.states = None if states is None else tuple(states)
         self.slots, self.active, self.lengths = slots, active, lengths
+        self.taps = taps
 
-    def updated(self, S, z):
-        return RecurrentStateView(S, z, self.slots, self.active,
-                                  self.lengths)
+    @property
+    def S(self):
+        return None if self.states is None else self.states[0]
+
+    @property
+    def z(self):
+        return None if self.states is None else self.states[1]
+
+    def updated(self, *states, taps=None):
+        return RecurrentStateView(states, self.slots, self.active,
+                                  self.lengths, taps)
 
     def tree_flatten(self):
-        return ((self.S, self.z, self.slots, self.active,
-                 self.lengths), None)
+        return ((self.states, self.slots, self.active, self.lengths,
+                 self.taps), None)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -1029,9 +1052,13 @@ class RecurrentStateView:
 
 
 class RecurrentStateCache:
-    """Per layer `S [slots, kv heads, value_dim, features]` and
-    `z [slots, kv heads, features]`, float32 (an accumulator over the
-    whole sequence), one slot a live sequence whatever its length.
+    """Per layer one array a state the model states, `[slots, *shape]`
+    float32 (an accumulator over the whole sequence), one slot a live
+    sequence whatever its length.  `shapes` is one slot's shape of each
+    array, in the order the model's views carry them; power retention's
+    pair, `S [kv heads, value_dim, features]` and `z [kv heads,
+    features]`, is what the four-number form `(num_layers, num_kv_heads,
+    value_dim, features)` states (`models/retention.py::state_spec`).
 
     It answers the calls the scheduler makes of the paged pool, with
     one "block" a sequence: `ensure` succeeds once a slot is held, so
@@ -1039,29 +1066,33 @@ class RecurrentStateCache:
     Ids run 1..slots as block ids do (0 is what a plan's padding rows
     carry, `TRASH_BLOCK`; no memory stands behind it): the device row
     of id `i` is `i - 1`.  A slot is overwritten whole by the prefill
-    that takes it, so a freed slot is not zeroed.
+    that takes it, so a freed slot is not zeroed.  (`HybridCache`
+    holds one beside a paged pool, and preempts by the pool.)
     """
 
     # no parameter: nothing serves another dtype yet, and a benchmark
     # cell's probe holds the state to this one (its `state_rel_tol`)
     dtype = jnp.float32
+    kinds = ('state',)
 
-    def __init__(self, num_layers, num_kv_heads, value_dim, features, *,
-                 slots, max_model_len, device_init=True):
+    def __init__(self, num_layers, num_kv_heads=None, value_dim=None,
+                 features=None, *, slots, max_model_len, shapes=None,
+                 device_init=True):
         self.num_layers = int(num_layers)
-        self.num_kv_heads = int(num_kv_heads)
-        self.value_dim = int(value_dim)
-        self.features = int(features)
+        # power retention's sizes (None where `shapes` are given)
+        self.num_kv_heads, self.value_dim, self.features = (
+            num_kv_heads, value_dim, features)
+        if shapes is None:
+            shapes = ((num_kv_heads, value_dim, features),
+                      (num_kv_heads, features))
+        self.shapes = tuple(tuple(int(n) for n in s) for s in shapes)
         self.slots = int(slots)
         # one "block" covers a whole sequence
         self.block_size = int(max_model_len)
         self.num_blocks = self.slots + 1
         if device_init:
-            s_shape = (self.slots, self.num_kv_heads, self.value_dim,
-                       self.features)
-            self.states = [(jnp.zeros(s_shape, self.dtype),
-                            jnp.zeros(s_shape[:2] + s_shape[3:],
-                                      self.dtype))
+            self.states = [tuple(jnp.zeros((self.slots,) + s, self.dtype)
+                                 for s in self.shapes)
                            for _ in range(self.num_layers)]
         else:
             self.states = None
@@ -1071,8 +1102,8 @@ class RecurrentStateCache:
 
     @property
     def bytes_per_slot(self):
-        return self.num_layers * self.num_kv_heads * self.features \
-            * (self.value_dim + 1) * jnp.dtype(self.dtype).itemsize
+        return self.num_layers * sum(math.prod(s) for s in self.shapes) \
+            * jnp.dtype(self.dtype).itemsize
 
     @property
     def state_bytes(self):
@@ -1167,8 +1198,9 @@ class RecurrentStateCache:
 
     # -- device side ----------------------------------------------------------
     def arrays(self):
-        Ss, zs = (tuple(x) for x in zip(*self.states))
-        return Ss, zs
+        """One tuple a state array, of every layer's: (Ss, zs) for power
+        retention."""
+        return tuple(tuple(x) for x in zip(*self.states))
 
     def set_arrays(self, arrays):
         self.states = list(zip(*arrays))
@@ -1182,16 +1214,24 @@ class RecurrentStateCache:
         where[:len(seq_ids)] = [self._owner[sid] - 1 for sid in seq_ids]
         return where
 
-    def decode_where(self, plan):
-        """Device rows [batch] of a decode plan, DISTINCT (the decode
-        update rewrites each row's slot in place): a padding row names
-        a slot no live sequence holds, which its inactive update
-        leaves as it was."""
-        ids = [int(t) for t in plan.tables[:len(plan.requests), 0]]
+    def state_row(self, where, i):
+        """The device row that holds the state of a prefill's `i`-th
+        sequence (what the engine notes on its trail)."""
+        return int(where[i])
+
+    def rows_of(self, seq_ids, batch):
+        """Device rows [batch] of a decode batch, DISTINCT (the decode
+        update rewrites each row's slot in place): the sequences' own,
+        and for a padding row a slot no live sequence holds, which its
+        inactive update leaves as it was."""
+        ids = [self._owner[sid] for sid in seq_ids]
         held = set(ids)
         spare = (s for s in range(1, self.slots + 1) if s not in held)
-        ids += [next(spare) for _ in range(plan.batch - len(ids))]
+        ids += [next(spare) for _ in range(batch - len(ids))]
         return np.asarray(ids, np.int32) - 1
+
+    def decode_where(self, plan):
+        return self.rows_of([r.rid for r in plan.requests], plan.batch)
 
     def idle_where(self, batch, width):
         del width
@@ -1201,14 +1241,10 @@ class RecurrentStateCache:
     layout_key = 'slot,head,value,feature'
 
     def decode_path(self, model, batch, width):
-        """'kernel' or 'plain': the gate retention_decode asks."""
-        from ..ops.power_retention import can_use_pallas
+        """'kernel' or 'plain': the gate of the model's state update
+        (`model.state_path`)."""
         del width
-        cfg = model.config
-        return 'kernel' if can_use_pallas(
-            self.states[0][0], jax.ShapeDtypeStruct(
-                (batch, cfg.num_heads, cfg.head_dim), jnp.float32)) \
-            else 'plain'
+        return model.state_path(self.states[0], batch)
 
     def prefill_caches(self, model, rows, bucket, lengths):
         del model, rows, bucket
@@ -1216,21 +1252,341 @@ class RecurrentStateCache:
                 for _ in range(self.num_layers)]
 
     def store_prefill(self, arrays, caches, where):
-        """Each row's final (S, z) into its slot, whole."""
-        Ss, zs = arrays
+        """Each row's final states into its slot, whole."""
         with jax.named_scope('state.write'):
-            return (tuple(S.at[where].set(c.S.astype(S.dtype), mode='drop')
-                          for S, c in zip(Ss, caches)),
-                    tuple(z.at[where].set(c.z.astype(z.dtype), mode='drop')
-                          for z, c in zip(zs, caches)))
+            return tuple(
+                tuple(a.at[where].set(c.states[j].astype(a.dtype),
+                                      mode='drop')
+                      for a, c in zip(arrays[j], caches))
+                for j in range(len(self.shapes)))
 
     def constrain(self, arrays):
         return arrays
 
     def decode_views(self, arrays, where, ctx, active):
         del ctx
-        return [RecurrentStateView(S, z, where, active)
-                for S, z in zip(*arrays)]
+        return [RecurrentStateView(states, where, active)
+                for states in zip(*arrays)]
 
     def arrays_of(self, views):
-        return tuple(v.S for v in views), tuple(v.z for v in views)
+        return tuple(tuple(v.states[j] for v in views)
+                     for j in range(len(self.shapes)))
+
+
+# -- a recurrent state AND paged keys and values a sequence ------------------------
+class HybridCache:
+    """The cache of a model whose layers are of two kinds
+    (`model.cache_spec()['layer_kinds']`, `models/granite_hybrid.py`):
+    'state' layers, which keep a recurrent state of fixed size a
+    sequence (a `RecurrentStateCache` of the shapes the model states,
+    one slot a sequence), and 'kv' layers on grouped query heads, which
+    keep keys and values a position in a paged pool (`PagedKVCache`'s
+    allocator; one pool a layer, the heads folded into the lanes as
+    `LayerGroupKVCache`'s, `[num_blocks, block_size, kv heads *
+    head_dim]`, read by `paged_decode_grouped`; one table a sequence).
+
+    One sequence holds both at once: `ensure` gives it a slot and the
+    blocks of its span, or neither (a slot it took for the call goes
+    back when the blocks cannot be had); `free_seq` frees both, so a
+    preempted sequence gives back its slot and its blocks and is
+    recomputed from its tokens when it is admitted again.  The pool is
+    what fills, so it is what preempts: the slots are as many as the
+    scheduler's rows.  `owned()`, `block_size`, `num_blocks`,
+    `free_blocks` and `high_water_blocks` are the pool's (the scheduler
+    reads them); `free_slots` counts the slots no sequence holds.
+    `groups` and `window` answer a reader of two-group caches as
+    `LatentKVCache`'s do: the pool, no window group, no window.  A
+    prefill's block past what a sequence holds names the trash block,
+    where its scatter drops the bucket's pads (`LatentKVCache`'s rule).
+
+    On the device the engine's modules carry `((k pools, v pools),
+    (state arrays))`: the first of the pair the kv layers', the second
+    the state layers' as `RecurrentStateCache.arrays` has them; `where`
+    is likewise a pair, (block ids or tables, state rows), and
+    `decode_views` hands each layer the view of its kind, in layer
+    order.  A prefill hands out what the first tapped layer computed
+    (`prefill_taps`), a decode step what every tapped layer did
+    (`step_stats`)."""
+
+    kinds = ('state', 'kv')
+    window = None
+    path_key = 'hybrid'
+    layout_key = 'state,slot,...;block,position,head*dim'
+
+    def __init__(self, layer_kinds, state_shapes, num_kv_heads, head_dim,
+                 *, block_size, num_blocks, slots, max_model_len,
+                 tap_layers=(), dtype=None, device_init=True):
+        self.layer_kinds = tuple(layer_kinds)
+        if set(self.layer_kinds) != {'state', 'kv'}:
+            raise ValueError(f'layers of two kinds, state and kv, not '
+                             f'{sorted(set(self.layer_kinds))}')
+        self.num_layers = len(self.layer_kinds)
+        self.tap_layers = tuple(int(i) for i in tap_layers)
+        self.num_kv_heads, self.head_dim = int(num_kv_heads), int(head_dim)
+        self.block_size = int(block_size)
+        self.dtype = dtype or jnp.float32
+        self.kv = PagedKVCache(0, self.num_kv_heads, self.head_dim,
+                               block_size=block_size,
+                               num_blocks=num_blocks, device_init=False)
+        self.state = RecurrentStateCache(
+            self.layer_kinds.count('state'), slots=slots,
+            max_model_len=max_model_len, shapes=state_shapes,
+            device_init=device_init)
+        self.groups = (self.kv, types.SimpleNamespace(num_blocks=1,
+                                                      high_water_blocks=0))
+        if device_init:
+            shape = (self.kv.num_blocks, self.block_size,
+                     self.num_kv_heads * self.head_dim)
+            self.pools = [(jnp.zeros(shape, self.dtype),
+                           jnp.zeros(shape, self.dtype))
+                          for _ in range(self.layer_kinds.count('kv'))]
+        else:
+            self.pools = None
+
+    @property
+    def slots(self):
+        return self.state.slots
+
+    @property
+    def state_bytes(self):
+        return self.state.state_bytes
+
+    @property
+    def bytes_per_slot(self):
+        return self.state.bytes_per_slot
+
+    @property
+    def pool_bytes(self):
+        return self.layer_kinds.count('kv') * self.kv.num_blocks \
+            * 2 * self.block_size * self.num_kv_heads * self.head_dim \
+            * jnp.dtype(self.dtype).itemsize
+
+    # -- allocator ----------------------------------------------------------
+    @property
+    def num_blocks(self):
+        return self.kv.num_blocks
+
+    @property
+    def free_blocks(self):
+        return self.kv.free_blocks
+
+    @property
+    def free_slots(self):
+        return self.state.free_blocks
+
+    @property
+    def high_water_blocks(self):
+        return self.kv.high_water_blocks
+
+    def owned(self, seq_id):
+        return self.kv.owned(seq_id)
+
+    def owners(self):
+        return sorted(set(self.kv.owners()) | set(self.state.owners()))
+
+    def holds(self, bucket, limit):
+        del bucket              # pads are dropped on the trash block
+        return blocks_for(limit, self.block_size) <= self.kv.num_blocks - 1
+
+    def prefill_positions(self, bucket, need):
+        del bucket
+        return need
+
+    def ensure(self, seq_id, num_positions, written=None):
+        """A slot and the blocks that cover `num_positions`, or neither:
+        a slot taken for this call goes back when the blocks cannot be
+        had (one the sequence held before stays)."""
+        del written
+        fresh = not self.state.owned(seq_id)
+        if not self.state.ensure(seq_id, num_positions):
+            return False
+        if self.kv.ensure(seq_id, num_positions):
+            return True
+        if fresh:
+            self.state.free_seq(seq_id)
+        return False
+
+    def free_seq(self, seq_id):
+        return self.kv.free_seq(seq_id) + self.state.free_seq(seq_id)
+
+    def table_row(self, seq_id, width):
+        return self.kv.table_row(seq_id, width)
+
+    def frag_report(self):
+        return self.kv.frag_report()
+
+    def audit(self):
+        """Both allocators' invariants, and this class's own: a sequence
+        holds a slot if and only if it holds blocks."""
+        problems = [f'{name}: {p}' for name, part in
+                    (('blocks', self.kv), ('slots', self.state))
+                    for p in part.audit()]
+        blocks, slots = set(self.kv.owners()), set(self.state.owners())
+        for sid in sorted(blocks ^ slots):
+            problems.append(f'seq {sid} holds '
+                            + ('blocks and no slot' if sid in blocks
+                               else 'a slot and no blocks'))
+        return problems
+
+    def kv_blocks(self, plan):
+        return plan.kv_blocks(self.block_size)
+
+    def span_reads(self, plan):
+        """What the decode dispatch of `plan` reads and rewrites: a kv
+        layer's blocks summed over the steps (`PagedKVCache`'s), and the
+        live rows x steps whose states it rewrites."""
+        return {'kv_blocks': blocks_read(plan.step_lengths(),
+                                         self.block_size,
+                                         plan.tables.shape[-1]),
+                'state_rows': int(sum(plan.sent))}
+
+    # -- device side ----------------------------------------------------------
+    def _split(self, per_layer):
+        """[one item a layer] -> ([the kv layers'], [the state layers'])."""
+        return ([x for x, k in zip(per_layer, self.layer_kinds) if k == 'kv'],
+                [x for x, k in zip(per_layer, self.layer_kinds)
+                 if k == 'state'])
+
+    def _merge(self, kv, state):
+        """The two kinds' items back in layer order."""
+        kv, state = iter(kv), iter(state)
+        return [next(kv) if k == 'kv' else next(state)
+                for k in self.layer_kinds]
+
+    def arrays(self):
+        ks, vs = (tuple(x) for x in zip(*self.pools))
+        return (ks, vs), self.state.arrays()
+
+    def set_arrays(self, arrays):
+        self.pools = list(zip(*arrays[0]))
+        self.state.set_arrays(arrays[1])
+
+    def prefill_where(self, seq_ids, rows, bucket):
+        """(block ids [rows, blocks of the bucket], state rows [rows]): a
+        padding row, and a block past what a sequence holds, name the
+        trash block; a padding row's state row is the one past the
+        last, which the write drops."""
+        nblk = blocks_for(bucket, self.block_size)
+        blocks = np.zeros((rows, nblk), np.int32)
+        for i, sid in enumerate(seq_ids):
+            held = len(self.owned(sid))
+            blocks[i] = self.table_row(sid, max(nblk, held))[:nblk]
+        return blocks, self.state.prefill_where(seq_ids, rows, bucket)
+
+    def state_row(self, where, i):
+        return self.state.state_row(where[1], i)
+
+    def decode_where(self, plan):
+        return plan.tables, self.state.rows_of(
+            [r.rid for r in plan.requests], plan.batch)
+
+    def idle_where(self, batch, width):
+        return (np.zeros((batch, width), np.int32),
+                self.state.idle_where(batch, width))
+
+    def decode_path(self, model, batch, width):
+        """'kernel' where the kv layers take `paged_decode_grouped` and
+        the state layers the model's kernel; else what each takes."""
+        from ..ops.paged_attention import can_use_pallas_grouped
+        cfg = model.config
+        kv = 'kernel' if can_use_pallas_grouped(
+            self.pools[0][0], jax.ShapeDtypeStruct((batch, width),
+                                                   jnp.int32),
+            cfg.num_heads, self.head_dim) else 'gather'
+        state = self.state.decode_path(model, batch, width)
+        return 'kernel' if kv == state == 'kernel' \
+            else f'kv:{kv},state:{state}'
+
+    def prefill_caches(self, model, rows, bucket, lengths):
+        del model, rows, bucket
+        return [PrefillKV(lengths=lengths) if k == 'kv'
+                else RecurrentStateView(lengths=lengths)
+                for k in self.layer_kinds]
+
+    def store_prefill(self, arrays, caches, where):
+        """Every row's keys and values, a block a table entry, into each
+        kv layer's pools, and its final states into its slot."""
+        (ks, vs), states = arrays
+        blocks, rows = where
+        kv, state = self._split(caches)
+        B, nblk = blocks.shape
+        bs = self.block_size
+        new_ks, new_vs = [], []
+        with jax.named_scope('paged.store_prefill'):
+            for c, kp, vp in zip(kv, ks, vs):
+                out = []
+                for x, pool in ((c.k, kp), (c.v, vp)):
+                    pad = nblk * bs - x.shape[1]
+                    if pad:
+                        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                    out.append(pool.at[blocks].set(
+                        x.reshape(B, nblk, bs, -1).astype(pool.dtype)))
+                new_ks.append(out[0])
+                new_vs.append(out[1])
+        return ((tuple(new_ks), tuple(new_vs)),
+                self.state.store_prefill(states, state, rows))
+
+    def constrain(self, arrays):
+        return arrays
+
+    def decode_views(self, arrays, where, ctx, active):
+        (ks, vs), states = arrays
+        tables, rows = where
+        first = jnp.zeros_like(ctx)
+        kv = [GroupedCacheView(k, v, tables, ctx, ctx + 1, first, active)
+              for k, v in zip(ks, vs)]
+        return self._merge(
+            kv, self.state.decode_views(states, rows, ctx, active))
+
+    def arrays_of(self, views):
+        kv, state = self._split(views)
+        return ((tuple(v.k_pool for v in kv), tuple(v.v_pool for v in kv)),
+                self.state.arrays_of(state))
+
+    def step_stats(self, views):
+        """No counts; the taps of `tap_layers`, a dict a layer (a state
+        layer and a kv layer hand different tensors)."""
+        return {'counts': jnp.zeros((0,), jnp.int32),
+                'taps': tuple(views[i].taps for i in self.tap_layers)}
+
+    def prefill_taps(self, caches):
+        """What a prefill's first tapped layer handed out, a dict of
+        [rows, bucket, ...]."""
+        return caches[self.tap_layers[0]].taps
+
+
+def cache_for(model, config):
+    """The cache the model says it needs, sized by the engine's resolved
+    `ServeConfig`: a `HybridCache` for layers of two kinds of memory
+    (`serving_state` 'hybrid'), a `RecurrentStateCache` for a recurrent
+    state alone ('recurrent'), a `LatentKVCache` for latent attention
+    ('latent'), a `LayerGroupKVCache` for full and window layers
+    (`cache_spec()` and 'paged'), and the paged pool otherwise."""
+    state = getattr(model, 'serving_state', 'paged')
+    if state == 'hybrid':
+        return HybridCache(
+            **model.cache_spec(), block_size=config.block_size,
+            num_blocks=config.num_blocks, slots=config.max_slots,
+            max_model_len=config.max_model_len)
+    if state == 'recurrent':
+        return RecurrentStateCache(
+            **model.state_spec(), slots=config.max_slots,
+            max_model_len=config.max_model_len)
+    if state == 'latent':
+        # a position's latent and rotary key, one pool a layer
+        return LatentKVCache(
+            **model.cache_spec(), block_size=config.block_size,
+            num_blocks=config.num_blocks)
+    if hasattr(model, 'cache_spec'):
+        # grouped query heads and layers of two kinds: the cache's
+        # shape is the model's to say (hidden_size // num_heads is no
+        # head size there)
+        return LayerGroupKVCache(
+            **model.cache_spec(), block_size=config.block_size,
+            num_blocks=config.num_blocks, max_slots=config.max_slots,
+            decode_span=config.decode_span)
+    cfg = model.config
+    nh = cfg.num_heads
+    return PagedKVCache(cfg.num_layers, nh, cfg.hidden_size // nh,
+                        block_size=config.block_size,
+                        num_blocks=config.num_blocks)

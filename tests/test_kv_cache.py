@@ -261,3 +261,65 @@ class TestBeamSearchWithCache:
         ids = np.asarray(outs.value if hasattr(outs, 'value') else outs)
         assert ids.shape[0] == 2 and ids.shape[2] == K
         assert ids.shape[1] <= 6
+
+
+class TestHybridCache:
+    """`serving/kv_cache.py::HybridCache`'s allocator: one sequence
+    holds a slot of state and paged blocks at once, gets both or
+    neither, gives both back; its audit sees a sequence that holds one
+    without the other."""
+
+    def _cache(self, slots=2, num_blocks=7):
+        from paddle_tpu.serving.kv_cache import HybridCache
+        return HybridCache(('state', 'kv', 'state'), ((4, 8), (3, 8)), 2,
+                           4, block_size=4, num_blocks=num_blocks,
+                           slots=slots, max_model_len=64, device_init=False)
+
+    def test_ensure_takes_a_slot_and_the_blocks_or_neither(self):
+        cache = self._cache()
+        assert cache.ensure('a', 9)            # a slot, 3 blocks
+        assert cache.state.owned('a') == [1] and len(cache.owned('a')) == 3
+        assert cache.ensure('a', 12) and len(cache.owned('a')) == 3
+        # 3 blocks are left: 'b' needs 4, so it takes no slot either
+        assert not cache.ensure('b', 13)
+        assert cache.state.owned('b') == [] and cache.owned('b') == []
+        assert cache.ensure('b', 12) and cache.state.owned('b') == [2]
+        # no slot is left: nothing is taken for 'c'
+        assert not cache.ensure('c', 1)
+        assert cache.free_blocks == cache.free_slots == 0
+        # a live sequence that cannot grow keeps what it held
+        assert not cache.ensure('a', 16)
+        assert cache.state.owned('a') == [1] and len(cache.owned('a')) == 3
+        assert cache.audit() == []
+
+    def test_free_gives_both_back_and_counts_both(self):
+        """The blocks are the pool's (what the scheduler reads), the
+        slots are counted apart."""
+        cache = self._cache()
+        whole = cache.num_blocks - 1
+        assert whole == 6 and cache.free_blocks == whole
+        assert cache.free_slots == cache.slots == 2
+        cache.ensure('a', 5)
+        assert cache.free_blocks == whole - 2 and cache.free_slots == 1
+        assert cache.free_seq('a') == 2 + 1
+        assert cache.free_blocks == whole and cache.free_slots == 2
+        assert cache.owners() == []
+
+    def test_the_audit_sees_one_kind_held_without_the_other(self):
+        cache = self._cache()
+        cache.ensure('a', 5)
+        cache.kv.free_seq('a')
+        assert any('a slot and no blocks' in p for p in cache.audit())
+        cache.kv.ensure('a', 5)
+        cache.state.free_seq('a')
+        assert any('blocks and no slot' in p for p in cache.audit())
+
+    def test_where_and_the_plan_rows(self):
+        cache = self._cache()
+        cache.ensure('a', 6)
+        cache.ensure('b', 3)
+        blocks, rows = cache.prefill_where(['a', 'b'], 3, 8)
+        assert blocks.shape == (3, 2) and list(blocks[1]) == [
+            cache.owned('b')[0], 0]
+        assert list(rows) == [0, 1, 2]         # the padding row: dropped
+        assert cache.state_row((blocks, rows), 1) == 1

@@ -664,7 +664,7 @@ def test_the_decode_module_holds_the_recurrent_state_once(one_chip,
     model = RetentionForCausalLM.__new__(RetentionForCausalLM)
     model.config = cfg
     eng = ServingEngine.__new__(ServingEngine)
-    eng.model, eng.recurrent = model, True
+    eng.model = model
     eng.config = ServeConfig(
         max_slots=slots, decode_span=span, prompt_buckets=(256,),
         batch_buckets=(slots,), prefill_batch=1, max_model_len=2048,
@@ -692,4 +692,59 @@ def test_the_decode_module_holds_the_recurrent_state_once(one_chip,
     assert memory.temp_size_in_bytes < one_layer // 2, memory
     copies = _results_of(text, 'f32[16,8,128,8320]',
                          ('copy', 'copy-start'))
+    assert not copies, copies[:3]
+
+
+# -- the hybrid Mamba-2 / attention decoder's decode module, same chip ----------
+def test_mosaic_compiles_the_ssm_update_at_serving_widths(one_chip):
+    """Granite-4.0-H-Micro's one-token update: 64 rows, a state [128,
+    4096] a row, two grid steps of 2,048 lanes.  The state's slots come
+    out in place: the kernel's second result is its sixth operand."""
+    from paddle_tpu.ops import ssm
+    R, N, HP = 64, 128, 4096
+
+    def sd(shape, dt='float32'):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    compiled = _uncached(lambda: ssm._ssm_decode.lower(
+        sd((R,), 'int32'), sd((R, 1, HP)), sd((R, 1, HP)), sd((R, N, 1)),
+        sd((R, N, 1)), sd((R, N, HP)), tile=ssm._tile(HP)).compile())
+    text = compiled.as_text()
+    assert 'tpu_custom_call' in text and 'ssm_decode' in text
+    assert 'output_to_operand_aliasing={{1}: (5, {})}' in text
+
+
+def test_the_decode_module_holds_the_hybrid_cache_once(one_chip,
+                                                       monkeypatch):
+    """serve.decode[64x8] of the hybrid decoder at Granite-4.0-H-Micro's
+    widths, one Mamba and one attention layer, through the engine's own
+    `_decode_build`, for the described chip: both kernels in it, the state and
+    the pools donated in and aliased out, nothing of a state layer's
+    size copied round the scan, the temporaries under one layer's
+    state."""
+    import json
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
+                                    'benchmark_suite'))
+    import granite_compile_report as report
+    monkeypatch.setattr(_gating, 'pallas_backend_ok', lambda: True)
+    with open(os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                           'benchmark', 'configs',
+                           'granite_4_0_h_micro_serve.json')) as f:
+        config = json.load(f)
+    config['model'].update(num_layers=2, layer_types=['mamba', 'attention'])
+    eng = report.engine_of_shapes(config, one_chip, num_blocks=1025)
+    fn, example, donate = report.module_args(
+        eng, 'decode', 64, eng.scheduler.table_width, one_chip)
+    compiled = _uncached(lambda: jax.jit(
+        fn, donate_argnums=donate).lower(*example).compile())
+    text = compiled.as_text()
+    assert 'ssm_decode' in text and 'paged_decode_grouped' in text
+    state = 64 * (128 * 4096 + 3 * 4352) * 4
+    pools = 2 * 1025 * 16 * 512 * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state + pools, memory
+    assert memory.temp_size_in_bytes < state, memory
+    copies = _results_of(text, 'f32[64,128,4096]', ('copy', 'copy-start'))
     assert not copies, copies[:3]

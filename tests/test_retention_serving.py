@@ -106,7 +106,8 @@ def test_the_model_is_drawn_in_its_serving_dtype():
 def test_the_cache_follows_from_the_model():
     from paddle_tpu.models.gpt import gpt_tiny
     eng = _engine()
-    assert eng.recurrent and isinstance(eng.cache, RecurrentStateCache)
+    assert eng.cache.kinds == ('state',)
+    assert isinstance(eng.cache, RecurrentStateCache)
     S, z = eng.cache.states[0]
     assert S.shape == (4, 2, 16, 144) and z.shape == (4, 2, 144)
     assert S.dtype == z.dtype == jnp.float32
@@ -114,7 +115,7 @@ def test_the_cache_follows_from_the_model():
     paddle.seed(0)
     gpt = ServingEngine(gpt_tiny(num_layers=1, max_seq_len=64),
                         ServeConfig(max_slots=2, max_model_len=64))
-    assert not gpt.recurrent and isinstance(gpt.cache, PagedKVCache)
+    assert gpt.cache.kinds == ('kv',) and isinstance(gpt.cache, PagedKVCache)
     assert 'state_rows_updated' not in gpt.report()
 
 

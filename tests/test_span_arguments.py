@@ -191,6 +191,26 @@ def test_recurrent_state_spans_count_the_states_rewritten(tmp_path):
         == sum(new - 1 for new in (6, 9, 5))
 
 
+def test_hybrid_spans_count_blocks_and_states(tmp_path):
+    """A model of state and attention layers: a dispatch carries both
+    the blocks a kv layer reads, step by step, and the states it
+    rewrites."""
+    from paddle_tpu.models.granite_hybrid import granite_hybrid_tiny
+    paddle.seed(3)
+    engine = ServingEngine(granite_hybrid_tiny(), ServeConfig(
+        block_size=4, max_slots=2, decode_span=4, prompt_buckets=(16, 32),
+        batch_buckets=(2,), prefill_batch=1, max_model_len=64,
+        temperature=0.0))
+    requests = load([(9, 6), (25, 9), (14, 5)])
+    spans, plans, report = traced_run(tmp_path, engine, requests)
+    check_common(spans, engine, report, requests)
+    dec = spans['serve.decode_dispatch']
+    assert [a['kv_blocks'] for a in dec] == \
+        [scan_blocks(p, engine.config.block_size) for p in plans]
+    assert total(dec, 'state_rows') == report['state_rows_updated'] \
+        == sum(new - 1 for new in (6, 9, 5))
+
+
 def test_a_span_hands_its_arguments_to_the_annotation(tmp_path):
     """Given at the open and by `set` inside; a span without any is
     the plain annotation; with telemetry on, the record carries them
